@@ -100,8 +100,9 @@ def run() -> dict:
     ecfg = EngineConfig(w_upe=1024, n_upe=0)
     t_single = time_fn(jax.jit(lambda c: convert(c, ecfg)), coo, iters=3)
     if n_dev > 1:
-        mesh = jax.make_mesh((n_dev,), ("data",))
-        with mesh:
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((n_dev,), ("data",))
+        with jax.set_mesh(mesh):
             t_shard = time_fn(
                 jax.jit(lambda c: shard_convert(mesh, c, ecfg)), coo,
                 iters=3)

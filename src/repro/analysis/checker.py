@@ -34,6 +34,7 @@ from repro.analysis.contracts import Case, Violation
 from repro.core import pipeline
 from repro.core.graph import COO, random_coo
 from repro.launch.hlo_analysis import collective_bytes, op_counts
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +158,7 @@ def _delta_cache_guard(cases: list[Case], progress=None) -> Report:
 
 def _lower_shard(case: Case) -> str:
     from repro.engine.shard import shard_convert
-    mesh = jax.make_mesh((case.n_dev,), ("data",))
+    mesh = make_mesh((case.n_dev,), ("data",))
     coo = _make_coo(case.workload)
     # repro: allow-raw-jit — AOT lowering probe; the compiled object is
     # discarded after its HLO text is read, nothing dispatches through it.

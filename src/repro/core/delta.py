@@ -298,17 +298,14 @@ def delta_merge(csc: CSC, delta: EdgeDelta, *, sort_fn,
     # -------- splice: one event rank per output slot + gathers
     j = jnp.arange(out_cap, dtype=jnp.int32)
     g = rank_in_sorted(b2, (j << 1) | 1, side="left", unroll=unroll)
-    # One 3-column gather hands every slot its event row (next event key,
-    # inserts so far, the rank itself) in a single pass. Separate gathers
-    # would each re-evaluate g's whole unrolled compare chain elementally
-    # (same CPU-backend fusion hazard as the event-table rung above);
-    # through one gather the chain is walked once and the three columns
-    # come out materialized.
-    t = jnp.arange(b2.shape[0] + 1, dtype=jnp.int32)
+    # Every slot gathers its event row (next event key, inserts so far) as
+    # two 1-D gathers. Not one [out_cap, 3] gather of stacked columns: a
+    # TPU lays a 3-wide minor dimension out across 128 lanes, a 64 GB
+    # buffer at a 2^27-edge capacity.
     b2_ext = jnp.concatenate([b2, jnp.full((1,), _EVENT_PAD)])
-    event_row = jnp.take(jnp.stack([b2_ext, ci_tab, t], axis=1), g,
-                         axis=0, mode="clip")
-    nxt, ci, g = event_row[:, 0], event_row[:, 1], event_row[:, 2]
+    nxt = jnp.take(b2_ext, g, mode="clip")
+    ci = jnp.take(ci_tab, g, mode="clip")
+    g = jnp.clip(g, 0, b2.shape[0])
     is_ins = nxt == ((j << 1) | 1)
     src = j + g - 2 * ci  # ci inserts pushed j back, g-ci deletes skipped
     n_edges_new = (csc.n_edges + n_ins_eff - n_del_eff).astype(jnp.int32)

@@ -38,17 +38,23 @@ def kernel_fns(cfg: EngineConfig):
     (VMEM-resident rank search + rename lookup,
     ``kernels/reindex_epilogue.py``); one definition shared by
     ``convert``, ``sample_subgraph`` and the mesh-sharded engine so no
-    path can silently drop a knob.
+    path can silently drop a knob. On a TPU backend, each kernel Mosaic
+    refuses (``kernels.ops.MOSAIC_REFUSALS``) is a stand-in that raises
+    ``NotImplementedError`` naming it when the route asks for it.
     """
     if not cfg.use_pallas:
         return None, None, None, None, None, None
     from repro.kernels import ops as _kops
-    return (_kops.make_pallas_chunk_sort_fn(cfg.radix_bits),
+    tpu = _kops.refused_on_tpu
+    return (tpu("radix_sort_chunks",
+                _kops.make_pallas_chunk_sort_fn(cfg.radix_bits)),
             _kops.pallas_count_fn,
-            _kops.make_pallas_merge_fn(cfg.merge_fan_in),
-            _kops.make_pallas_digit_pass_fn(cfg.radix_bits, cfg.w_upe),
-            _kops.pallas_rank_fn,
-            _kops.pallas_rename_fn)
+            tpu("fused_merge_rounds",
+                _kops.make_pallas_merge_fn(cfg.merge_fan_in)),
+            tpu("global_digit_pass",
+                _kops.make_pallas_digit_pass_fn(cfg.radix_bits, cfg.w_upe)),
+            tpu("rank_search_tiles", _kops.pallas_rank_fn),
+            tpu("reindex_rename_tiles", _kops.pallas_rename_fn))
 
 
 def convert(coo: COO, cfg: EngineConfig | None = None,

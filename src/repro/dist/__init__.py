@@ -15,7 +15,6 @@ which itself imports ``hints`` — keeping this __init__ light avoids the
 cycle at package-import time).
 """
 from . import hints, sharding  # noqa: F401
-from .compat import shard_map  # noqa: F401
 from .hints import (current_layout, layout, mesh_info, shard_hint,  # noqa: F401
                     suspend_hints)
 from .sharding import (batch_sharding, dlrm_param_shardings,  # noqa: F401
@@ -26,5 +25,5 @@ __all__ = [
     "batch_sharding", "current_layout", "dlrm_param_shardings", "dp_axes",
     "gnn_batch_shardings", "hints", "layout", "lm_cache_shardings",
     "lm_param_shardings", "mesh_info", "model_axis_size", "replicated",
-    "shard_hint", "shard_map", "sharding", "suspend_hints",
+    "shard_hint", "sharding", "suspend_hints",
 ]

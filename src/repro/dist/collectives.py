@@ -19,13 +19,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.attention import (decode_attention,
                                     decode_attention_partial,
                                     dequantize_kv)
 
-from .compat import shard_map
 from .sharding import _axes_size, dp_axes, model_axis_size
 
 

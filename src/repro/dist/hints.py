@@ -18,8 +18,9 @@ Layouts name a token→mesh-axis mapping:
   sequence dim splits across pods) and nothing otherwise.
 
 The active mesh comes from an explicit ``layout(mesh, ...)`` entry or,
-failing that, from the ambient ``with mesh:`` context — so test code that
-only does ``with mesh: jax.jit(fn)(...)`` still gets hints applied.
+failing that, from the ambient ``jax.set_mesh(mesh)`` context — so test
+code that only does ``with jax.set_mesh(mesh): jax.jit(fn)(...)`` still
+gets hints applied.
 """
 from __future__ import annotations
 
@@ -28,7 +29,7 @@ import dataclasses
 import threading
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 from .sharding import _axes_size as _mesh_axes_size
 
@@ -49,19 +50,14 @@ def _stack() -> list:
     return _state.stack
 
 
-def _ambient_mesh() -> Mesh | None:
-    """The mesh from an enclosing ``with mesh:`` block, if any."""
-    try:
-        from jax._src import mesh as mesh_lib
-        env = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover — future-jax fallback
-        return None
-    if env is None or env.empty:
-        return None
-    return env
+def _ambient_mesh() -> AbstractMesh | None:
+    """The mesh from an enclosing ``jax.set_mesh`` block, if any (its
+    abstract form, which is also what a trace inside ``jax.jit`` sees)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
-def _current_mesh() -> Mesh | None:
+def _current_mesh() -> Mesh | AbstractMesh | None:
     for entry in reversed(_stack()):
         if entry.mesh is not None:
             return entry.mesh

@@ -28,7 +28,8 @@ from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core.costmodel import (EngineConfig, Workload,
                                   pointer_reindex_strategy,
@@ -41,13 +42,19 @@ from repro.core.pipeline import kernel_fns
 from repro.core.pipeline import preprocess as _preprocess_single
 from repro.core.pipeline import sample_subgraph
 from repro.core.set_count import rank_in_sorted
-from repro.dist.compat import shard_map
 from repro.dist.sharding import _axes_size, dp_axes
 
 
 def _dp(mesh: Mesh | None) -> tuple[tuple[str, ...], int]:
     if mesh is None:
         return (), 1
+    explicit = [a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                if t == AxisType.Explicit]
+    if explicit:
+        raise ValueError(
+            f"mesh axes {explicit} are Explicit; the sharded engine runs "
+            f"under GSPMD and needs Auto axes — build the mesh with "
+            f"repro.launch.mesh.make_mesh")
     dp = dp_axes(mesh)
     return dp, _axes_size(mesh, dp)
 
@@ -83,7 +90,8 @@ def shard_sort_by_key(mesh: Mesh, keys: jnp.ndarray, vals: jnp.ndarray,
     bit-identical by the stable-sort argument above)::
 
         >>> import jax, jax.numpy as jnp
-        >>> mesh = jax.make_mesh((1,), ("data",))
+        >>> from repro.launch.mesh import make_mesh
+        >>> mesh = make_mesh((1,), ("data",))
         >>> ks, vs = shard_sort_by_key(mesh, jnp.array([3, 1, 2, 0]),
         ...                            jnp.arange(4), key_bound=4, chunk=4)
         >>> ks.tolist(), vs.tolist()
@@ -160,7 +168,8 @@ def shard_edge_ordering(mesh: Mesh, coo: COO,
 
         >>> import jax
         >>> from repro.core.graph import COO
-        >>> mesh = jax.make_mesh((1,), ("data",))
+        >>> from repro.launch.mesh import make_mesh
+        >>> mesh = make_mesh((1,), ("data",))
         >>> coo = COO.from_arrays([1, 0, 1, 0], [1, 1, 0, 0], n_nodes=2)
         >>> s = shard_edge_ordering(mesh, coo)
         >>> s.dst.tolist(), s.src.tolist()  # sorted by (dst, src)
@@ -197,7 +206,8 @@ def shard_pointer_array(mesh: Mesh, sorted_dst: jnp.ndarray,
     Example::
 
         >>> import jax, jax.numpy as jnp
-        >>> mesh = jax.make_mesh((1,), ("data",))
+        >>> from repro.launch.mesh import make_mesh
+        >>> mesh = make_mesh((1,), ("data",))
         >>> shard_pointer_array(mesh, jnp.array([0, 0, 1, 1]),
         ...                     n_nodes=2).tolist()
         [0, 2, 4]
@@ -229,7 +239,8 @@ def shard_convert(mesh: Mesh, coo: COO,
 
         >>> import jax
         >>> from repro.core.graph import COO
-        >>> mesh = jax.make_mesh((1,), ("data",))
+        >>> from repro.launch.mesh import make_mesh
+        >>> mesh = make_mesh((1,), ("data",))
         >>> coo = COO.from_arrays([1, 0, 1, 0], [1, 1, 0, 0], n_nodes=2)
         >>> csc = shard_convert(mesh, coo)
         >>> csc.ptr.tolist(), csc.idx.tolist()
@@ -261,7 +272,8 @@ def shard_preprocess(mesh: Mesh, coo: COO, batch_nodes: jnp.ndarray,
 
         >>> import jax, jax.numpy as jnp
         >>> from repro.core.graph import COO
-        >>> mesh = jax.make_mesh((1,), ("data",))
+        >>> from repro.launch.mesh import make_mesh
+        >>> mesh = make_mesh((1,), ("data",))
         >>> coo = COO.from_arrays([1, 0, 1, 0], [1, 1, 0, 0], n_nodes=2)
         >>> sub = shard_preprocess(mesh, coo, jnp.array([0], jnp.int32),
         ...                        fanouts=(1,), key=jax.random.PRNGKey(0))
@@ -285,7 +297,8 @@ def jit_shard_preprocess(mesh: Mesh):
     Example::
 
         >>> import jax
-        >>> mesh = jax.make_mesh((1,), ("data",))
+        >>> from repro.launch.mesh import make_mesh
+        >>> mesh = make_mesh((1,), ("data",))
         >>> jit_shard_preprocess(mesh) is jit_shard_preprocess(mesh)
         True
     """

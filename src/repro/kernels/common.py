@@ -10,18 +10,30 @@ TPU-adaptation notes (DESIGN.md §2):
   of every output slot, and the move is one ``jnp.take`` — O(N·log N)
   versus the O(N²) one-hot MXU matmul it replaced.
   ``onehot_relocate_i32`` is kept as the MXU reference/benchmark router.
-* interpret=True executes kernels in Python on CPU — the validation target
-  in this container; on real TPUs the same pallas_call lowers to Mosaic.
+* Every kernel goes through :func:`pallas_call`, which picks the interpreter
+  or Mosaic from the platform the program is lowered for.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-# CPU container: always interpret. On TPU hosts flip with REPRO_PALLAS_HW=1.
-INTERPRET = os.environ.get("REPRO_PALLAS_HW", "0") != "1"
+
+def pallas_call(kernel, **kwargs):
+    """``pl.pallas_call`` that runs in the Pallas interpreter where the
+    program is lowered for the CPU and compiles through Mosaic everywhere
+    else. ``jax.lax.platform_dependent`` makes the choice per lowering,
+    so one jitted function serves both and nothing is decided at import.
+    """
+    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+    compiled = pl.pallas_call(kernel, **kwargs)
+
+    def call(*args):
+        return jax.lax.platform_dependent(*args, cpu=interpreted,
+                                          default=compiled)
+
+    return call
 
 
 def prefix_sum_tree(x: jnp.ndarray, axis: int = 0,

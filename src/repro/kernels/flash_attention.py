@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .common import INTERPRET
+from .common import pallas_call
 
 NEG_INF = -1e30
 
@@ -94,7 +94,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kernel = functools.partial(_kernel, bq=bq, bk=bk, causal=causal,
                                window=window, logit_cap=logit_cap,
                                scale=scale)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -109,7 +109,6 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        interpret=INTERPRET,
     )(q, k, v)
 
 
@@ -291,7 +290,7 @@ def flash_attention_bwd(q, k, v, dout, *, causal=True, window=None,
     kern_a = functools.partial(_dq_kernel, bq=bq, bk=bk, causal=causal,
                                window=window, logit_cap=logit_cap,
                                scale=scale)
-    dq = pl.pallas_call(
+    dq = pallas_call(
         kern_a,
         grid=(bh, sq // bq, skv // bk),
         in_specs=[
@@ -305,13 +304,12 @@ def flash_attention_bwd(q, k, v, dout, *, causal=True, window=None,
         out_specs=pl.BlockSpec((1, bq, dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        interpret=INTERPRET,
     )(q, k, v, dout, lse, delta)
 
     kern_b = functools.partial(_dkv_kernel, bq=bq, bk=bk, causal=causal,
                                window=window, logit_cap=logit_cap,
                                scale=scale)
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         kern_b,
         grid=(bh, skv // bk, sq // bq),
         in_specs=[
@@ -332,6 +330,5 @@ def flash_attention_bwd(q, k, v, dout, *, causal=True, window=None,
         ],
         scratch_shapes=[pltpu.VMEM((bk, dh), jnp.float32),
                         pltpu.VMEM((bk, dh), jnp.float32)],
-        interpret=INTERPRET,
     )(q, k, v, dout, lse, delta)
     return dq, dk, dv

@@ -26,7 +26,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.ordering import merge_round_fan_ins, merge_sorted_k
 
-from .common import INTERPRET
+from .common import pallas_call
 
 # Elements of one (keys, vals) super-block held in VMEM per grid step.
 # 2 arrays × in+out × 4 B × 65536 = 2 MiB — comfortably inside the ~16 MiB
@@ -106,16 +106,15 @@ def fused_merge_rounds(keys: jnp.ndarray, vals: jnp.ndarray, run: int,
         block *= k
     grid = n // block
     if vals is None:
-        out_k = pl.pallas_call(
+        out_k = pallas_call(
             _make_kernel(run, fan_ins, keys_only=True),
             grid=(grid,),
             in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
             out_specs=pl.BlockSpec((block,), lambda i: (i,)),
             out_shape=jax.ShapeDtypeStruct((n,), keys.dtype),
-            interpret=INTERPRET,
         )(keys)
         return out_k, None, block
-    out_k, out_v = pl.pallas_call(
+    out_k, out_v = pallas_call(
         _make_kernel(run, fan_ins),
         grid=(grid,),
         in_specs=[
@@ -130,7 +129,6 @@ def fused_merge_rounds(keys: jnp.ndarray, vals: jnp.ndarray, run: int,
             jax.ShapeDtypeStruct((n,), keys.dtype),
             jax.ShapeDtypeStruct((n,), vals.dtype),
         ],
-        interpret=INTERPRET,
     )(keys, vals)
     return out_k, out_v, block
 

@@ -5,6 +5,7 @@ they pad to block multiples, handle sentinels, and dispatch to the kernels.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .merge import fused_merge_rounds, make_pallas_merge_fn, pallas_merge_fn
@@ -26,10 +27,46 @@ __all__ = [
     "set_count_less", "filter_tree_lookup", "pallas_count_fn",
     "rank_search_tiles", "reindex_rename_tiles", "pallas_rank_fn",
     "pallas_rename_fn",
-    "segment_sum_sorted", "segment_sum_padded",
+    "segment_sum_sorted", "segment_sum_padded", "MOSAIC_REFUSALS",
+    "refused_on_tpu",
 ]
 
 _I32_MAX = 0x7FFFFFFF
+
+# The kernels Mosaic refuses to lower for a TPU, each with the compiler's
+# own words. Every one holds an in-kernel gather or a dynamic slice of a
+# vector, which the Pallas TPU lowering does not implement.
+# tests/test_tpu_compile.py pins each refusal as a strict xfail, so a
+# kernel that starts to compile must leave this table.
+MOSAIC_REFUSALS = {
+    "radix_sort_chunks": "Unimplemented primitive in Pallas TPU lowering "
+                         "for KernelType.TC: dynamic_slice",
+    "radix_sort_chunks_keys": "Unimplemented primitive in Pallas TPU "
+                              "lowering for KernelType.TC: dynamic_slice",
+    "global_digit_pass": "Unimplemented primitive in Pallas TPU lowering "
+                         "for KernelType.TC: dynamic_slice",
+    "prefix_partition": "Unimplemented primitive in Pallas TPU lowering "
+                        "for KernelType.TC: dynamic_slice",
+    "fused_merge_rounds": "Unsupported gather",
+    "rank_search_tiles": "Only 2D gather is supported",
+    "reindex_rename_tiles": "Only 2D gather is supported",
+}
+
+
+def refused_on_tpu(name: str, fn):
+    """``fn`` where the default backend is not a TPU; on a TPU, a stand-in
+    that raises the moment a route asks for kernel ``name`` — neither
+    the interpreter nor the jnp reference stands in for it there."""
+    if name not in MOSAIC_REFUSALS or jax.default_backend() != "tpu":
+        return fn
+
+    def refuse(*args, **kwargs):
+        raise NotImplementedError(
+            f"Pallas kernel {name} does not compile for TPU (Mosaic: "
+            f"{MOSAIC_REFUSALS[name]}); run this route with "
+            f"use_pallas=False")
+
+    return refuse
 
 
 def segment_sum_padded(dst: jnp.ndarray, messages: jnp.ndarray, n_nodes: int,

@@ -17,7 +17,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.set_partition import gather_sources_from_counts
 
-from .common import INTERPRET, prefix_sum_tree
+from .common import pallas_call, prefix_sum_tree
 
 
 def _partition_kernel(cond_ref, val_ref, out_ref, nsel_ref):
@@ -29,7 +29,7 @@ def _partition_kernel(cond_ref, val_ref, out_ref, nsel_ref):
     base = jnp.stack([jnp.int32(0), n_sel])
     src = gather_sources_from_counts(incl, base)  # inverse-permutation router
     out_ref[...] = jnp.take(vals, src, mode="clip")
-    nsel_ref[...] = n_sel[None]
+    nsel_ref[...] = jnp.full(nsel_ref.shape, n_sel, jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("block",))
@@ -44,7 +44,7 @@ def prefix_partition(values: jnp.ndarray, cond: jnp.ndarray,
     n = values.shape[0]
     assert n % block == 0, (n, block)
     grid = n // block
-    out, nsel = pl.pallas_call(
+    out, nsel = pallas_call(
         _partition_kernel,
         grid=(grid,),
         in_specs=[
@@ -53,12 +53,13 @@ def prefix_partition(values: jnp.ndarray, cond: jnp.ndarray,
         ],
         out_specs=[
             pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            # one (1, 128) lane row per block: a legal Mosaic block that
+            # carries the block's count broadcast across the lanes
+            pl.BlockSpec((1, 1, 128), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((grid,), jnp.int32),
+            jax.ShapeDtypeStruct((grid, 1, 128), jnp.int32),
         ],
-        interpret=INTERPRET,
     )(cond, values)
-    return out, nsel
+    return out, nsel[:, 0, 0]

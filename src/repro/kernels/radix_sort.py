@@ -20,7 +20,7 @@ from jax.experimental import pallas as pl
 from repro.core.set_partition import (digit_relocation_sources,
                                       rank_gather_sources)
 
-from .common import INTERPRET, prefix_sum_tree
+from .common import pallas_call, prefix_sum_tree
 
 
 def _make_kernel(n_passes: int, radix_bits: int, keys_only: bool = False):
@@ -62,7 +62,7 @@ def radix_sort_chunks(keys: jnp.ndarray, values: jnp.ndarray, chunk: int,
     assert n % chunk == 0, (n, chunk)
     n_passes = max(1, -(-key_bits // radix_bits))
     grid = n // chunk
-    out_k, out_v = pl.pallas_call(
+    out_k, out_v = pallas_call(
         _make_kernel(n_passes, radix_bits),
         grid=(grid,),
         in_specs=[
@@ -77,7 +77,6 @@ def radix_sort_chunks(keys: jnp.ndarray, values: jnp.ndarray, chunk: int,
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.int32),
         ],
-        interpret=INTERPRET,
     )(keys, values)
     return out_k, out_v
 
@@ -95,13 +94,12 @@ def radix_sort_chunks_keys(keys: jnp.ndarray, chunk: int, key_bits: int,
     assert n % chunk == 0, (n, chunk)
     n_passes = max(1, -(-key_bits // radix_bits))
     grid = n // chunk
-    return pl.pallas_call(
+    return pallas_call(
         _make_kernel(n_passes, radix_bits, keys_only=True),
         grid=(grid,),
         in_specs=[pl.BlockSpec((chunk,), lambda i: (i,))],
         out_specs=pl.BlockSpec((chunk,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=INTERPRET,
     )(keys)
 
 
@@ -135,7 +133,7 @@ def _make_partition_hist_kernel(shift: int, radix_bits: int,
             [base, jnp.full((1,), tile, jnp.int32)]))
         pk = jnp.take(keys, src, mode="clip")
         pv = None if vals is None else jnp.take(vals, src, mode="clip")
-        return pk, pv, base.reshape(1, -1), hist.reshape(1, -1)
+        return pk, pv, base.reshape(1, 1, -1), hist.reshape(1, 1, -1)
 
     if keys_only:
         def kernel(key_ref, out_key_ref, lbase_ref, hist_ref):
@@ -178,34 +176,36 @@ def global_digit_pass(keys: jnp.ndarray, values: jnp.ndarray | None,
     assert n % tile == 0, (n, tile)
     n_buckets = 1 << radix_bits
     grid = n // tile
-    row_spec = pl.BlockSpec((1, n_buckets), lambda i: (i, 0))
+    # each tile's [B] table row is a (1, B) block of a [T, 1, B] array —
+    # its last two dims are the array's own, as Mosaic's block rule asks
+    row_spec = pl.BlockSpec((1, 1, n_buckets), lambda i: (i, 0, 0))
     tile_spec = pl.BlockSpec((tile,), lambda i: (i,))
-    tables = [jax.ShapeDtypeStruct((grid, n_buckets), jnp.int32)] * 2
+    tables = [jax.ShapeDtypeStruct((grid, 1, n_buckets), jnp.int32)] * 2
     if values is None:
-        pk, lbase, hist = pl.pallas_call(
+        pk, lbase, hist = pallas_call(
             _make_partition_hist_kernel(shift, radix_bits, keys_only=True),
             grid=(grid,),
             in_specs=[tile_spec],
             out_specs=[tile_spec, row_spec, row_spec],
             out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] + tables,
-            interpret=INTERPRET,
         )(keys)
         pv = None
     else:
-        pk, pv, lbase, hist = pl.pallas_call(
+        pk, pv, lbase, hist = pallas_call(
             _make_partition_hist_kernel(shift, radix_bits),
             grid=(grid,),
             in_specs=[tile_spec, tile_spec],
             out_specs=[tile_spec, tile_spec, row_spec, row_spec],
             out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * 2 + tables,
-            interpret=INTERPRET,
         )(keys, values)
     # tiny [T, B] table math between the kernels (host of the adder tree)
+    lbase = lbase.reshape(grid, n_buckets)
+    hist = hist.reshape(grid, n_buckets)
     incl_t = jnp.cumsum(hist, axis=0)
     excl_t = incl_t - hist
     counts = incl_t[-1]
     gbase = jnp.cumsum(counts) - counts
-    src = pl.pallas_call(
+    src = pallas_call(
         _make_rank_gather_kernel(tile),
         grid=(grid,),
         in_specs=[
@@ -216,7 +216,6 @@ def global_digit_pass(keys: jnp.ndarray, values: jnp.ndarray | None,
         ],
         out_specs=tile_spec,
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
-        interpret=INTERPRET,
     )(gbase.astype(jnp.int32), incl_t, excl_t, lbase)
     pk = jnp.take(pk, src, mode="clip")
     if pv is not None:

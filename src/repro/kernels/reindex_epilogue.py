@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import INTERPRET, pad_pow2_1d
+from .common import pallas_call, pad_pow2_1d
 
 _SENTINEL = 0x7FFFFFFF
 
@@ -66,7 +66,7 @@ def rank_search_tiles(sorted_arr: jnp.ndarray, queries: jnp.ndarray,
     n = sorted_arr.shape[0]
     q = queries.shape[0]
     assert q % q_block == 0, (q, q_block)
-    return pl.pallas_call(
+    return pallas_call(
         partial(_rank_kernel, side=side, n=n),
         grid=(q // q_block,),
         in_specs=[
@@ -75,7 +75,6 @@ def rank_search_tiles(sorted_arr: jnp.ndarray, queries: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((q_block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((q,), jnp.int32),
-        interpret=INTERPRET,
     )(sorted_arr, queries)
 
 
@@ -104,7 +103,7 @@ def reindex_rename_tiles(sorted_vids: jnp.ndarray, slot_to_new: jnp.ndarray,
     q = queries.shape[0]
     assert q % q_block == 0, (q, q_block)
     assert slot_to_new.shape[0] == n
-    return pl.pallas_call(
+    return pallas_call(
         partial(_rename_kernel, n=n),
         grid=(q // q_block,),
         in_specs=[
@@ -114,7 +113,6 @@ def reindex_rename_tiles(sorted_vids: jnp.ndarray, slot_to_new: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((q_block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((q,), jnp.int32),
-        interpret=INTERPRET,
     )(sorted_vids, slot_to_new, queries)
 
 

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import INTERPRET
+from .common import pallas_call
 
 
 def _agg_kernel(dst_ref, msg_ref, out_ref, *, v_block: int):
@@ -30,18 +30,18 @@ def _agg_kernel(dst_ref, msg_ref, out_ref, *, v_block: int):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    dst = dst_ref[...]  # [Eb] int32 (sorted)
+    dst = dst_ref[...]  # [1, Eb] int32 (sorted)
     v_start = i * v_block
-    lo = dst[0]
-    hi = dst[-1]
+    lo = jnp.min(dst)
+    hi = jnp.max(dst)
     overlap = (hi >= v_start) & (lo < v_start + v_block)
 
     @pl.when(overlap)
     def _accum():
         msg = msg_ref[...]  # [Eb, Db] f32
         rel = dst - v_start
-        iota = jax.lax.broadcasted_iota(jnp.int32, (v_block, dst.shape[0]), 0)
-        onehot = (rel[None, :] == iota).astype(jnp.float32)  # [Vb, Eb]
+        iota = jax.lax.broadcasted_iota(jnp.int32, (v_block, dst.shape[1]), 0)
+        onehot = (rel == iota).astype(jnp.float32)  # [Vb, Eb]
         out_ref[...] += jax.lax.dot(onehot, msg,
                                     preferred_element_type=jnp.float32)
 
@@ -60,14 +60,14 @@ def segment_sum_sorted(dst: jnp.ndarray, messages: jnp.ndarray, n_nodes: int,
     e, d = messages.shape
     assert dst.shape[0] == e
     assert n_nodes % v_block == 0 and e % e_block == 0 and d % d_block == 0
-    return pl.pallas_call(
+    return pallas_call(
         partial(_agg_kernel, v_block=v_block),
         grid=(n_nodes // v_block, d // d_block, e // e_block),
         in_specs=[
-            pl.BlockSpec((e_block,), lambda i, j, k: (k,)),
+            # dst as a [1, E] row: each block is one lane-major row
+            pl.BlockSpec((1, e_block), lambda i, j, k: (0, k)),
             pl.BlockSpec((e_block, d_block), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((v_block, d_block), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_nodes, d), jnp.float32),
-        interpret=INTERPRET,
-    )(dst, messages)
+    )(dst.reshape(1, e), messages)

@@ -14,7 +14,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import INTERPRET
+from .common import pallas_call
+
+# Targets run down the sublanes as a [T, 1] column and elements along the
+# lanes as a [1, E] row, so each tile's comparator array is a plain 2-D
+# broadcast and both blocks meet Mosaic's (8, 128) block rule.
+
+
+def _target_spec(t_block):
+    return pl.BlockSpec((t_block, 1), lambda i, j: (i, 0))
+
+
+def _element_spec(e_block):
+    return pl.BlockSpec((1, e_block), lambda i, j: (0, j))
 
 
 def _count_kernel(tgt_ref, elem_ref, out_ref):
@@ -24,10 +36,10 @@ def _count_kernel(tgt_ref, elem_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tgt = tgt_ref[...]  # [T]
-    elem = elem_ref[...]  # [E]
-    cmp = (elem[None, :] < tgt[:, None]).astype(jnp.int32)  # comparators
-    out_ref[...] += jnp.sum(cmp, axis=1)  # adder tree
+    tgt = tgt_ref[...]  # [T, 1]
+    elem = elem_ref[...]  # [1, E]
+    cmp = (elem < tgt).astype(jnp.int32)  # [T, E] comparators
+    out_ref[...] += jnp.sum(cmp, axis=1, keepdims=True)  # adder tree
 
 
 @partial(jax.jit, static_argnames=("t_block", "e_block"))
@@ -41,17 +53,14 @@ def set_count_less(elements: jnp.ndarray, targets: jnp.ndarray,
     e = elements.shape[0]
     t = targets.shape[0]
     assert e % e_block == 0 and t % t_block == 0, (e, e_block, t, t_block)
-    return pl.pallas_call(
+    out = pallas_call(
         _count_kernel,
         grid=(t // t_block, e // e_block),
-        in_specs=[
-            pl.BlockSpec((t_block,), lambda i, j: (i,)),
-            pl.BlockSpec((e_block,), lambda i, j: (j,)),
-        ],
-        out_specs=pl.BlockSpec((t_block,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.int32),
-        interpret=INTERPRET,
-    )(targets, elements)
+        in_specs=[_target_spec(t_block), _element_spec(e_block)],
+        out_specs=_target_spec(t_block),
+        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.int32),
+    )(targets.reshape(t, 1), elements.reshape(1, e))
+    return out.reshape(t)
 
 
 def _filter_kernel(tgt_ref, key_ref, pay_ref, out_ref):
@@ -61,11 +70,12 @@ def _filter_kernel(tgt_ref, key_ref, pay_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    tgt = tgt_ref[...]
-    keys = key_ref[...]
-    pays = pay_ref[...]
-    hit = keys[None, :] == tgt[:, None]  # equality comparators
-    enc = jnp.max(jnp.where(hit, pays[None, :] + 1, 0), axis=1)  # OR tree
+    tgt = tgt_ref[...]  # [T, 1]
+    keys = key_ref[...]  # [1, E]
+    pays = pay_ref[...]  # [1, E]
+    hit = keys == tgt  # [T, E] equality comparators
+    enc = jnp.max(jnp.where(hit, pays + 1, 0), axis=1,
+                  keepdims=True)  # OR tree
     out_ref[...] = jnp.maximum(out_ref[...], enc)
 
 
@@ -82,18 +92,15 @@ def filter_tree_lookup(keys: jnp.ndarray, payloads: jnp.ndarray,
     e = keys.shape[0]
     t = targets.shape[0]
     assert e % e_block == 0 and t % t_block == 0
-    enc = pl.pallas_call(
+    enc = pallas_call(
         _filter_kernel,
         grid=(t // t_block, e // e_block),
-        in_specs=[
-            pl.BlockSpec((t_block,), lambda i, j: (i,)),
-            pl.BlockSpec((e_block,), lambda i, j: (j,)),
-            pl.BlockSpec((e_block,), lambda i, j: (j,)),
-        ],
-        out_specs=pl.BlockSpec((t_block,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((t,), jnp.int32),
-        interpret=INTERPRET,
-    )(targets, keys, payloads)
+        in_specs=[_target_spec(t_block), _element_spec(e_block),
+                  _element_spec(e_block)],
+        out_specs=_target_spec(t_block),
+        out_shape=jax.ShapeDtypeStruct((t, 1), jnp.int32),
+    )(targets.reshape(t, 1), keys.reshape(1, e),
+      payloads.reshape(1, e)).reshape(t)
     hit = enc > 0
     return jnp.where(hit, enc - 1, -1), hit
 

@@ -41,7 +41,7 @@ def run_cell(cell: Cell, mesh, mesh_name: str) -> dict:
         rec["skip_reason"] = cell.skipped
         return rec
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         # repro: allow-raw-jit — one-shot compile probe per cell; the CLI
         # measures lower/compile time, nothing re-dispatches this wrapper.
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings,
@@ -66,8 +66,6 @@ def run_cell(cell: Cell, mesh, mesh_name: str) -> dict:
             mem, "generated_code_size_in_bytes", None),
         "alias_bytes": getattr(mem, "alias_size_in_bytes", None),
     }
-    if isinstance(cost, list):
-        cost = cost[0] if cost else {}
     rec["cost"] = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
@@ -109,6 +107,8 @@ def main() -> None:
                     help="run the AutoGNN pipeline cells")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     meshes = {"single": False, "multi": True}
     mesh_names = (["single", "multi"] if args.mesh == "both"

@@ -77,20 +77,20 @@ def main():
     ap.add_argument("--dump", help="write HLO text to this path")
     ap.add_argument("--collectives", action="store_true")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.launch.mesh import make_production_mesh
     from repro.launch.steps import build_cell
     mesh = make_production_mesh(multi_pod=args.mesh == "multi")
     cell = build_cell(args.arch, args.shape, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         # repro: allow-raw-jit — one-shot CLI compile for inspection, not a
         # hot path; nothing caches or re-dispatches this jit.
         comp = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                        donate_argnums=cell.donate_argnums
                        ).lower(*cell.args).compile()
     cost = comp.cost_analysis()
-    if isinstance(cost, list):  # jax<=0.4.x CPU returns [dict]
-        cost = cost[0] if cost else {}
     print("cost_analysis flops:", cost.get("flops"))
     print("cost_analysis bytes:", cost.get("bytes accessed"))
     hlo = comp.as_text()

@@ -66,6 +66,8 @@ def main():
                     help="GNN: max batch nodes per request; counts mixed")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     family = get_arch(args.arch).family
     assert family in ("lm", "gnn"), f"no serving path for family {family!r}"

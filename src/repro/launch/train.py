@@ -131,6 +131,8 @@ def main():
     ap.add_argument("--fail-at", type=int, default=None,
                     help="inject a crash at this step (chaos drill)")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     family = get_arch(args.arch).family
     runner = {"gnn": run_gnn, "lm": run_lm, "recsys": run_recsys}[family]
     _, _, history = runner(args.arch, args.steps, args.smoke, args.ckpt_dir,

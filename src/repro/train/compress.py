@@ -13,9 +13,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.dist.compat import shard_map
 
 
 def quantize_ef(g: jnp.ndarray, err: jnp.ndarray

@@ -7,7 +7,8 @@ from conftest import run_under_devices
 def test_sharded_decode_matches_unsharded():
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         from repro.dist.collectives import sharded_decode_attention
         from repro.models.attention import decode_attention
         b, h, hkv, s, dh = 2, 4, 2, 64, 16
@@ -17,7 +18,7 @@ def test_sharded_decode_matches_unsharded():
         vc = jax.random.normal(k3, (b, hkv, s, dh))
         clen = jnp.full((b,), 48, jnp.int32)
         want = decode_attention(q, kc, vc, clen)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda q, kc, vc, c: sharded_decode_attention(
                 mesh, q, kc, vc, c))(q, kc, vc, clen)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -31,7 +32,8 @@ def test_seq_sharded_decode_matches_unsharded():
     """Flash-decoding: sequence-sharded cache, LSE-combined across shards."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         from repro.dist.collectives import sharded_decode_attention_seq
         from repro.models.attention import decode_attention
         b, h, hkv, s, dh = 2, 4, 2, 128, 16
@@ -41,7 +43,7 @@ def test_seq_sharded_decode_matches_unsharded():
         vc = jax.random.normal(k3, (b, hkv, s, dh))
         clen = jnp.array([100, 17], jnp.int32)  # straddles shard boundaries
         want = decode_attention(q, kc, vc, clen)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda q, kc, vc, c: sharded_decode_attention_seq(
                 mesh, q, kc, vc, c))(q, kc, vc, clen)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -57,7 +59,8 @@ def test_seq_sharded_decode_heads_on_model_axis_with_int8():
     matches the dense reference."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         from repro.dist.collectives import sharded_decode_attention_seq
         from repro.models.attention import decode_attention, quantize_kv
         b, h, hkv, s, dh = 2, 8, 4, 128, 16
@@ -69,7 +72,7 @@ def test_seq_sharded_decode_heads_on_model_axis_with_int8():
         vq, vs = quantize_kv(vc)
         clen = jnp.array([100, 17], jnp.int32)
         want = decode_attention(q, kq, vq, clen, k_scale=ks, v_scale=vs)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda *a: sharded_decode_attention_seq(
                 mesh, *a[:4], k_scale=a[4], v_scale=a[5]))(
                 q, kq, vq, clen, ks, vs)
@@ -87,7 +90,8 @@ def test_long_context_decode_step_with_seq_sharded_attn():
     out = run_under_devices("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         from repro.configs import get_config
         from repro.dist.collectives import seq_sharded_decode_attn_fn
         from repro.dist.sharding import lm_cache_shardings
@@ -103,7 +107,7 @@ def test_long_context_decode_step_with_seq_sharded_attn():
             lambda p, c, t, q: lm_decode_step(cfg, p, c, t, q)
         )(params, cache, tok, pos)
         attn = seq_sharded_decode_attn_fn(mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             c_sh = lm_cache_shardings(mesh, cache, seq_sharded=True)
             cache_s = jax.device_put(cache, c_sh)
             got_tok, got_cache = jax.jit(
@@ -127,7 +131,8 @@ def test_long500k_cell_wires_seq_sharded_collective():
     decode cell (LSE-combine collective) with consistent spec trees."""
     out = run_under_devices("""
         import jax
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         from repro.launch.steps import build_cell
         cell = build_cell("gemma2-9b", "long_500k", mesh)
         assert not cell.skipped, cell.skipped
@@ -146,7 +151,8 @@ def test_lm_train_cell_runs_on_tiny_mesh():
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
         import dataclasses
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         from repro.configs import get_config
         from repro.dist.sharding import lm_param_shardings
         from repro.models.transformer import lm_init, lm_loss
@@ -155,7 +161,7 @@ def test_lm_train_cell_runs_on_tiny_mesh():
         cfg = get_config("granite-moe-1b-a400m", smoke=True)
         cfg = dataclasses.replace(cfg, n_layers=2).padded(2)
         params = lm_init(cfg, jax.random.PRNGKey(0))
-        with mesh:
+        with jax.set_mesh(mesh):
             p_sh = lm_param_shardings(mesh, params, fsdp=True,
                                       n_experts=cfg.moe_experts)
             params = jax.device_put(params, p_sh)
@@ -185,7 +191,8 @@ def test_lm_train_cell_runs_on_tiny_mesh():
 def test_gnn_cell_sharded_executes():
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         from repro.configs import get_config
         from repro.models.gnn import GraphBatch, gnn_init, gnn_loss
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -200,7 +207,7 @@ def test_gnn_cell_sharded_executes():
                            jnp.ones((n,), bool))
         params = gnn_init(cfg, jax.random.PRNGKey(4), d_in=f, n_classes=3)
         loss_ref = gnn_loss(cfg, params, batch)
-        with mesh:
+        with jax.set_mesh(mesh):
             sh = GraphBatch(
                 NamedSharding(mesh, P("data")),
                 NamedSharding(mesh, P("data")),
@@ -220,7 +227,8 @@ def test_preprocess_pipeline_sharded_executes():
     equals the single-device run."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         from repro.core import COO, EngineConfig, preprocess, random_coo
         from jax.sharding import NamedSharding, PartitionSpec as P
         rng = np.random.default_rng(0)
@@ -230,7 +238,7 @@ def test_preprocess_pipeline_sharded_executes():
         key = jax.random.PRNGKey(0)
         cfg = EngineConfig(w_upe=256, n_upe=0)
         sub_ref = preprocess(coo, bn, (4, 3), key, cfg)
-        with mesh:
+        with jax.set_mesh(mesh):
             coo_s = COO(
                 dst=jax.device_put(coo.dst, NamedSharding(mesh, P("data"))),
                 src=jax.device_put(coo.src, NamedSharding(mesh, P("data"))),
@@ -250,7 +258,8 @@ def test_build_cell_all_archs_construct():
     not require devices: validate tree structure matching."""
     out = run_under_devices("""
         import jax
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.configs import all_cells
         from repro.launch.steps import build_cell
         n = 0

@@ -3,6 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import make_mesh
 from repro.dist.hints import (_current_mesh, current_layout, layout,
                               mesh_info, shard_hint, suspend_hints)
 
@@ -22,8 +23,8 @@ def test_shard_hint_rank_mismatch_is_identity():
 
 def test_layout_nesting_restores_previous_mesh():
     assert _current_mesh() is None
-    m1 = jax.make_mesh((1, 1), ("data", "model"))
-    m2 = jax.make_mesh((1,), ("data",))
+    m1 = make_mesh((1, 1), ("data", "model"))
+    m2 = make_mesh((1,), ("data",))
     with layout(m1):
         assert _current_mesh() is m1
         assert current_layout() == "tp"
@@ -37,8 +38,8 @@ def test_layout_nesting_restores_previous_mesh():
 
 
 def test_layout_by_name_inherits_ambient_mesh():
-    m = jax.make_mesh((1, 1), ("data", "model"))
-    with m:
+    m = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(m):
         with layout("dp_only"):
             assert current_layout() == "dp_only"
             assert _current_mesh() is not None
@@ -46,7 +47,7 @@ def test_layout_by_name_inherits_ambient_mesh():
 
 
 def test_layout_restores_on_exception():
-    m = jax.make_mesh((1, 1), ("data", "model"))
+    m = make_mesh((1, 1), ("data", "model"))
     try:
         with layout(m):
             raise RuntimeError("boom")
@@ -62,7 +63,7 @@ def test_mesh_info_without_mesh():
 
 
 def test_mesh_info_tp_vs_dp_only():
-    m = jax.make_mesh((1, 1), ("data", "model"))
+    m = make_mesh((1, 1), ("data", "model"))
     with layout(m):
         dp, msz = mesh_info()
         assert dp == ("data",)
@@ -73,7 +74,7 @@ def test_mesh_info_tp_vs_dp_only():
 
 
 def test_shard_hint_values_unchanged_under_mesh():
-    m = jax.make_mesh((1, 1), ("data", "model"))
+    m = make_mesh((1, 1), ("data", "model"))
     x = jnp.arange(8.0).reshape(2, 4)
     with layout(m):
         y = shard_hint(x, "dp", "model")

@@ -28,9 +28,10 @@ def test_checkpoint_restores_on_different_mesh(tmp_path):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train import checkpoint as ckpt
         from repro.train.optim import AdamWConfig, adamw_init, adamw_update
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         params = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
-        with mesh:
+        with jax.set_mesh(mesh):
             params = jax.device_put(params, {{"w": NamedSharding(
                 mesh, P("data", None))}})
             opt = adamw_init(params)
@@ -52,12 +53,13 @@ def test_checkpoint_restores_on_different_mesh(tmp_path):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train import checkpoint as ckpt
         from repro.train.optim import AdamWConfig, adamw_init, adamw_update
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         like_p = {{"w": jnp.zeros((8, 8), jnp.float32)}}
         like_o = adamw_init(like_p)
         sh = {{"w": NamedSharding(mesh, P("data", None))}}
         sh_o = {{"m": sh, "v": sh, "step": NamedSharding(mesh, P())}}
-        with mesh:
+        with jax.set_mesh(mesh):
             (params, opt), meta = ckpt.restore(
                 {ck!r}, 3, (like_p, like_o), shardings=(sh, sh_o))
             assert meta["step"] == 3

@@ -11,7 +11,8 @@ def test_shard_preprocess_bit_identical_to_single_device():
     (ptr/idx/order) for two graph sizes × two EngineConfigs."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         from repro.core import COO, EngineConfig, preprocess, random_coo
         from repro.engine.shard import jit_shard_preprocess
         rng = np.random.default_rng(0)
@@ -25,7 +26,7 @@ def test_shard_preprocess_bit_identical_to_single_device():
             key = jax.random.PRNGKey(0)
             for cfg in cfgs:
                 ref = preprocess(coo, bn, (4, 3), key, cfg)
-                with mesh:
+                with jax.set_mesh(mesh):
                     got = jit_shard_preprocess(mesh)(
                         coo, bn, fanouts=(4, 3), key=key, cfg=cfg)
                 tag = f"{n}/{e}/{cfg.key}"
@@ -45,7 +46,8 @@ def test_shard_convert_matches_single_device():
     """Ordering + Reshaping alone: sharded CSC == single-device CSC."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         from repro.core import COO, EngineConfig, convert, random_coo
         from repro.engine.shard import shard_convert
         rng = np.random.default_rng(3)
@@ -53,7 +55,7 @@ def test_shard_convert_matches_single_device():
         coo = COO.from_arrays(dst, src, 300, capacity=4096)
         cfg = EngineConfig(w_upe=256, n_upe=0)
         ref = convert(coo, cfg)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda c: shard_convert(mesh, c, cfg))(coo)
         np.testing.assert_array_equal(np.asarray(got.ptr),
                                       np.asarray(ref.ptr))
@@ -69,7 +71,8 @@ def test_shard_preprocess_on_2d_mesh_dp_axes_only():
     still matches the single-device pipeline exactly."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         from repro.core import COO, EngineConfig, preprocess, random_coo
         from repro.engine.shard import jit_shard_preprocess
         rng = np.random.default_rng(7)
@@ -79,7 +82,7 @@ def test_shard_preprocess_on_2d_mesh_dp_axes_only():
         key = jax.random.PRNGKey(1)
         cfg = EngineConfig(w_upe=128, n_upe=0)
         ref = preprocess(coo, bn, (3, 2), key, cfg)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jit_shard_preprocess(mesh)(
                 coo, bn, fanouts=(3, 2), key=key, cfg=cfg)
         np.testing.assert_array_equal(np.asarray(got.order),
@@ -96,7 +99,8 @@ def test_shard_sort_falls_back_on_non_pow2_device_count():
     must fall back to the single-device path, not crash at trace time."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((6,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((6,), ("data",))
         from repro.core import COO, EngineConfig, preprocess, random_coo
         from repro.engine.shard import shard_preprocess
         rng = np.random.default_rng(11)
@@ -105,7 +109,7 @@ def test_shard_sort_falls_back_on_non_pow2_device_count():
         bn = jnp.arange(8, dtype=jnp.int32)
         key = jax.random.PRNGKey(2)
         cfg = EngineConfig(w_upe=256, n_upe=0)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(lambda c, b, k: shard_preprocess(
                 mesh, c, b, (3, 2), k, cfg))(coo, bn, key)
         ref = preprocess(coo, bn, (3, 2), key, cfg)
@@ -123,7 +127,8 @@ def test_preprocess_cells_construct_with_shard_route():
     specs/shardings trees stay structurally consistent."""
     out = run_under_devices("""
         import jax
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         from repro.launch.steps import preprocess_cells
         cells = preprocess_cells(mesh)
         keys = [c.key for c in cells]
@@ -145,7 +150,8 @@ def test_shard_convert_strategy_equality():
     sorts; cross-device merge rounds unchanged)."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         from repro.core import COO, EngineConfig, convert, random_coo
         from repro.engine.shard import shard_convert
         rng = np.random.default_rng(13)
@@ -158,7 +164,7 @@ def test_shard_convert_strategy_equality():
         for strat, use_pallas in cases:
             cfg = EngineConfig(w_upe=256, n_upe=0, sort_strategy=strat,
                                use_pallas=use_pallas)
-            with mesh:
+            with jax.set_mesh(mesh):
                 got = jax.jit(lambda c, cfg=cfg: shard_convert(
                     mesh, c, cfg))(coo)
             tag = (strat, use_pallas)
@@ -178,7 +184,8 @@ def test_shard_preprocess_reindex_strategy_equality():
     shard_map'd Ordering without divergence."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         from repro.core import COO, EngineConfig, preprocess, random_coo
         from repro.engine.shard import jit_shard_preprocess
         rng = np.random.default_rng(17)
@@ -193,7 +200,7 @@ def test_shard_preprocess_reindex_strategy_equality():
         for strat, use_pallas in cases:
             cfg = EngineConfig(w_upe=256, n_upe=0, reindex_strategy=strat,
                                use_pallas=use_pallas)
-            with mesh:
+            with jax.set_mesh(mesh):
                 got = jit_shard_preprocess(mesh)(
                     coo, bn, fanouts=(4, 3), key=key, cfg=cfg)
             tag = (strat, use_pallas)

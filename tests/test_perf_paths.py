@@ -166,8 +166,6 @@ def test_chunked_ladder_round_count_matches_costmodel(fan_in):
 
 def _bytes_accessed(jitted, *args) -> float:
     ca = jitted.lower(*args).compile().cost_analysis()
-    if isinstance(ca, list):  # pre-0.5 jax returns one dict per partition
-        ca = ca[0]
     return float(ca["bytes accessed"])
 
 
@@ -256,12 +254,13 @@ def test_moe_sharded_dispatch_matches_global_when_no_drops():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         from repro.models.moe import moe_apply, moe_apply_local, moe_init
         p = moe_init(jax.random.PRNGKey(0), 16, 32, 4)
         x = jax.random.normal(jax.random.PRNGKey(1), (32, 16))
         y_ref, _ = moe_apply(p, x, top_k=2, capacity_factor=8.0)
-        with mesh:
+        with jax.set_mesh(mesh):
             y, _ = jax.jit(lambda p, x: moe_apply_local(
                 p, x, top_k=2, capacity_factor=8.0))(p, x)
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),

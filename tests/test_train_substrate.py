@@ -171,7 +171,8 @@ def test_compressed_psum_matches_mean_under_shard_map():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.train.compress import make_compressed_allreduce
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         g = jnp.arange(32, dtype=jnp.float32).reshape(4, 8) / 7.3
         e = jnp.zeros_like(g)
         fn = make_compressed_allreduce(mesh, {"g": P("pod", None)})
