@@ -1,0 +1,11 @@
+"""Device time of the convert's CSC pointer build (scope
+``convert.pointer``) per execution of ``jit_convert``, in ms, over
+outermost operations (``bench/scopes.py``). Layer: convert."""
+from bench import scopes
+
+
+def read(r):
+    if not scopes.names:
+        return None
+    return scopes.device_ms(r, r"^jit_convert\b",
+                            scopes.names.CONVERT_POINTER)

@@ -1,0 +1,12 @@
+"""Device time of the serving step's feature gather and GraphSAGE forward
+(scopes ``serve.gather`` and ``serve.forward``) per execution of
+``jit_step``, in ms, over outermost operations (``bench/scopes.py``).
+Layer: serve step."""
+from bench import scopes
+
+
+def read(r):
+    if not scopes.names:
+        return None
+    return scopes.device_ms(r, r"^jit_step\b", scopes.names.SERVE_GATHER,
+                            scopes.names.SERVE_FORWARD)

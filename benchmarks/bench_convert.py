@@ -5,8 +5,7 @@ median wall-clock per call for the graph-conversion paths at three scales —
 the shape ``sample_subgraph`` re-converts every step (16k), a mid graph
 (131k) and a large graph (1M edges) — comparing the three ``sort_strategy``
 values, the Table-I auto dispatch, the two-pass key scheme and the XLA
-comparison-sort baseline, plus a per-phase (sort / pointer / reindex)
-breakdown of the dispatched path. The headline series is
+comparison-sort baseline. The headline series is
 ``speedup_packed_vs_xla``: the auto-dispatched engine path over the XLA
 lexsort baseline, which the chunked-merge ladder used to LOSE at scale
 (0.71× at 131k in PR 3). The dispatch wins it back twice over: the
@@ -25,14 +24,14 @@ chunked path; from PR 5 they alias the auto-DISPATCHED engine path
 the packed key scheme, the VID space forces two-pass). Compare across
 PRs on ``auto_us``/strategy columns, not on the legacy names.
 
-Trajectory note (PR 7): the ``reindex_us`` phase is the SERVING-critical
-number — Ordering/Reshaping run once per graph, but the Reindexing
-primitive re-runs on every sampled subgraph, so its tail bounds
-steady-state serve throughput. PR 7 rebuilt it as a fused SCR epilogue
-(ONE shared VID sort + rank-arithmetic numbering + unrolled rename
-gathers, dispatched per ``reindex_strategy``), and the
-``subgraph_reconvert`` case times the full ``sample_subgraph`` hot path
-end-to-end per reindex strategy, recording what ``auto`` picked.
+The Reindexing primitive re-runs on every sampled subgraph, so its tail
+bounds steady-state serve throughput; it is a fused SCR epilogue (ONE
+shared VID sort + rank-arithmetic numbering + unrolled rename gathers,
+dispatched per ``reindex_strategy``), and the ``subgraph_reconvert`` case
+times the full ``sample_subgraph`` hot path end-to-end per reindex
+strategy, recording what ``auto`` picked. Per-stage device time comes
+from the named scopes in a chip trace (``bench/scopes.py``), not from
+this CPU proxy.
 
 Trajectory note (PR 10): the ``delta_update`` case times the incremental
 conversion path — ``apply_delta`` splicing an insert/delete batch into a
@@ -69,9 +68,6 @@ from repro.core import (EdgeDelta, EngineConfig, Workload, apply_delta,
 from repro.core.costmodel import (digit_pass_count, reindex_query_count,
                                   sample_edge_capacity, sample_vid_capacity)
 from repro.core.graph import next_pow2
-from repro.core.ordering import edge_ordering
-from repro.core.reindexing import build_reindex_map, reindex_edges
-from repro.core.reshaping import build_pointer_array
 
 from .common import emit, make_graph, time_fn
 
@@ -218,45 +214,6 @@ def _jit_convert(cfg: EngineConfig):
     return jax.jit(partial(convert, cfg=cfg))
 
 
-def _phase_times(coo, cfg: EngineConfig, strategy: str, iters: int) -> dict:
-    """Per-phase breakdown of the dispatched path: sort (Ordering),
-    pointer (Reshaping), reindex (the Reindexing primitive at batch
-    scale — it runs per sampled SUBGRAPH, not per graph, which makes
-    ``reindex_us`` the serving-critical phase). The reindex row times
-    the PR-7 fused SCR epilogue at the strategy the cost model resolves
-    for this query count (recorded as ``reindex_strategy``)."""
-    sort_fn = jax.jit(partial(
-        edge_ordering, chunk=min(cfg.w_upe, coo.capacity),
-        radix_bits=cfg.radix_bits, map_batch=cfg.n_upe,
-        mode=cfg.sort_mode, strategy=strategy, fan_in=cfg.merge_fan_in))
-    t_sort = time_fn(sort_fn, coo, iters=iters, warmup=2)
-    sorted_coo = jax.block_until_ready(sort_fn(coo))
-    ptr_fn = jax.jit(partial(build_pointer_array, n_nodes=coo.n_nodes))
-    t_ptr = time_fn(ptr_fn, sorted_coo.dst, iters=iters, warmup=2)
-    rng = np.random.default_rng(0)
-    vids = jax.numpy.asarray(
-        rng.integers(0, coo.n_nodes, 8192).astype(np.int32))
-    e_dst = jax.numpy.asarray(
-        rng.integers(0, coo.n_nodes, 8192).astype(np.int32))
-    e_src = jax.numpy.asarray(
-        rng.integers(0, coo.n_nodes, 8192).astype(np.int32))
-
-    cap = int(vids.shape[0])
-    r_strat = resolve_reindex_strategy(
-        cfg, reindex_query_count(cap, int(e_dst.shape[0])), cap)
-
-    @jax.jit
-    def reindex_fn(vids, e_dst, e_src):
-        rmap = build_reindex_map(vids, vid_bound=int(coo.n_nodes),
-                                 strategy=r_strat)
-        return reindex_edges(rmap, e_dst, e_src,
-                             n_nodes_cap=vids.shape[0])
-
-    t_reidx = time_fn(reindex_fn, vids, e_dst, e_src, iters=iters, warmup=2)
-    return {"sort_us": t_sort, "pointer_us": t_ptr, "reindex_us": t_reidx,
-            "reindex_strategy": r_strat}
-
-
 def _subgraph_reconvert_case(smoke: bool, iters: int) -> dict:
     """The serving hot path end-to-end: ``sample_subgraph`` re-converts a
     fresh subgraph every step (select → reindex → sub-sort → pointers).
@@ -342,7 +299,6 @@ def run(smoke: bool = False) -> dict:
         speedup_xla = rows["xla"] / rows["auto"]
         emit(f"convert/{label}/speedup_packed_vs_xla", speedup_xla,
              f"auto={strategy_auto}")
-        phases = _phase_times(coo, base, strategy_auto, iters)
         results["cases"][label] = {
             "n_edges": n_edges,
             "n_nodes": int(coo.n_nodes),
@@ -360,7 +316,6 @@ def run(smoke: bool = False) -> dict:
             "xla_us": rows["xla"],
             "speedup_packed_vs_two_pass": speedup_two,
             "speedup_packed_vs_xla": speedup_xla,
-            "phases": phases,
         }
         if smoke:
             _assert_structure(coo, base, jits, results["cases"][label])

@@ -1,7 +1,7 @@
 """Benchmark harness entry point — one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows. Pass module names to run a
-subset: ``python -m benchmarks.run fig6 fig18``. ``--smoke`` shrinks any
+subset: ``python -m benchmarks.run fig5 fig18``. ``--smoke`` shrinks any
 suite whose ``run`` accepts a ``smoke`` flag to CI-sized cases with
 structural asserts instead of wall-clock gates (the bench-smoke CI job
 runs ``python -m benchmarks.run convert --smoke``); in smoke mode a
@@ -18,14 +18,13 @@ def main() -> None:
     jax.config.update("jax_platform_name", "cpu")
 
     from . import (bench_convert, bench_serve, fig5_preproc_fraction,
-                   fig6_breakdown, fig10_serialization, fig18_end2end,
-                   fig22_reconfig, fig24_costmodel, fig25_sensitivity,
-                   fig_engine_overlap, roofline)
+                   fig10_serialization, fig18_end2end, fig22_reconfig,
+                   fig24_costmodel, fig25_sensitivity, fig_engine_overlap,
+                   roofline)
     suites = {
         "convert": bench_convert.run,  # emits BENCH_convert.json
         "serve": bench_serve.run,  # emits BENCH_serve.json
         "fig5": fig5_preproc_fraction.run,
-        "fig6": fig6_breakdown.run,
         "fig10": fig10_serialization.run,
         "fig18": fig18_end2end.run,
         "fig22": fig22_reconfig.run,

@@ -15,6 +15,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
+
 from .delta import EdgeDelta, delta_merge, rebuild_coo
 from .graph import COO, CSC, SENTINEL, Subgraph, next_pow2, pad_to
 from .ordering import edge_ordering, edge_ordering_xla, stable_sort_by_key
@@ -81,18 +83,22 @@ def convert(coo: COO, cfg: EngineConfig | None = None,
     count_fn = count_fn or k_count
     w = Workload(n=coo.n_nodes, e=coo.capacity)
     strategy = resolve_sort_strategy(cfg, w)
-    sorted_coo = edge_ordering(coo, chunk=min(cfg.w_upe, coo.capacity),
-                               radix_bits=cfg.radix_bits,
-                               map_batch=cfg.n_upe,
-                               chunk_sort_fn=chunk_sort_fn,
-                               merge_fn=merge_fn, mode=cfg.sort_mode,
-                               strategy=strategy, fan_in=cfg.merge_fan_in,
-                               digit_pass_fn=digit_pass_fn)
+    with jax.named_scope(scopes.CONVERT_ORDERING):
+        sorted_coo = edge_ordering(coo, chunk=min(cfg.w_upe, coo.capacity),
+                                   radix_bits=cfg.radix_bits,
+                                   map_batch=cfg.n_upe,
+                                   chunk_sort_fn=chunk_sort_fn,
+                                   merge_fn=merge_fn, mode=cfg.sort_mode,
+                                   strategy=strategy,
+                                   fan_in=cfg.merge_fan_in,
+                                   digit_pass_fn=digit_pass_fn)
     # pointer build = SCR epilogue: fused (statically unrolled rank
     # rounds, Pallas tiles when routed) exactly where the model prices it
     ptr_fused = pointer_reindex_strategy(cfg, w) == "fused"
-    return data_reshaping(sorted_coo, count_fn=count_fn, unroll=ptr_fused,
-                          rank_fn=k_rank if ptr_fused else None)
+    with jax.named_scope(scopes.CONVERT_POINTER):
+        return data_reshaping(sorted_coo, count_fn=count_fn,
+                              unroll=ptr_fused,
+                              rank_fn=k_rank if ptr_fused else None)
 
 
 def apply_delta(csc: CSC, delta: EdgeDelta, cfg: EngineConfig | None = None,
@@ -120,7 +126,13 @@ def apply_delta(csc: CSC, delta: EdgeDelta, cfg: EngineConfig | None = None,
     (``engine.service.PreprocService.apply_delta`` grows the bucket on
     overflow — a traced count cannot raise here).
     """
-    cfg = cfg or EngineConfig()
+    with jax.named_scope(scopes.DELTA_APPLY):
+        return _apply_delta(csc, delta, cfg or EngineConfig(), mode,
+                            out_capacity)
+
+
+def _apply_delta(csc: CSC, delta: EdgeDelta, cfg: EngineConfig, mode: str,
+                 out_capacity: int | None) -> CSC:
     k_sort, k_count, merge_fn, digit_pass_fn, k_rank, _ = kernel_fns(cfg)
     e_cap = csc.idx.shape[0]
     d_cap = delta.capacity
@@ -190,8 +202,9 @@ def sample_subgraph(csc: CSC, batch_nodes: jnp.ndarray,
      k_rename) = kernel_fns(cfg)
     chunk_sort_fn = chunk_sort_fn or k_sort
     count_fn = count_fn or k_count
-    nodes, e_dst, e_src = sample_khop(
-        csc, batch_nodes, fanouts, key, selection=cfg.selection)
+    with jax.named_scope(scopes.SAMPLE_SELECT):
+        nodes, e_dst, e_src = sample_khop(
+            csc, batch_nodes, fanouts, key, selection=cfg.selection)
     n_cap = nodes.shape[0]
     # Reindexing rides the spine: ONE shared strategy-dispatched sort of
     # the collected VID list (same reduction machinery as the Ordering,
@@ -213,30 +226,32 @@ def sample_subgraph(csc: CSC, batch_nodes: jnp.ndarray,
     r_strat = resolve_reindex_strategy(
         cfg, reindex_query_count(n_cap, e_dst.shape[0]), n_cap)
     r_fused = r_strat == "fused"
-    rmap = build_reindex_map(nodes, vid_bound=csc.n_nodes,
-                             strategy=r_strat, sort_fn=reindex_sort_fn,
-                             rank_fn=k_rank if r_fused else None,
-                             rename_fn=k_rename if r_fused else None)
-    sub_coo_raw = reindex_edges(rmap, e_dst, e_src, n_nodes_cap=n_cap)
+    with jax.named_scope(scopes.SAMPLE_REINDEX):
+        rmap = build_reindex_map(nodes, vid_bound=csc.n_nodes,
+                                 strategy=r_strat, sort_fn=reindex_sort_fn,
+                                 rank_fn=k_rank if r_fused else None,
+                                 rename_fn=k_rename if r_fused else None)
+        sub_coo_raw = reindex_edges(rmap, e_dst, e_src, n_nodes_cap=n_cap)
     # pad edge buffers to pow2 for the chunked sorter
     e_cap = next_pow2(sub_coo_raw.dst.shape[0])
-    sub_coo = COO(
-        dst=jnp.pad(sub_coo_raw.dst, (0, e_cap - sub_coo_raw.dst.shape[0]),
-                    constant_values=int(SENTINEL)),
-        src=jnp.pad(sub_coo_raw.src, (0, e_cap - sub_coo_raw.src.shape[0]),
-                    constant_values=int(SENTINEL)),
-        n_edges=sub_coo_raw.n_edges, n_nodes=n_cap)
     strategy = resolve_sort_strategy(cfg, Workload(n=n_cap, e=e_cap))
-    sub_sorted = edge_ordering(sub_coo, chunk=min(cfg.w_upe, e_cap),
-                               radix_bits=cfg.radix_bits,
-                               chunk_sort_fn=chunk_sort_fn,
-                               merge_fn=merge_fn, mode=cfg.sort_mode,
-                               strategy=strategy, fan_in=cfg.merge_fan_in,
-                               digit_pass_fn=digit_pass_fn)
     sub_ptr_fused = resolve_reindex_strategy(cfg, n_cap + 1, e_cap) == "fused"
-    sub_csc = data_reshaping(sub_sorted, count_fn=count_fn,
-                             unroll=sub_ptr_fused,
-                             rank_fn=k_rank if sub_ptr_fused else None)
+    with jax.named_scope(scopes.SAMPLE_RECONVERT):
+        pad = (0, e_cap - sub_coo_raw.dst.shape[0])
+        sub_coo = COO(
+            dst=jnp.pad(sub_coo_raw.dst, pad, constant_values=int(SENTINEL)),
+            src=jnp.pad(sub_coo_raw.src, pad, constant_values=int(SENTINEL)),
+            n_edges=sub_coo_raw.n_edges, n_nodes=n_cap)
+        sub_sorted = edge_ordering(sub_coo, chunk=min(cfg.w_upe, e_cap),
+                                   radix_bits=cfg.radix_bits,
+                                   chunk_sort_fn=chunk_sort_fn,
+                                   merge_fn=merge_fn, mode=cfg.sort_mode,
+                                   strategy=strategy,
+                                   fan_in=cfg.merge_fan_in,
+                                   digit_pass_fn=digit_pass_fn)
+        sub_csc = data_reshaping(sub_sorted, count_fn=count_fn,
+                                 unroll=sub_ptr_fused,
+                                 rank_fn=k_rank if sub_ptr_fused else None)
     return Subgraph(csc=sub_csc, order=rmap.order, n_sub_nodes=rmap.n_unique)
 
 

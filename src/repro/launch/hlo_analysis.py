@@ -15,6 +15,8 @@ import dataclasses
 import re
 from collections import defaultdict
 
+from repro.scopes import DEVICE_SCOPES
+
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
@@ -267,6 +269,49 @@ def op_counts(hlo_text: str) -> dict[str, int]:
             if m:
                 counts[m.group(1)] += 1
     return dict(counts)
+
+
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def op_scope(op_name: str, scopes=DEVICE_SCOPES) -> str | None:
+    """The scope among ``scopes`` on an ``op_name`` path, or None.
+
+    A path component names a scope bare or as the argument of the
+    transforms around it: ``jit(step)/vmap(sample.select)/while`` carries
+    ``sample.select``. The program's scopes never nest, so a path that
+    carries two of them raises ValueError.
+
+    >>> op_scope("jit(f)/vmap(serve.forward)/while/body/add")
+    'serve.forward'
+    >>> op_scope("jit(f)/reduce_sum") is None
+    True
+    """
+    found = []
+    for part in op_name.split("/"):
+        while part.endswith(")") and "(" in part:
+            part = part[part.find("(") + 1:-1]
+        if part in scopes and part not in found:
+            found.append(part)
+    if len(found) > 1:
+        raise ValueError(f"op_name {op_name!r} carries the scopes {found}")
+    return found[0] if found else None
+
+
+def op_scopes(hlo_text: str, scopes=DEVICE_SCOPES) -> dict[str, str | None]:
+    """Instruction name → the scope among ``scopes`` its ``op_name``
+    metadata carries (None: unscoped), for every instruction of every
+    computation of a compiled program's text. Profiler op events are
+    named ``%<instruction> = ...``, so the map joins them to the trace."""
+    out: dict[str, str | None] = {}
+    for lines in _split_computations(hlo_text).values():
+        for line in lines:
+            m = _INSTR_RE.match(line)
+            if m:
+                n = _OP_NAME_RE.search(line)
+                out[m.group(1)] = op_scope(n.group(1), scopes) if n else None
+    return out
 
 
 def loop_aware_stats(hlo_text: str) -> LoopAwareStats:

@@ -22,6 +22,8 @@ import threading
 import jax
 import numpy as np
 
+from repro import scopes
+
 from .queue import RequestQueue
 from .request import Request, RequestState
 
@@ -47,10 +49,11 @@ def _produce(rq: RequestQueue, out: _queue.Queue, stop: threading.Event,
                 if rq.closed and len(rq) == 0:
                     return  # stream over; `finished` set in the finally
                 continue
-            row = np.full((prompt_cap,), pad_value, np.int32)
-            row[:len(req.prompt)] = np.asarray(req.prompt, np.int32)
-            if device_put:
-                row = jax.device_put(row)
+            with jax.profiler.TraceAnnotation(scopes.FEED):
+                row = np.full((prompt_cap,), pad_value, np.int32)
+                row[:len(req.prompt)] = np.asarray(req.prompt, np.int32)
+                if device_put:
+                    row = jax.device_put(row)
             req.state = RequestState.PREPARED
             item = PreparedAdmission(req, row, len(req.prompt))
             while not stop.is_set():

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import scopes
 from repro.core import pipeline
 from repro.core.costmodel import EngineConfig
 from repro.core.delta import EdgeDelta
@@ -61,11 +62,13 @@ def build_slot_fn(gcfg: GNNConfig, fanouts: tuple[int, ...], seed_cap: int,
     def slot_fn(bundle, seeds, key):
         sub = pipeline.sample_subgraph(bundle["csc"], seeds, fanouts, key,
                                        cfg)
-        batch = subgraph_batch(sub, bundle["features"])
-        out = gnn_apply(gcfg, bundle["gnn"], batch)
-        # first-occurrence numbering: the request's seeds own the first
-        # seed_cap new VIDs, so its predictions are the first rows
-        return jnp.argmax(out[:seed_cap], axis=-1).astype(jnp.int32)
+        with jax.named_scope(scopes.SERVE_GATHER):
+            batch = subgraph_batch(sub, bundle["features"])
+        with jax.named_scope(scopes.SERVE_FORWARD):
+            out = gnn_apply(gcfg, bundle["gnn"], batch)
+            # first-occurrence numbering: the request's seeds own the
+            # first seed_cap new VIDs, so its predictions are the first rows
+            return jnp.argmax(out[:seed_cap], axis=-1).astype(jnp.int32)
 
     return slot_fn
 

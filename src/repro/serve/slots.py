@@ -33,8 +33,12 @@ import dataclasses
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+from repro import scopes
 
 from .feeder import AdmissionFeeder
 from .queue import RequestQueue
@@ -49,6 +53,8 @@ class ServeStats:
     retired: int = 0
     tokens_processed: int = 0  # payload units touched, active slots only
     tokens_generated: int = 0  # tokens (LM) / predictions (GNN) emitted
+    window_waits: int = 0  # admission windows opened
+    window_seated: int = 0  # requests those windows seated
 
 
 def deactivate_update(state, slot):
@@ -115,6 +121,13 @@ class SlotEngineBase:
                 "jax.jit cache introspection (_cache_size) is unavailable "
                 "on this JAX version") from e
 
+    def step_hlo_text(self) -> str:
+        """The compiled text of the slot step at the engine's current
+        params and state: what ``launch.hlo_analysis.op_scopes`` maps to
+        the program's named scopes. Compiles again (or loads from the
+        compile cache); call it outside any timed window."""
+        return self._step.lower(self.params, self.state).compile().as_text()
+
     # ------------------------------------------------------------ admission
     def _enqueue(self, prompt: list[int], max_new: int,
                  payload=None) -> Request:
@@ -173,12 +186,16 @@ class SlotEngineBase:
 
     def _apply_held(self, completed: list[Request]) -> None:
         prep, self._held_prep = self._held_prep, None
-        self._apply_control(prep)
         req = prep.request
+        with TraceAnnotation(scopes.UPDATE):
+            self._apply_control(prep)
+            if req.admit_t is None:
+                req.admit_t = time.perf_counter()
+            # finished once the updated state exists on the device, not
+            # when its program was dispatched
+            jax.block_until_ready(self.params)
+            req.finish_t = time.perf_counter()
         req.state = RequestState.FINISHED
-        if req.admit_t is None:
-            req.admit_t = time.perf_counter()
-        req.finish_t = time.perf_counter()
         self.stats.retired += 1
         completed.append(req)
 
@@ -213,11 +230,14 @@ class SlotEngineBase:
         return len(wave)
 
     def _process(self, emitted, completed: list[Request]) -> None:
-        for slot, req in self.scheduler.process(np.asarray(emitted)):
-            self.state = self._deactivate_fn(self.state, jnp.int32(slot))
-            self.stats.retired += 1
-            self.stats.tokens_generated += len(req.tokens_out)
-            completed.append(req)
+        with TraceAnnotation(scopes.WAIT):
+            jax.block_until_ready(emitted)
+        with TraceAnnotation(scopes.ROUTE):
+            for slot, req in self.scheduler.process(np.asarray(emitted)):
+                self.state = self._deactivate_fn(self.state, jnp.int32(slot))
+                self.stats.retired += 1
+                self.stats.tokens_generated += len(req.tokens_out)
+                completed.append(req)
 
     # ------------------------------------------------------------- the loop
     def run(self) -> list[Request]:
@@ -237,7 +257,9 @@ class SlotEngineBase:
                              device_put=self._feeder_device_put,
                              pad_value=self._pad_value) as feeder:
             while True:
-                self._try_admit(feeder)
+                if self.scheduler.has_free_slot:
+                    with TraceAnnotation(scopes.ADMIT):
+                        self._try_admit(feeder)
                 if (self._admit_window and self.scheduler.n_active
                         and self.scheduler.has_free_slot
                         and not feeder.done):
@@ -245,7 +267,11 @@ class SlotEngineBase:
                     # the last retirement wave would otherwise ride empty —
                     # give the feeder one bounded wait to fill the wave
                     # before paying for a step.
-                    self._try_admit(feeder, timeout=self._admit_window)
+                    with TraceAnnotation(scopes.ADMIT_WINDOW):
+                        seated = self._try_admit(
+                            feeder, timeout=self._admit_window)
+                    self.stats.window_waits += 1
+                    self.stats.window_seated += seated
                 if self.scheduler.n_active == 0:
                     if pending is not None:
                         self._process(pending, completed)
@@ -260,9 +286,12 @@ class SlotEngineBase:
                         continue
                     if feeder.done:
                         break
-                    self._try_admit(feeder, timeout=0.05)
+                    with TraceAnnotation(scopes.IDLE):
+                        self._try_admit(feeder, timeout=0.05)
                     continue
-                self.state, emitted = self._step(self.params, self.state)
+                with TraceAnnotation(scopes.STEP):
+                    self.state, emitted = self._step(self.params,
+                                                     self.state)
                 self.stats.steps += 1
                 self.stats.tokens_processed += self.scheduler.n_active
                 if self._pipeline_steps:

@@ -261,6 +261,30 @@ def test_interleaved_updates_and_inference_match_sequential_oracle():
                                   np.asarray(oracle_csc.idx))
 
 
+def test_update_finishes_once_its_csc_is_ready(monkeypatch):
+    """A streamed update's ``finish_t`` is stamped after the engine waited
+    for the params that hold the updated CSC, not at its dispatch."""
+    import time
+    eng = _make_engine(delta_cap=8)
+    real = jax.block_until_ready
+    ready = []
+
+    def spy(x):
+        out = real(x)
+        if isinstance(x, dict) and "csc" in x:
+            ready.append((time.perf_counter(), x["csc"]))
+        return out
+
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    upd = eng.submit_update([(1, 2), (3, 4)], [])
+    eng.close_submissions()
+    eng.run()
+    (t_ready, csc), = ready
+    assert csc is eng.params["csc"] and csc is not CSC_G
+    assert t_ready <= upd.finish_t
+    assert upd.admit_t <= upd.finish_t
+
+
 def test_submit_update_validates_size_and_vids():
     eng = _make_engine(delta_cap=8)
     with pytest.raises(ValueError):
