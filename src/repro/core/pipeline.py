@@ -43,6 +43,9 @@ def kernel_fns(cfg: EngineConfig):
     path can silently drop a knob. On a TPU backend, each kernel Mosaic
     refuses (``kernels.ops.MOSAIC_REFUSALS``) is a stand-in that raises
     ``NotImplementedError`` naming it when the route asks for it.
+    Not listed here: the windowed pointer kernel that the full-graph
+    :func:`convert` takes on a TPU whatever ``use_pallas`` says, where
+    none of these builds the pointers (:func:`pointer_array`).
     """
     if not cfg.use_pallas:
         return None, None, None, None, None, None
@@ -60,7 +63,7 @@ def kernel_fns(cfg: EngineConfig):
 
 
 def convert(coo: COO, cfg: EngineConfig | None = None,
-            count_fn=None, chunk_sort_fn=None) -> CSC:
+            count_fn=None, chunk_sort_fn=None, _windowed=None) -> CSC:
     """Graph conversion: Ordering + Reshaping under an engine config.
 
     ``cfg.sort_mode`` selects packed single-pass vs two-pass LSD Ordering
@@ -76,6 +79,12 @@ def convert(coo: COO, cfg: EngineConfig | None = None,
     sort / merge ladder / global digit passes / pointer build through the
     Pallas kernels (interpret mode on CPU; Mosaic on TPU). Explicit
     ``count_fn``/``chunk_sort_fn`` override.
+
+    Where no Pallas route builds the pointers, the program lowered for a
+    TPU builds them with the windowed SCR kernel
+    (``kernels/pointer_window.py``) and the one lowered for the CPU with
+    the rank search (:func:`pointer_array`); ``_windowed`` forces one
+    side, for tests.
     """
     cfg = cfg or EngineConfig()
     k_sort, k_count, merge_fn, digit_pass_fn, k_rank, _ = kernel_fns(cfg)
@@ -95,10 +104,35 @@ def convert(coo: COO, cfg: EngineConfig | None = None,
     # pointer build = SCR epilogue: fused (statically unrolled rank
     # rounds, Pallas tiles when routed) exactly where the model prices it
     ptr_fused = pointer_reindex_strategy(cfg, w) == "fused"
+    rank_fn = k_rank if ptr_fused else None
     with jax.named_scope(scopes.CONVERT_POINTER):
-        return data_reshaping(sorted_coo, count_fn=count_fn,
-                              unroll=ptr_fused,
-                              rank_fn=k_rank if ptr_fused else None)
+        if count_fn is not None or rank_fn is not None:
+            return data_reshaping(sorted_coo, count_fn=count_fn,
+                                  unroll=ptr_fused, rank_fn=rank_fn)
+        ptr = pointer_array(sorted_coo.dst, coo.n_nodes, unroll=ptr_fused,
+                            windowed=_windowed)
+    return CSC(ptr=ptr, idx=sorted_coo.src, n_edges=sorted_coo.n_edges,
+               n_nodes=coo.n_nodes)
+
+
+def pointer_array(sorted_dst: jnp.ndarray, n_nodes: int, unroll: bool,
+                  windowed: bool | None = None) -> jnp.ndarray:
+    """The full-graph convert's CSC pointers, chosen per lowering: the
+    rank search (``build_pointer_array``) for the CPU; for a TPU the
+    windowed SCR kernel, which reads the stream in order, since there the
+    rank search's single-element gathers (N+1 in each of its log2(E)
+    rounds) cost ~16 ns each. ``windowed`` forces one side."""
+    def rank(d):
+        return build_pointer_array(d, n_nodes, unroll=unroll)
+
+    def window(d):
+        from repro.kernels.pointer_window import windowed_pointer_array
+        return windowed_pointer_array(d, n_nodes)
+
+    if windowed is None:
+        return jax.lax.platform_dependent(sorted_dst, cpu=rank,
+                                          default=window)
+    return (window if windowed else rank)(sorted_dst)
 
 
 def apply_delta(csc: CSC, delta: EdgeDelta, cfg: EngineConfig | None = None,

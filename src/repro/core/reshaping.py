@@ -42,6 +42,11 @@ def build_pointer_array(sorted_dst: jnp.ndarray, n_nodes: int,
     ``rank_fn(sorted, targets, side)`` swaps in the Pallas rank-epilogue
     kernel (``kernels/reindex_epilogue.py``), which runs the same unrolled
     search over VMEM-resident sorted tiles; it outranks ``count_fn``.
+
+    The full-graph convert calls this only where it is lowered for the
+    CPU or a Pallas route is set; on a TPU ``pipeline.pointer_array``
+    takes the windowed SCR kernel (``kernels/pointer_window.py``).
+    Subgraph re-conversion and the delta rebuild keep this path.
     """
     targets = jnp.arange(n_nodes + 1, dtype=jnp.int32)
     if rank_fn is not None:

@@ -1,5 +1,6 @@
 """Compile for one described TPU v5e chip: every Pallas kernel of the
-preprocessing path, ``flash_attention_fwd`` and ``convert_jit``.
+preprocessing path, ``flash_attention_fwd``, ``convert_jit`` and the
+windowed pointer build at ogbn-products' size.
 
 Nothing runs here. The TPU compiler compiles for a chip that is described,
 not attached, so this catches what Mosaic or XLA:TPU would refuse (block
@@ -15,11 +16,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import scopes
 from repro.core.costmodel import EngineConfig
 from repro.core.graph import COO
 from repro.engine.service import convert_jit
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.pointer_window import windowed_pointer_array
+from repro.launch import hlo_analysis
 
 N = 1 << 16  # elements per kernel call: 16 tiles of the default w_upe
 T = 4096     # targets / queries per call
@@ -130,7 +134,26 @@ def test_convert_jit_compiles_for_v5e(cfg, one_chip, no_compile_cache):
     mem = compiled.memory_analysis()
     # two int32 edge arrays in, one out: the program holds O(E) state
     assert mem.argument_size_in_bytes >= 2 * 4 * (1 << 20)
-    assert ("tpu_custom_call" in compiled.as_text()) == cfg.use_pallas
+    # one kernel either way, the pointer build's: the windowed SCR count
+    # by default, the all-pairs SCR count under use_pallas; it carries
+    # the scope that pointer_ms.convert reads
+    text = compiled.as_text()
+    kernels = [m.group(1) for m in map(hlo_analysis._INSTR_RE.match,
+                                       text.splitlines())
+               if m and 'custom_call_target="tpu_custom_call"' in m.string]
+    assert len(kernels) == 1
+    assert hlo_analysis.op_scopes(text)[kernels[0]] == scopes.CONVERT_POINTER
+
+
+def test_windowed_pointer_build_compiles_at_products_size(one_chip,
+                                                          no_compile_cache):
+    """ogbn-products: 2^27 edge slots, 2,449,029 nodes. Targets or output
+    as an (N+1, 1) column pad 128-fold along lanes, 2.5 GB of temp."""
+    dst = jax.ShapeDtypeStruct((1 << 27,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda d: windowed_pointer_array(
+        d, 2_449_029)).lower(dst).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 @pytest.mark.parametrize("name", sorted(ops.MOSAIC_REFUSALS))

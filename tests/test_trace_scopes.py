@@ -63,6 +63,11 @@ def texts():
     return {
         "convert": service.convert_jit.lower(
             COO_G, cfg=EngineConfig()).compile().as_text(),
+        # the route a TPU takes, forced here: the windowed pointer kernel
+        # in the interpreter, with its work list
+        "convert_windowed": jax.jit(lambda c: pipeline.convert(
+            c, EngineConfig(), _windowed=True)).lower(
+                COO_G).compile().as_text(),
         "step": _engine().step_hlo_text(),
         "delta": service.apply_delta_jit.lower(
             CSC_G, delta, cfg=EngineConfig()).compile().as_text(),
@@ -71,6 +76,7 @@ def texts():
 
 @pytest.mark.parametrize("program,wanted", [
     ("convert", {scopes.CONVERT_ORDERING, scopes.CONVERT_POINTER}),
+    ("convert_windowed", {scopes.CONVERT_ORDERING, scopes.CONVERT_POINTER}),
     ("step", STEP_SCOPES),
     ("delta", {scopes.DELTA_APPLY}),
 ])
