@@ -3,7 +3,7 @@
 
 Run from the repository root on a machine with a TPU::
 
-    python chip_smoke.py [--seed N]          # one chip, phases 1-4
+    python chip_smoke.py [--seed N]          # one chip, phases 1-5
     python chip_smoke.py --four-chips        # the 4-chip mesh phase only
 
 Everything runs in this one process, through the entry points a user calls:
@@ -20,7 +20,11 @@ Everything runs in this one process, through the entry points a user calls:
    two streamed edge updates, then 8 more requests. Predictions must equal
    the sequential ``slot_fn`` oracle, the updated CSC a host re-convert,
    and two requests' subgraphs a CPU-backend run of the same program.
-4. ``kernels`` — each Pallas kernel that compiles for the chip against its
+4. ``gat`` — whole-graph GAT inference (``engine.service.gat_infer_jit``)
+   over a 2^20-edge graph's CSC must agree with the sampled GAT forward
+   over full neighbourhoods and with a CPU-backend run, and lower with no
+   scatter.
+5. ``kernels`` — each Pallas kernel that compiles for the chip against its
    ``kernels/ref.py`` oracle, the one Pallas preprocessing route that
    compiles, and the refusal of one that does not.
 
@@ -364,6 +368,68 @@ def phase_serve(g: Graph, seed: int, n_requests: int = 64,
     }
 
 
+def phase_gat(n_nodes: int, n_edges: int, seed: int, d_feat: int = 64,
+              width: int = 32, n_classes: int = 16, sizes=None) -> dict:
+    """Whole-graph GAT inference (``engine.service.gat_infer_jit``, 3
+    layers of 4 heads with skips, the ogbn-products form at smaller
+    widths) over a seeded graph's CSC, against the sampled GAT forward
+    over every node's full neighbourhood on the chip and against the same
+    entry on the CPU backend, all at ``highest`` matmul precision. ``sizes``
+    (row tile, edge chunk, chunks per step, projection rows) is for the
+    CPU rehearsal."""
+    import jax
+    import jax.numpy as jnp
+    from functools import partial
+    from repro.core.graph import SENTINEL as SEN
+    from repro.engine import service
+    from repro.launch import hlo_analysis
+    from repro.models.gnn import (GNNConfig, GraphBatch, gat_infer,
+                                  gnn_apply, gnn_init)
+
+    _, _, coo = make_graph(n_nodes, n_edges, seed)
+    csc = service.convert_jit(coo)
+    gcfg = GNNConfig(name="gat-smoke", kind="gat", n_layers=3,
+                     d_hidden=width, n_heads=4, out_heads=4, skip=True)
+    params = gnn_init(gcfg, jax.random.PRNGKey(seed), d_in=d_feat,
+                      n_classes=n_classes)
+    feats = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                              (n_nodes, d_feat))
+    infer = jax.jit(partial(gat_infer, cfg=gcfg, _sizes=sizes))
+    with jax.default_matmul_precision("highest"):
+        t0 = time.perf_counter()
+        compiled = infer.lower(csc, feats, params).compile()
+        t_compile = time.perf_counter() - t0
+        check(hlo_analysis.op_counts(compiled.as_text()).get("scatter", 0)
+              == 0, "the whole-graph GAT lowers to a scatter")
+        t0 = time.perf_counter()
+        logits, counters = jax.block_until_ready(compiled(csc, feats,
+                                                          params))
+        t_run = time.perf_counter() - t0
+        pos = jnp.arange(csc.idx.shape[0], dtype=jnp.int32)
+        ptr = csc.ptr[:n_nodes + 1]
+        dst = jnp.where(pos < csc.n_edges,
+                        jnp.searchsorted(ptr, pos, side="right") - 1, SEN)
+        batch = GraphBatch(edge_dst=dst.astype(jnp.int32), edge_src=csc.idx,
+                           node_feat=feats,
+                           labels=jnp.zeros(n_nodes, jnp.int32),
+                           label_mask=jnp.zeros(n_nodes, bool), ptr=ptr)
+        sampled = jax.jit(partial(gnn_apply, gcfg))(params, batch)
+        cpu = jax.devices("cpu")[0]
+        on_cpu, _ = infer(*jax.device_put((csc, feats, params), cpu))
+    scale = max(float(np.max(np.abs(np.asarray(on_cpu)))), 1.0)
+    errs = {name: float(np.max(np.abs(np.asarray(logits)
+                                      - np.asarray(other))))
+            for name, other in (("sampled", sampled), ("cpu", on_cpu))}
+    for name, err in errs.items():
+        check(err <= 1e-3 * scale,
+              f"whole-graph GAT vs {name}: max error {err} > 1e-3 x {scale}")
+    return {"n_nodes": n_nodes, "n_edges": n_edges,
+            "counters": {k: int(v) for k, v in counters.items()},
+            "max_abs_err": errs, "logit_scale": scale,
+            "compile_s": t_compile, "run_s": t_run,
+            "memory_analysis": compiled_memory(compiled)}
+
+
 def phase_kernels(seed: int, n_elems: int = 1 << 16, n_targets: int = 4096,
                   sweep=(SWEEP_NODES, SWEEP_EDGES),
                   agg=(2304, 4096, 640), flash=(8, 2048, 128)) -> dict:
@@ -549,6 +615,7 @@ def main(argv=None) -> int:
         run_phase("sweep", phase_sweep, SWEEP_NODES, SWEEP_EDGES,
                   args.seed + 1, fanouts)
         run_phase("serve", phase_serve, graph, args.seed + 2)
+        run_phase("gat", phase_gat, SWEEP_NODES, SWEEP_EDGES, args.seed + 4)
         run_phase("kernels", phase_kernels, args.seed + 3)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

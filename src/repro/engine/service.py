@@ -32,6 +32,7 @@ from repro.core.delta import EdgeDelta
 from repro.core.graph import COO, SENTINEL, next_pow2, pad_to
 from repro.core.reconfig import (RECONFIG_S_PARTIAL, ReconfigDecision,
                                  decide)
+from repro.models import gnn
 
 
 # ---------------------------------------------------------------------------
@@ -48,6 +49,9 @@ sample_batched_jit = jax.jit(pipeline.sample_subgraph_batched,
 convert_jit = jax.jit(pipeline.convert, static_argnames=("cfg",))
 apply_delta_jit = jax.jit(pipeline.apply_delta,
                           static_argnames=("cfg", "mode", "out_capacity"))
+# convert → whole-graph inference: GAT logits of every node from the CSC
+# ``convert_jit`` returns, (csc, features, params) -> (logits, counters)
+gat_infer_jit = jax.jit(gnn.gat_infer, static_argnames=("cfg", "_sizes"))
 
 
 def preprocess_cache_size() -> int:

@@ -22,10 +22,12 @@ SAMPLE_RECONVERT = "sample.reconvert"   # the subgraph's ordering + pointers
 SERVE_GATHER = "serve.gather"           # feature gather
 SERVE_FORWARD = "serve.forward"         # GNN forward and argmax
 DELTA_APPLY = "delta.apply"             # one streamed edge update
+INFER_PROJECT = "infer.project"         # whole-graph GAT: projections, skips
+INFER_EDGE = "infer.edge"               # whole-graph GAT: edge softmax, sums
 
 DEVICE_SCOPES = (CONVERT_ORDERING, CONVERT_POINTER, SAMPLE_SELECT,
                  SAMPLE_REINDEX, SAMPLE_RECONVERT, SERVE_GATHER,
-                 SERVE_FORWARD, DELTA_APPLY)
+                 SERVE_FORWARD, DELTA_APPLY, INFER_PROJECT, INFER_EDGE)
 
 # host spans (jax.profiler.TraceAnnotation)
 ADMIT = "serve.admit"                   # an admission poll with slots free

@@ -50,6 +50,14 @@ def test_serve_phase(graph):
     assert report["logits_vs_cpu"]["max_abs_err"] == 0.0  # same backend
 
 
+def test_gat_phase():
+    report = chip_smoke.phase_gat(400, 5000, seed=4, d_feat=12, width=8,
+                                  n_classes=5, sizes=(16, 256, 4, 128))
+    assert report["counters"]["chunks"] >= 25
+    assert max(report["max_abs_err"].values()) <= 1e-5 * report[
+        "logit_scale"]  # the CPU is both sides here
+
+
 def test_kernels_phase():
     report = chip_smoke.phase_kernels(3, n_elems=4096, n_targets=512,
                                       sweep=(300, 4096), agg=(256, 512, 128),

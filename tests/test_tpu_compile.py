@@ -1,6 +1,6 @@
 """Compile for one described TPU v5e chip: every Pallas kernel of the
-preprocessing path, ``flash_attention_fwd``, ``convert_jit`` and the
-windowed pointer build at ogbn-products' size.
+preprocessing path, ``flash_attention_fwd``, ``convert_jit``, and the
+windowed pointer build and the whole-graph GAT at ogbn-products' size.
 
 Nothing runs here. The TPU compiler compiles for a chip that is described,
 not attached, so this catches what Mosaic or XLA:TPU would refuse (block
@@ -18,12 +18,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import scopes
 from repro.core.costmodel import EngineConfig
-from repro.core.graph import COO
-from repro.engine.service import convert_jit
+from repro.core.graph import COO, CSC
+from repro.engine.service import convert_jit, gat_infer_jit
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.pointer_window import windowed_pointer_array
 from repro.launch import hlo_analysis
+from repro.models.gnn import GNNConfig, gnn_init
 
 N = 1 << 16  # elements per kernel call: 16 tiles of the default w_upe
 T = 4096     # targets / queries per call
@@ -154,6 +155,37 @@ def test_windowed_pointer_build_compiles_at_products_size(one_chip,
         d, 2_449_029)).lower(dst).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_whole_graph_gat_compiles_at_products_size(one_chip,
+                                                   no_compile_cache):
+    """PyG's OGB GAT (3 layers, 4 heads of 128, skips) over ogbn-products:
+    2,449,029 nodes, 2^27 edge slots. A layer holds its input-and-output
+    buffer and z (5.04 GB each at 512 wide, padded rows) and one step's
+    chunks; a layer that kept its input beside its output would need
+    15 GB of temp and not fit the chip."""
+    n, cap = 2_449_029, 1 << 27
+    cfg = GNNConfig(name="gat-products", kind="gat", n_layers=3,
+                    d_hidden=128, n_heads=4, out_heads=4, skip=True)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    csc = CSC(ptr=spec((n + 1,)), idx=spec((cap,)), n_edges=spec(()),
+              n_nodes=n)
+    params = jax.tree.map(lambda a: spec(a.shape, a.dtype), jax.eval_shape(
+        lambda: gnn_init(cfg, jax.random.PRNGKey(0), d_in=100,
+                         n_classes=47)))
+    with jax.default_matmul_precision("high"):
+        compiled = gat_infer_jit.lower(csc, spec((n, 100), jnp.float32),
+                                       params, cfg=cfg).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 12e9
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 14e9
+    text = compiled.as_text()
+    assert hlo_analysis.op_counts(text).get("scatter", 0) == 0
+    assert set(hlo_analysis.op_scopes(text).values()) - {None} == {
+        scopes.INFER_PROJECT, scopes.INFER_EDGE}
 
 
 @pytest.mark.parametrize("name", sorted(ops.MOSAIC_REFUSALS))

@@ -20,7 +20,7 @@ from repro.core.delta import EdgeDelta
 from repro.core.graph import COO, random_coo
 from repro.engine import service
 from repro.launch import hlo_analysis
-from repro.models.gnn import gnn_init
+from repro.models.gnn import GNNConfig, gnn_init
 from repro.serve import GnnServeEngine
 
 REPO = Path(__file__).resolve().parents[1]
@@ -38,6 +38,10 @@ CSC_G = pipeline.convert(COO_G)
 FEATS = jnp.asarray(_rng.normal(size=(N_NODES, 12)).astype(np.float32))
 GCFG = smoke_config()
 PARAMS = gnn_init(GCFG, jax.random.PRNGKey(1), d_in=12, n_classes=7)
+
+GAT_CFG = GNNConfig(name="gat-scopes", kind="gat", n_layers=2, d_hidden=4,
+                    n_heads=2, out_heads=2, skip=True)
+GAT_PARAMS = gnn_init(GAT_CFG, jax.random.PRNGKey(2), d_in=12, n_classes=7)
 
 STEP_SCOPES = {scopes.SAMPLE_SELECT, scopes.SAMPLE_REINDEX,
                scopes.SAMPLE_RECONVERT, scopes.SERVE_GATHER,
@@ -71,6 +75,8 @@ def texts():
         "step": _engine().step_hlo_text(),
         "delta": service.apply_delta_jit.lower(
             CSC_G, delta, cfg=EngineConfig()).compile().as_text(),
+        "gat_infer": service.gat_infer_jit.lower(
+            CSC_G, FEATS, GAT_PARAMS, cfg=GAT_CFG).compile().as_text(),
     }
 
 
@@ -79,6 +85,7 @@ def texts():
     ("convert_windowed", {scopes.CONVERT_ORDERING, scopes.CONVERT_POINTER}),
     ("step", STEP_SCOPES),
     ("delta", {scopes.DELTA_APPLY}),
+    ("gat_infer", {scopes.INFER_PROJECT, scopes.INFER_EDGE}),
 ])
 def test_every_scope_reaches_the_compiled_program(texts, program, wanted):
     # op_scopes raises on an instruction that carries two listed scopes
