@@ -41,8 +41,8 @@ from repro.core.costmodel import (EngineConfig, SORT_STRATEGIES, Workload,
                                   sample_edge_capacity, sample_vid_capacity,
                                   shard_collective_bytes_budget,
                                   shard_convert_while_count,
-                                  sort_op_count, sort_pass_count,
-                                  sort_while_count)
+                                  sort_fn_op_count, sort_op_count,
+                                  sort_pass_count, sort_while_count)
 from repro.core.graph import next_pow2
 from repro.core.ordering import supports_packed_keys
 
@@ -467,7 +467,7 @@ def model_self_consistency(cfg: EngineConfig, w: Workload,
             2 * sort_while_count(cfg, wd, ds) + ranks:
         return "delta while census inconsistent with its sort + rank terms"
     if delta_sort_op_count(cfg, w, 64) != \
-            2 * sort_op_count(cfg, wd, ds) + 1:
+            2 * sort_fn_op_count(cfg, wd, ds) + 1:
         return ("delta sort census must be 2·stream passes + the event-zip "
                 "rung")
     # Below ~2048 edges both paths finish inside one dispatch quantum and

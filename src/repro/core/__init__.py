@@ -27,7 +27,8 @@ from .set_count import (count_equal, count_less_than, filter_lookup,
 from .ordering import (DEFAULT_CHUNK, edge_ordering, edge_ordering_xla,
                        global_radix_sort_by_key, merge_round_fan_ins,
                        merge_sorted, merge_sorted_k, stable_sort_by_key,
-                       supports_packed_keys, xla_stable_sort_by_key)
+                       supports_packed_keys, xla_lex_sort,
+                       xla_stable_sort_by_key)
 from .reshaping import (build_pointer_array, build_pointer_array_serial,
                         data_reshaping, graph_convert)
 from .sampling import sample_khop, select_floyd, select_keysort, \

@@ -285,7 +285,18 @@ def sort_op_count(cfg: EngineConfig, w: Workload,
                   strategy: str | None = None) -> int:
     """Native ``sort`` ops in the compiled Ordering: the radix strategies
     must lower to zero (their order is produced by histogram + gather);
-    xla_sort dispatches one per global sort pass."""
+    xla_sort dispatches one in either key scheme — the packed key's one
+    pass, or the two-pass scheme's ONE two-key sort
+    (``ordering.xla_lex_sort``)."""
+    strategy = strategy or resolve_sort_strategy(cfg, w)
+    return 1 if strategy == "xla_sort" else 0
+
+
+def sort_fn_op_count(cfg: EngineConfig, w: Workload,
+                     strategy: str | None = None) -> int:
+    """Native ``sort`` ops of one (dst, src) stream sorted pass by pass
+    through a ``sort_fn`` (the delta streams): one per global pass on
+    xla_sort, zero on the radix strategies."""
     strategy = strategy or resolve_sort_strategy(cfg, w)
     return sort_pass_count(cfg, w) if strategy == "xla_sort" else 0
 
@@ -478,7 +489,7 @@ def delta_sort_op_count(cfg: EngineConfig, w: Workload, d_cap: int,
     wd = delta_workload(w, d_cap)
     if strategy is None:
         strategy = resolve_delta_sort_strategy(cfg, wd, cal)
-    return 2 * sort_op_count(cfg, wd, strategy) + 1
+    return 2 * sort_fn_op_count(cfg, wd, strategy) + 1
 
 
 def delta_merge_seconds(cfg: EngineConfig, w: Workload, d_cap: int,

@@ -12,7 +12,11 @@ are provided, selected by ``mode``:
   run a single global sort, then unpack ``(dst, src)``. Half the sort passes
   and half the merge rounds of the LSD scheme.
 * ``"two_pass"`` — the LSD fallback for wide VID spaces: a stable global
-  sort by src followed by a stable global sort by dst.
+  sort by src followed by a stable global sort by dst. Under ``"xla_sort"``
+  (with no caller ``sort_fn``) the two passes are ONE two-key comparison
+  sort over the (dst, src) columns (``xla_lex_sort``): the pair is the
+  whole record, so equal keys are identical records and the unstable sort
+  is exact.
 * ``"auto"`` (default) — ``"packed"`` whenever the VID space allows it.
 
 Both modes produce bit-identical output (stable sort by the lexicographic
@@ -37,8 +41,7 @@ the reduction structure per workload — ``EngineConfig.sort_strategy``,
   (``set_partition.tiled_digit_sources``), O(digit_passes·N) with zero
   merge rounds (guarded in tests/test_perf_paths.py).
 * ``"xla_sort"`` — the platform's native comparison-sort unit (one
-  ``lax.sort``); the CPU-host calibration dispatches large arrays here,
-  the TPU calibration doesn't (see ``xla_stable_sort_by_key``).
+  ``lax.sort``; see ``xla_stable_sort_by_key`` and ``xla_lex_sort``).
 
 Sentinel handling: padded entries carry SENTINEL; keys are clipped to
 ``n_nodes`` (one past any valid VID) before sorting so the radix width stays
@@ -333,10 +336,9 @@ def xla_stable_sort_by_key(keys: jnp.ndarray, vals: jnp.ndarray,
     On CPU hosts the native sort's fused compare loop beats any
     jnp-composed radix pass structure at scale — which is exactly why the
     strategy axis exists (§V: pick the reduction structure per workload
-    per platform); on TPU the comparison sort loses its advantage (XLA
-    sorts replicate under GSPMD and lower poorly to Mosaic — see
-    ``set_count.rank_in_sorted``) and the cost model's calibration sends
-    large graphs to ``global_radix`` instead.
+    per platform). Under GSPMD an XLA sort replicates (see
+    ``set_count.rank_in_sorted``), so the mesh-sharded engine keeps its
+    own sorter.
     """
     clipped = jnp.minimum(keys, jnp.int32(key_bound))
     if vals is None:
@@ -345,6 +347,26 @@ def xla_stable_sort_by_key(keys: jnp.ndarray, vals: jnp.ndarray,
         ks, vs = jax.lax.sort([clipped, vals], num_keys=1, is_stable=True)
     ks = jnp.where(ks >= key_bound, SENTINEL, ks)
     return ks, vs
+
+
+def xla_lex_sort(dst: jnp.ndarray, src: jnp.ndarray, bound: int
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The two-pass Ordering on ``"xla_sort"`` as ONE two-key sort.
+
+    Both columns are clipped to ``bound`` so padding sorts last, and one
+    ``lax.sort`` orders the (dst, src) pair lexicographically. The pair is
+    the whole record, so records with equal keys are identical and the
+    result equals the two stable LSD passes bit for bit. It is unstable on
+    purpose: XLA:TPU makes a sort stable by sorting an extra ``iota``
+    operand, which would put a third array back through the sort network.
+    Sentinels are restored as the two passes restore them.
+    """
+    d = jnp.minimum(dst, jnp.int32(bound))
+    s = jnp.minimum(src, jnp.int32(bound))
+    d, s = jax.lax.sort([d, s], num_keys=2, is_stable=False)
+    d = jnp.where(d >= bound, SENTINEL, d)
+    s = jnp.where((s >= bound) | (d == SENTINEL), SENTINEL, s)
+    return d, s
 
 
 def stable_sort_by_key(keys: jnp.ndarray, vals: jnp.ndarray, key_bound: int,
@@ -404,6 +426,11 @@ def edge_ordering(coo: COO, chunk: int | None = None, radix_bits: int = 4,
                   fan_in: int = 2, digit_pass_fn=None) -> COO:
     """Sort edges by (dst, src) — packed single-pass or two-pass LSD.
 
+    On ``strategy="xla_sort"`` with no ``sort_fn``, ``"two_pass"`` runs as
+    one two-key comparison sort (``xla_lex_sort``) — exact, as ties are
+    identical (dst, src) records. The radix strategies sort by one key a
+    pass and keep the two passes.
+
     ``sort_fn(keys, vals, key_bound) -> (keys, vals)`` overrides the global
     stable sorter — the mesh-sharded engine passes its shard_map sorter so
     both paths share ONE copy of the packing/two-pass/sentinel-restore
@@ -418,6 +445,7 @@ def edge_ordering(coo: COO, chunk: int | None = None, radix_bits: int = 4,
     edge-id payload the two-pass scheme rides along would be pure waste;
     False retained for A/B bytes-moved measurement.
     """
+    lex_sort = sort_fn is None and strategy == "xla_sort"
     if sort_fn is None:
         def sort_fn(k, v, bound):
             return stable_sort_by_key(k, v, bound, chunk=chunk,
@@ -458,6 +486,10 @@ def edge_ordering(coo: COO, chunk: int | None = None, radix_bits: int = 4,
                    n_nodes=coo.n_nodes)
     if mode != "two_pass":
         raise ValueError(f"unknown ordering mode {mode!r}")
+    if lex_sort:
+        dst2, src2 = xla_lex_sort(coo.dst, coo.src, bound)
+        return COO(dst=dst2, src=src2, n_edges=coo.n_edges,
+                   n_nodes=coo.n_nodes)
     # pass 1: by src (secondary key), dst rides along as payload
     src1, dst1 = sort_fn(coo.src, coo.dst, bound)
     # pass 2: by dst (primary key), src rides along; stability keeps src order

@@ -17,8 +17,9 @@ import pytest
 from repro.core import (COO, SENTINEL, EngineConfig, convert, convert_xla,
                         global_radix_sort_by_key, random_coo,
                         stable_sort_by_key, supports_packed_keys)
-from repro.core.ordering import (edge_ordering, merge_round_fan_ins,
-                                 merge_rounds, merge_sorted_k)
+from repro.core.ordering import (edge_ordering, edge_ordering_xla,
+                                 merge_round_fan_ins, merge_rounds,
+                                 merge_sorted_k)
 from repro.core.set_partition import (digit_relocation_sources,
                                       gather_sources_from_counts,
                                       tiled_digit_sources)
@@ -147,6 +148,58 @@ def test_strategy_equality_sweep_across_vid_widths(n_nodes):
                                           src[order], tag)
             assert np.all(np.asarray(out.dst)[e:] == SEN), tag
             assert np.all(np.asarray(out.src)[e:] == SEN), tag
+
+
+def _wide_graph(case):
+    """(n_nodes, dst, src) for ``case``, with ids too wide for the packed
+    key; 1,500 edges or none, so a 4,096-slot COO is mostly padding."""
+    rng = np.random.default_rng(len(case))
+    n = 32768 if case == "random_32768" else 300_000
+    if case == "empty":
+        return n, np.zeros(0, np.int32), np.zeros(0, np.int32)
+    dst, src = random_coo(rng, n, 1500)
+    if case == "repeats":  # 1,500 draws of 40 (dst, src) pairs
+        pick = rng.integers(0, 40, 1500)
+        dst, src = dst[pick], src[pick]
+    elif case == "self_loops":
+        src[::2] = dst[::2]
+    elif case == "hub":  # the widest id holds half the edges
+        dst[rng.random(1500) < 0.5] = n - 1
+        src[:8] = n - 1
+    return n, dst, src
+
+
+@pytest.mark.parametrize("case", ["random_32768", "random_300k", "repeats",
+                                  "self_loops", "hub", "empty"])
+def test_two_pass_one_sort_on_xla_sort_bit_equal(case):
+    """On ``xla_sort`` the two-pass scheme is ONE two-key sort over the
+    (dst, src) columns: bit-equal to the two stable LSD passes (the
+    chunked_merge two-pass) and to the XLA lexsort baseline, SENTINEL
+    tail of both columns included."""
+    n, dst, src = _wide_graph(case)
+    e = dst.shape[0]
+    coo = COO.from_arrays(dst, src, n, capacity=4096)
+    assert not supports_packed_keys(n)
+    one = edge_ordering(coo, chunk=128, mode="two_pass", strategy="xla_sort")
+    two = edge_ordering(coo, chunk=128, mode="two_pass",
+                        strategy="chunked_merge")
+    base = edge_ordering_xla(coo)
+    for name, ref in [("two LSD passes", two), ("lexsort", base)]:
+        np.testing.assert_array_equal(np.asarray(one.dst),
+                                      np.asarray(ref.dst), name)
+        np.testing.assert_array_equal(np.asarray(one.src),
+                                      np.asarray(ref.src), name)
+    order = np.lexsort((src, dst))
+    np.testing.assert_array_equal(np.asarray(one.dst)[:e], dst[order])
+    np.testing.assert_array_equal(np.asarray(one.src)[:e], src[order])
+    assert np.all(np.asarray(one.dst)[e:] == SEN)
+    assert np.all(np.asarray(one.src)[e:] == SEN)
+    sorts = [q for q in jax.make_jaxpr(lambda c: edge_ordering(
+        c, mode="two_pass", strategy="xla_sort"))(coo).eqns
+        if q.primitive.name == "sort"]
+    assert [len(q.invars) for q in sorts] == [2]
+    assert sorts[0].params["num_keys"] == 2
+    assert not sorts[0].params["is_stable"]
 
 
 @pytest.mark.parametrize("fan_in", [2, 3, 4, 8])

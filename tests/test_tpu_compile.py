@@ -10,6 +10,7 @@ kernel that starts to compile fails here until it leaves
 ``kernels.ops.MOSAIC_REFUSALS``.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -144,6 +145,27 @@ def test_convert_jit_compiles_for_v5e(cfg, one_chip, no_compile_cache):
                if m and 'custom_call_target="tpu_custom_call"' in m.string]
     assert len(kernels) == 1
     assert hlo_analysis.op_scopes(text)[kernels[0]] == scopes.CONVERT_POINTER
+
+
+def test_two_pass_convert_runs_one_sort_for_v5e(one_chip, no_compile_cache):
+    """300,000 nodes are 19-bit ids, too wide for the packed key, so the
+    default convert orders by the two-pass scheme on ``xla_sort``: ONE
+    two-key sort over the (dst, src) columns in ``convert.ordering``,
+    with no ``iota`` operand (XLA:TPU's way to make a sort stable) where
+    two stable LSD passes would sort three arrays each."""
+    compiled = convert_jit.lower(_coo_spec(one_chip, 300_000, 1 << 20),
+                                 cfg=EngineConfig()).compile()
+    text = compiled.as_text()
+    scope = hlo_analysis.op_scopes(text)
+    sorts = [line for line in text.splitlines()
+             if (m := hlo_analysis._INSTR_RE.match(line))
+             and " sort(" in line
+             and scope[m.group(1)] == scopes.CONVERT_ORDERING]
+    assert len(sorts) == 1, sorts
+    operands = re.findall(r"%([\w.\-]+)",
+                          sorts[0].split(" sort(", 1)[1].split(")", 1)[0])
+    assert len(operands) == 2, sorts[0]
+    assert not any(o.startswith("iota") for o in operands), sorts[0]
 
 
 def test_windowed_pointer_build_compiles_at_products_size(one_chip,
