@@ -12,8 +12,8 @@ Everything runs in this one process, through the entry points a user calls:
    (``GNN_SHAPES["minibatch_lg"]``: 232,965 nodes, 114,615,892 edges)
    goes COO → CSC through ``engine.service.convert_jit`` and must equal a
    host NumPy CSC bit for bit.
-2. ``sweep`` — on a 2^20-edge graph, every ``sort_strategy`` ×
-   ``reindex_strategy`` convert, one sample per reindex strategy and both
+2. ``sweep`` — on a 2^20-edge graph, a convert under each key scheme ×
+   ``reindex_strategy``, one sample per reindex strategy and both
    ``apply_delta`` modes must agree bit for bit.
 3. ``serve`` — ``GnnServeEngine`` runs graphsage-reddit at its published
    widths over the Reddit CSC and a 602-wide feature table: 64 requests,
@@ -24,9 +24,8 @@ Everything runs in this one process, through the entry points a user calls:
    over a 2^20-edge graph's CSC must agree with the sampled GAT forward
    over full neighbourhoods and with a CPU-backend run, and lower with no
    scatter.
-5. ``kernels`` — each Pallas kernel that compiles for the chip against its
-   ``kernels/ref.py`` oracle, the one Pallas preprocessing route that
-   compiles, and the refusal of one that does not.
+5. ``kernels`` — each Pallas kernel against its ``kernels/ref.py`` oracle,
+   and the windowed pointer kernel against the host pointers.
 
 ``--four-chips`` runs ``engine.shard.shard_preprocess`` over a 4-chip
 ``("data",)`` mesh on the Reddit-shaped graph against the single-chip
@@ -156,7 +155,6 @@ def phase_convert(n_nodes: int, n_edges: int, d_feat: int, n_classes: int,
     import jax
     from repro.core.costmodel import (EngineConfig, Workload,
                                       pointer_reindex_strategy,
-                                      resolve_sort_strategy,
                                       sort_pass_count)
     from repro.engine.service import convert_jit
 
@@ -177,7 +175,6 @@ def phase_convert(n_nodes: int, n_edges: int, d_feat: int, n_classes: int,
         "result": "CSC bit-identical to the host NumPy CSC",
         "n_nodes": n_nodes, "n_edges": n_edges, "capacity": coo.capacity,
         "auto_dispatch (CPU-measured constants)": {
-            "sort_strategy": resolve_sort_strategy(cfg, w),
             "sort_passes": sort_pass_count(cfg, w),
             "pointer_epilogue": pointer_reindex_strategy(cfg, w)},
         "setup_s": t_setup, "compile_s": t_compile, "run_s": t_run,
@@ -188,12 +185,11 @@ def phase_convert(n_nodes: int, n_edges: int, d_feat: int, n_classes: int,
 def phase_sweep(n_nodes: int, n_edges: int, seed: int,
                 fanouts: tuple[int, ...], n_seeds: int = 64,
                 delta_cap: int = 64) -> dict:
-    """Every sort × reindex strategy convert, a sample per reindex
-    strategy, and both delta modes — all bit-identical."""
+    """A convert under each key scheme × reindex strategy, a sample per
+    reindex strategy, and both delta modes — all bit-identical."""
     import jax
     import jax.numpy as jnp
-    from repro.core.costmodel import (REINDEX_STRATEGIES, SORT_STRATEGIES,
-                                      EngineConfig)
+    from repro.core.costmodel import REINDEX_STRATEGIES, EngineConfig
     from repro.core.delta import EdgeDelta
     from repro.engine.service import apply_delta_jit, convert_jit, sample_jit
 
@@ -201,9 +197,10 @@ def phase_sweep(n_nodes: int, n_edges: int, seed: int,
     ptr_ref, idx_ref = host_csc(dst, src, n_nodes)
     t0 = time.perf_counter()
     csc, memory = None, {}
-    for s in SORT_STRATEGIES:
+    modes = ("auto", "two_pass")
+    for s in modes:
         for r in REINDEX_STRATEGIES:
-            cfg = EngineConfig(sort_strategy=s, reindex_strategy=r)
+            cfg = EngineConfig(sort_mode=s, reindex_strategy=r)
             compiled = convert_jit.lower(coo, cfg=cfg).compile()
             memory[f"convert {s}/{r}"] = compiled_memory(compiled)
             csc = compiled(coo)
@@ -230,7 +227,7 @@ def phase_sweep(n_nodes: int, n_edges: int, seed: int,
                                          out_capacity=coo.capacity).compile()
         memory[f"apply_delta {mode}"] = compiled_memory(compiled)
         check_csc(compiled(csc, delta), ptr2, idx2, f"apply_delta {mode}")
-    return {"result": f"{len(SORT_STRATEGIES) * len(REINDEX_STRATEGIES)} "
+    return {"result": f"{len(modes) * len(REINDEX_STRATEGIES)} "
                       f"converts, {len(subs)} samples and 2 delta modes "
                       f"bit-identical",
             "n_nodes": n_nodes, "n_edges": n_edges,
@@ -433,15 +430,13 @@ def phase_gat(n_nodes: int, n_edges: int, seed: int, d_feat: int = 64,
 def phase_kernels(seed: int, n_elems: int = 1 << 16, n_targets: int = 4096,
                   sweep=(SWEEP_NODES, SWEEP_EDGES),
                   agg=(2304, 4096, 640), flash=(8, 2048, 128)) -> dict:
-    """Every Pallas kernel that compiles for the chip, against its
-    ``kernels/ref.py`` oracle; the Pallas pointer-build route end to end;
-    and, on a TPU, the refusal of a route whose kernel Mosaic rejects."""
+    """Every Pallas kernel against its ``kernels/ref.py`` oracle, and the
+    windowed pointer kernel against the host pointers."""
     import jax
     import jax.numpy as jnp
-    from repro.core.costmodel import EngineConfig
-    from repro.engine.service import convert_jit
     from repro.kernels import ops, ref
     from repro.kernels.flash_attention import flash_attention_fwd
+    from repro.kernels.pointer_window import windowed_pointer_array
 
     rng = np.random.default_rng(seed)
     out = {}
@@ -494,32 +489,18 @@ def phase_kernels(seed: int, n_elems: int = 1 << 16, n_targets: int = 4096,
     err = float(np.max(np.abs(got - want)))
     check(err <= 3e-2, f"flash_attention_fwd max error {err} > 3e-2")
     out["flash_attention_fwd"] = {"max_abs_err": err}
-    # the Pallas route that compiles: SCR-kernel pointer build
+    # the windowed SCR pointer kernel over a sorted, SENTINEL-padded dst
     n, e = sweep
     dst, src, coo = make_graph(n, e, seed)
-    ptr_ref, idx_ref = host_csc(dst, src, n)
-    pallas_cfg = EngineConfig(use_pallas=True, sort_strategy="xla_sort",
-                              reindex_strategy="unfused")
-    compiled = convert_jit.lower(coo, cfg=pallas_cfg).compile()
-    check("tpu_custom_call" in compiled.as_text()
-          or jax.default_backend() != "tpu",
-          "the use_pallas route holds no Mosaic kernel")
-    check_csc(compiled(coo), ptr_ref, idx_ref,
-              "convert use_pallas xla_sort/unfused")
-    out["route use_pallas xla_sort/unfused"] = {
-        "result": "CSC equal to host",
-        "memory_analysis": compiled_memory(compiled)}
-    if jax.default_backend() == "tpu":
-        refused = EngineConfig(use_pallas=True,
-                               sort_strategy="chunked_merge")
-        try:
-            convert_jit(coo, cfg=refused)
-        except NotImplementedError as exc:
-            check("radix_sort_chunks" in str(exc), f"wrong refusal: {exc}")
-            out["route use_pallas chunked_merge"] = f"refused: {exc}"
-        else:
-            raise SmokeFailure("a refused Pallas route ran on the TPU")
-    return {"result": "every compiling kernel matches its oracle",
+    ptr_ref, _ = host_csc(dst, src, n)
+    sorted_dst = np.full(coo.capacity, SENTINEL, np.int32)
+    sorted_dst[:e] = np.sort(dst)
+    got = np.asarray(jax.jit(windowed_pointer_array, static_argnums=1)(
+        jnp.asarray(sorted_dst), n))
+    check(np.array_equal(got[:n + 1], ptr_ref),
+          "windowed_pointer_array differs from the host pointers")
+    out["windowed_pointer_array"] = "equal"
+    return {"result": "every kernel matches its oracle",
             "kernels": out}
 
 

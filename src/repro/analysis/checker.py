@@ -3,7 +3,7 @@ group, evaluate every contract case against the compiled HLO.
 
 The flow per contract:
 
-1. ``contracts.*_cases`` enumerates (config, workload, strategy) cases,
+1. ``contracts.*_cases`` enumerates (config, workload) cases,
    each carrying a model-derived :class:`~repro.analysis.contracts.Expectation`
    and a ``structure`` dedupe key.
 2. Cases are grouped by key; ONE representative is lowered per group
@@ -11,7 +11,7 @@ The flow per contract:
    case is evaluated against that one program. Members of a group whose
    expectations disagree therefore can't all pass — the group is also a
    model-consistency check, and it is what makes the full 81-config ×
-   workload × strategy sweep compile ~40 programs instead of ~1000.
+   workload sweep compile a handful of programs instead of ~340.
 3. The convert contract additionally runs the recompile guard: dispatching
    the module-level ``engine.service.convert_jit`` twice with the group's
    (cfg, bucket) must add zero cache entries on the second call.
@@ -214,8 +214,7 @@ def _check_grouped(cases: list[Case], lower, progress=None) -> Report:
         for m in members:
             rep.checks += 1
             rep.violations.extend(evaluate_hlo(hlo, m))
-            err = contracts.model_self_consistency(m.cfg, m.workload,
-                                                   m.strategy)
+            err = contracts.model_self_consistency(m.cfg, m.workload)
             if err:
                 rep.violations.append(Violation(
                     m.contract, m.label, "model-consistency", err))
@@ -294,7 +293,7 @@ def check_serve(grid: str = "full", progress=None) -> Report:
     eng = ServeEngine(cfg, params, n_slots=2, max_len=32, prompt_cap=8)
     case = Case(contract="serve", label="gemma2-9b smoke step",
                 cfg=contracts.EngineConfig(), workload=contracts.Workload(
-                    n=0, e=0), strategy="-", structure=("serve",),
+                    n=0, e=0), structure=("serve",),
                 expect=contracts.serve_expectation())
     hlo = eng._step.lower(eng.params, eng.state).compile().as_text()
     rep = Report(groups=1, checks=1,
@@ -317,7 +316,7 @@ def check_serve(grid: str = "full", progress=None) -> Report:
 
 def _gnn_serve_engine(cfg):
     """A smoke GnnServeEngine on the contract workload's graph — shared by
-    the lowering probe (per sort strategy) and the runtime cache guard."""
+    the lowering probe and the runtime cache guard."""
     from repro.configs.graphsage_reddit import smoke_config
     from repro.models.gnn import gnn_init
     from repro.serve.gnn import GnnServeEngine
@@ -338,8 +337,8 @@ def _lower_gnn_serve(case: Case) -> str:
 
 
 def check_gnn_serve(grid: str = "full", progress=None) -> Report:
-    """Lower the GNN serving step once per sort strategy and check the
-    scatter-free / sort-census contract, then run two heterogeneous
+    """Lower the GNN serving step and check the scatter-free /
+    sort-census contract, then run two heterogeneous
     inference requests end-to-end and assert zero recompiles — the same
     two-leg shape as the LM serve contract."""
     cases = contracts.gnn_serve_cases(grid)

@@ -1,19 +1,19 @@
 """Declarative contracts over the compiled hot paths.
 
-Every jitted hot path — ``pipeline.convert`` per sort strategy,
-``sample_subgraph``, the ``engine.shard`` sorted convert, the serve step —
+Every jitted hot path — ``pipeline.convert``, ``sample_subgraph``, the
+``engine.shard`` sorted convert, the serve steps, the delta update —
 registers machine-checkable invariants over its lowered HLO:
 
 * **forbidden / required ops** — no ``scatter`` anywhere on the convert
-  spine; no native ``sort`` on the radix strategies (their order comes from
-  histogram + gather); exactly the priced number of ``sort`` ops on
-  xla_sort paths.
+  spine; exactly one native ``sort`` per Ordering (the packed key, or the
+  (dst, src) pair as one two-key sort).
 * **while-op budgets** — computed FROM the cost model
-  (``costmodel.convert_while_count`` / ``shard_convert_while_count``, which
-  are ``merge_round_count``/``digit_pass_count`` re-expressed as a lowering
-  census). The model and the compiled program must agree for every config
-  in ``bitstream_library()`` across the workload grid — a disagreement
-  means the model is pricing a program that does not run.
+  (``costmodel.convert_while_count`` / ``shard_convert_while_count`` /
+  ``delta_while_count``: the rank-search loops the resolved SCR-epilogue
+  strategy leaves, plus the sharded sort's cross-device merge rounds).
+  The model and the compiled program must agree for every config in
+  ``bitstream_library()`` across the workload grid — a disagreement means
+  the model is pricing a program that does not run.
 * **collective-byte ceilings** — ``hlo_analysis.collective_bytes`` on the
   sharded paths must stay under ``costmodel.shard_collective_bytes_budget``.
 * **recompile guards** — re-dispatching the module-level jit entry
@@ -28,21 +28,16 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.core.costmodel import (EngineConfig, SORT_STRATEGIES, Workload,
+from repro.core.costmodel import (EngineConfig, Workload,
                                   bitstream_library, convert_while_count,
                                   delta_epilogue_strategy,
-                                  delta_sort_op_count, delta_while_count,
-                                  delta_workload, merge_round_count,
+                                  delta_while_count, delta_workload,
                                   pointer_reindex_strategy,
                                   reindex_dispatch_count,
-                                  reindex_sort_op_count,
                                   resolve_delta_mode,
-                                  resolve_delta_sort_strategy,
-                                  sample_edge_capacity, sample_vid_capacity,
                                   shard_collective_bytes_budget,
                                   shard_convert_while_count,
-                                  sort_fn_op_count, sort_op_count,
-                                  sort_pass_count, sort_while_count)
+                                  sort_pass_count)
 from repro.core.graph import next_pow2
 from repro.core.ordering import supports_packed_keys
 
@@ -63,17 +58,17 @@ class Case:
     """One (config, workload) point of one contract.
 
     ``structure`` is the dedupe key: cases with equal keys lower to the
-    same HLO (the program depends on shapes + the resolved strategy knobs,
-    not on SCR geometry), so the checker compiles once per key and
-    evaluates every member case against that one program — which also
-    proves the members' expectations are mutually consistent.
+    same HLO (the program depends on shapes + the resolved key scheme and
+    epilogue strategy, not on UPE or SCR geometry), so the checker
+    compiles once per key and evaluates every member case against that
+    one program — which also proves the members' expectations are
+    mutually consistent.
     """
 
     contract: str
     label: str
     cfg: EngineConfig
     workload: Workload
-    strategy: str
     structure: tuple
     expect: Expectation
     n_dev: int = 1
@@ -103,87 +98,56 @@ CONVERT_WORKLOADS = (
 SMOKE_WORKLOADS = (Workload(n=200, e=2048),)
 
 # Off-library configs that exercise program shapes the generated library
-# never hits: a k-ary ladder, the lax.map lane path (0 < n_upe < n_chunks),
-# a wide digit, and the forced two-pass key scheme.
+# never hits: the forced two-pass key scheme and the pinned unfused
+# pointer epilogue.
 EXTRA_CONFIGS = (
-    EngineConfig(w_upe=256, n_upe=8, merge_fan_in=4),
-    EngineConfig(w_upe=256, n_upe=2),
-    EngineConfig(w_upe=512, n_upe=8, radix_bits=8),
-    EngineConfig(w_upe=256, n_upe=8, sort_mode="two_pass"),
+    EngineConfig(sort_mode="two_pass"),
+    EngineConfig(reindex_strategy="unfused"),
 )
 SMOKE_CONFIGS = (
     EngineConfig(),
-    EngineConfig(w_upe=256, n_upe=2),
-    EngineConfig(w_upe=512, n_upe=8, merge_fan_in=4),
+    EngineConfig(sort_mode="two_pass"),
 )
 
 
-def convert_structure(cfg: EngineConfig, w: Workload,
-                      strategy: str) -> tuple:
+def convert_structure(cfg: EngineConfig, w: Workload) -> tuple:
     """Program-identity key for the compiled ``pipeline.convert``.
 
     Two configs with equal keys trace to the same jaxpr: the program is a
-    function of shapes (n, pow2 edge capacity), the resolved key scheme,
-    the strategy, and — on the radix paths — the chunk width, digit width,
-    ladder fan-in and the lane-batch routing (vmap when ``n_upe`` covers
-    the chunk grid, ``lax.map`` over ``n_upe``-sized batches otherwise).
-    SCR geometry (w_scr/n_scr) prices Reshaping but never changes the
-    program, which is what collapses the 81-config library to a handful of
-    lowered programs per workload.
+    function of shapes (n, pow2 edge capacity), the resolved key scheme
+    and the pointer epilogue's resolved strategy. UPE and SCR geometry
+    price the Table-I terms but never change the program, which is what
+    collapses the 81-config library to one lowered program per workload.
     """
-    e = next_pow2(w.e)
-    passes = sort_pass_count(cfg, w)
-    if strategy == "xla_sort":
-        extra: tuple = ()
-    else:
-        chunk = min(cfg.w_upe, e)
-        n_chunks = e // chunk
-        lax_map = 0 < cfg.n_upe < n_chunks
-        extra = (chunk, cfg.radix_bits, cfg.merge_fan_in,
-                 cfg.n_upe if lax_map else 0)
-    return (strategy, passes, w.n, e) + extra
+    return (sort_pass_count(cfg, w), w.n, next_pow2(w.e),
+            pointer_reindex_strategy(cfg, w))
 
 
-def convert_expectation(cfg: EngineConfig, w: Workload,
-                        strategy: str) -> Expectation:
-    """The census ``costmodel`` prices for this (cfg, workload, strategy):
-    scatter-free always, native sorts only on xla_sort, while ops exactly
-    ``convert_while_count`` (= the merge-round/digit-pass structure of
-    ``merge_round_count``, plus the pointer-build rank search when — and
-    only when — ``pointer_reindex_strategy`` resolves it unfused; the
+def convert_expectation(cfg: EngineConfig, w: Workload) -> Expectation:
+    """The census ``costmodel`` prices for this (cfg, workload):
+    scatter-free always, ONE native sort (either key scheme), while ops
+    exactly ``convert_while_count`` (the pointer-build rank search when —
+    and only when — ``pointer_reindex_strategy`` resolves it unfused; the
     fused epilogue unrolls the search rounds to zero whiles)."""
-    forbidden = ("scatter",)
-    if strategy != "xla_sort":
-        forbidden = ("scatter", "sort")
     return Expectation(
-        forbidden_ops=forbidden,
+        forbidden_ops=("scatter",),
         required_ops=("gather",),
-        while_count=convert_while_count(cfg, w, strategy),
-        sort_count=sort_op_count(cfg, w, strategy),
+        while_count=convert_while_count(cfg, w),
+        sort_count=1,
     )
 
 
 def convert_cases(grid: str = "full") -> list[Case]:
-    """The tentpole sweep: every library config × the workload grid × every
-    sort strategy (strategy forced, so all three programs are checked for
-    every config — ``auto`` would only check the model's winner)."""
+    """The tentpole sweep: every library config × the workload grid."""
     if grid == "smoke":
         workloads, configs = SMOKE_WORKLOADS, SMOKE_CONFIGS
     else:
         workloads = CONVERT_WORKLOADS
         configs = tuple(bitstream_library()) + EXTRA_CONFIGS
-    cases = []
-    for w in workloads:
-        for base in configs:
-            for strategy in SORT_STRATEGIES:
-                cfg = dataclasses.replace(base, sort_strategy=strategy)
-                cases.append(Case(
-                    contract="convert",
-                    label=f"{cfg.key} n={w.n} e={w.e}",
-                    cfg=cfg, workload=w, strategy=strategy,
-                    structure=convert_structure(cfg, w, strategy),
-                    expect=convert_expectation(cfg, w, strategy)))
-    return cases
+    return [Case(contract="convert", label=f"{cfg.key} n={w.n} e={w.e}",
+                 cfg=cfg, workload=w, structure=convert_structure(cfg, w),
+                 expect=convert_expectation(cfg, w))
+            for w in workloads for cfg in configs]
 
 
 SAMPLE_FANOUTS = (2, 2)
@@ -191,56 +155,36 @@ SAMPLE_BATCH = 8
 
 
 def _sample_case_workload() -> Workload:
-    """The graph-level workload of the registered sample cases — its
-    (l, k, b) are the Table-I sampling knobs the capacity helpers read."""
+    """The graph-level workload of the registered sample cases."""
     return Workload(n=200, e=2048, l=len(SAMPLE_FANOUTS),
                     k=max(SAMPLE_FANOUTS), b=SAMPLE_BATCH)
 
 
-def _sample_sub_workload() -> Workload:
-    """The padded subgraph ``sample_subgraph`` re-converts: capacity is the
-    pow2 bucket of the sampled edge count, VID space is the node budget
-    (seeds + every frontier) — the exact ``costmodel.sample_vid_capacity``
-    / ``sample_edge_capacity`` arithmetic, so the contract and the model
-    price the same buffers."""
-    w = _sample_case_workload()
-    return Workload(n=sample_vid_capacity(w), e=sample_edge_capacity(w))
+# Native sorts in one ``sample_subgraph`` lane: the ONE shared sort of
+# the collected VID list (packed ``vid << pos`` key) plus the sub-COO
+# re-conversion's Ordering. vmap batches ops instead of replicating them,
+# so the slot-batched serve step has the same census.
+SAMPLE_SORTS = 2
 
 
-def sample_expectation(cfg: EngineConfig, strategy: str) -> Expectation:
+def sample_expectation() -> Expectation:
     """``sample_subgraph``'s program: Selecting + Reindexing + the sub-COO
     re-conversion. The RNG primitives lower to while loops (threefry), so
     the while census is not model-owned here; the contract pins what IS
-    priced: scatter-free relocation and the exact native-sort census.
-
-    Reindexing rides the spine since the fused-SCR-epilogue refit: the VID
-    list is sorted by ONE shared strategy-dispatched sort (replacing the
-    old pair of private argsorts), so it contributes exactly
-    ``reindex_sort_op_count`` native sorts — 1 on the xla_sort strategy,
-    0 on the radix strategies — on top of the sub-convert's own census."""
-    sub = _sample_sub_workload()
-    sub_sorts = sort_op_count(cfg, sub, strategy)
-    reindex_sorts = reindex_sort_op_count(
-        cfg, _sample_case_workload().n, next_pow2(sub.n))
+    priced: scatter-free relocation and the exact native-sort census."""
     return Expectation(
         forbidden_ops=("scatter",),
         required_ops=("gather",),
-        sort_count=reindex_sorts + sub_sorts,
+        sort_count=SAMPLE_SORTS,
     )
 
 
 def sample_cases(grid: str = "full") -> list[Case]:
-    w = _sample_case_workload()
-    cases = []
-    for strategy in SORT_STRATEGIES:
-        cfg = EngineConfig(w_upe=256, n_upe=8, sort_strategy=strategy)
-        cases.append(Case(
-            contract="sample",
-            label=f"{cfg.key} fanouts={SAMPLE_FANOUTS} b={SAMPLE_BATCH}",
-            cfg=cfg, workload=w, strategy=strategy,
-            structure=("sample", strategy),
-            expect=sample_expectation(cfg, strategy)))
-    return cases
+    cfg = EngineConfig()
+    return [Case(contract="sample",
+                 label=f"{cfg.key} fanouts={SAMPLE_FANOUTS} b={SAMPLE_BATCH}",
+                 cfg=cfg, workload=_sample_case_workload(),
+                 structure=("sample",), expect=sample_expectation())]
 
 
 GNN_SERVE_FANOUTS = (3, 2)  # = configs.graphsage_reddit smoke sample_sizes
@@ -254,47 +198,29 @@ def _gnn_serve_workload() -> Workload:
                     k=max(GNN_SERVE_FANOUTS), b=GNN_SERVE_SEED_CAP)
 
 
-def _gnn_serve_sub_workload() -> Workload:
-    """The padded per-lane subgraph the slot re-converts — the same
-    ``sample_vid_capacity``/``sample_edge_capacity`` arithmetic as the
-    sample contract, so both price the same buffers."""
-    w = _gnn_serve_workload()
-    return Workload(n=sample_vid_capacity(w), e=sample_edge_capacity(w))
-
-
-def gnn_serve_expectation(cfg: EngineConfig, strategy: str) -> Expectation:
+def gnn_serve_expectation() -> Expectation:
     """The ``GnnServeEngine`` step: every occupied slot's whole
     sample → reindex/re-convert → feature gather → forward → argmax as vmap
     lanes of ONE program. vmap batches ops instead of replicating them, so
-    the step's native-sort census equals ONE lane's — exactly the sample
-    contract's ``reindex_sort_op_count + sort_op_count`` arithmetic — and
-    the forward must ride the pointer-based segment reduction (cumsum +
-    boundary gathers), never ``scatter``: a ``jax.ops.segment_sum`` in the
-    batched forward would lower to scatter and fail here. RNG threefry
-    whiles are unasserted, as in the sample contract."""
-    sub = _gnn_serve_sub_workload()
+    the step's native-sort census equals ONE lane's (``SAMPLE_SORTS``),
+    and the forward must ride the pointer-based segment reduction (cumsum
+    + boundary gathers), never ``scatter``: a ``jax.ops.segment_sum`` in
+    the batched forward would lower to scatter and fail here. RNG
+    threefry whiles are unasserted, as in the sample contract."""
     return Expectation(
         forbidden_ops=("scatter",),
         required_ops=("gather",),
-        sort_count=(reindex_sort_op_count(cfg, _gnn_serve_workload().n,
-                                          next_pow2(sub.n))
-                    + sort_op_count(cfg, sub, strategy)),
+        sort_count=SAMPLE_SORTS,
     )
 
 
 def gnn_serve_cases(grid: str = "full") -> list[Case]:
-    w = _gnn_serve_workload()
-    cases = []
-    for strategy in SORT_STRATEGIES:
-        cfg = EngineConfig(w_upe=256, n_upe=8, sort_strategy=strategy)
-        cases.append(Case(
-            contract="gnn_serve",
-            label=(f"{cfg.key} fanouts={GNN_SERVE_FANOUTS} "
-                   f"cap={GNN_SERVE_SEED_CAP}"),
-            cfg=cfg, workload=w, strategy=strategy,
-            structure=("gnn_serve", strategy),
-            expect=gnn_serve_expectation(cfg, strategy)))
-    return cases
+    cfg = EngineConfig()
+    return [Case(contract="gnn_serve",
+                 label=(f"{cfg.key} fanouts={GNN_SERVE_FANOUTS} "
+                        f"cap={GNN_SERVE_SEED_CAP}"),
+                 cfg=cfg, workload=_gnn_serve_workload(),
+                 structure=("gnn_serve",), expect=gnn_serve_expectation())]
 
 
 # Delta grid: the convert smoke graph at two delta buckets, plus the
@@ -307,90 +233,77 @@ DELTA_WORKLOADS = (
 SMOKE_DELTA_WORKLOADS = ((Workload(n=200, e=2048), 64),)
 
 
-def delta_structure(cfg: EngineConfig, w: Workload, d_cap: int,
-                    strategy: str) -> tuple:
+# Native sorts in the compiled merge path: one per delta stream (inserts,
+# deletes; packed key or one two-key sort) plus the ONE event-zip rung,
+# which doubles as the materialization barrier. Nothing else may sort
+# (the existing stream never re-sorts; that is the point).
+DELTA_SORTS = 3
+
+
+def delta_structure(cfg: EngineConfig, w: Workload, d_cap: int) -> tuple:
     """Program-identity key for the compiled ``apply_delta`` merge path:
-    shapes (n, e_cap, delta bucket), the delta sorts' pass count and
-    strategy knobs, and the rank passes' fused/unfused lowering."""
+    shapes (n, e_cap, delta bucket), the delta sorts' key scheme, and the
+    rank passes' fused/unfused lowering."""
     wd = delta_workload(w, d_cap)
     fused = delta_epilogue_strategy(cfg, w, d_cap) == "fused"
-    if strategy == "xla_sort":
-        extra: tuple = ()
-    else:
-        chunk = min(cfg.w_upe, wd.e)
-        extra = (chunk, cfg.radix_bits, cfg.merge_fan_in)
-    return (("delta", strategy, sort_pass_count(cfg, wd), w.n,
-             next_pow2(w.e), wd.e, fused) + extra)
+    return ("delta", sort_pass_count(cfg, wd), w.n, next_pow2(w.e), wd.e,
+            fused)
 
 
-def delta_expectation(cfg: EngineConfig, w: Workload, d_cap: int,
-                      strategy: str) -> Expectation:
+def delta_expectation(cfg: EngineConfig, w: Workload,
+                      d_cap: int) -> Expectation:
     """The incremental-conversion census the Table-I delta terms price:
     scatter-free like the whole spine (tombstones compact through the
-    rank/gather router), while ops exactly ``delta_while_count`` (ZERO on
-    the resolved program: native delta sorts + fused rank passes — the
-    whole merge is while-free), native sorts exactly
-    ``delta_sort_op_count`` (2 delta streams × passes, plus the ONE
-    event-zip merge rung, which is always a native sort: it doubles as
-    the materialization barrier against elemental re-evaluation of the
-    event table inside the splice gathers)."""
+    rank/gather router), while ops exactly ``delta_while_count`` (ZERO
+    with fused rank passes — the whole merge is while-free), native sorts
+    exactly ``DELTA_SORTS``."""
     return Expectation(
         forbidden_ops=("scatter",),
         required_ops=("gather", "sort"),
-        while_count=delta_while_count(cfg, w, d_cap, strategy),
-        sort_count=delta_sort_op_count(cfg, w, d_cap, strategy),
+        while_count=delta_while_count(cfg, w, d_cap),
+        sort_count=DELTA_SORTS,
     )
 
 
 def delta_cases(grid: str = "full") -> list[Case]:
-    """The delta sweep: every sort strategy forced (as in the convert
-    contract) × both rank lowerings, over the delta workload grid."""
+    """The delta sweep: both rank lowerings over the delta workload
+    grid."""
     points = SMOKE_DELTA_WORKLOADS if grid == "smoke" else DELTA_WORKLOADS
     reindex = ("auto",) if grid == "smoke" else ("auto", "unfused")
     cases = []
     for w, d_cap in points:
         for rs in reindex:
-            for strategy in SORT_STRATEGIES:
-                cfg = EngineConfig(sort_strategy=strategy,
-                                   reindex_strategy=rs)
-                cases.append(Case(
-                    contract="delta_update",
-                    label=f"{cfg.key} n={w.n} e={w.e} d={d_cap}",
-                    cfg=cfg, workload=w, strategy=strategy,
-                    structure=delta_structure(cfg, w, d_cap, strategy),
-                    expect=delta_expectation(cfg, w, d_cap, strategy),
-                    d_cap=d_cap))
+            cfg = EngineConfig(reindex_strategy=rs)
+            cases.append(Case(
+                contract="delta_update",
+                label=f"{cfg.key} n={w.n} e={w.e} d={d_cap}",
+                cfg=cfg, workload=w,
+                structure=delta_structure(cfg, w, d_cap),
+                expect=delta_expectation(cfg, w, d_cap), d_cap=d_cap))
     return cases
 
 
-def shard_expectation(cfg: EngineConfig, w: Workload, n_dev: int,
-                      strategy: str) -> Expectation:
+def shard_expectation(cfg: EngineConfig, w: Workload,
+                      n_dev: int) -> Expectation:
     """The sharded convert: scatter-free, while census from
-    ``shard_convert_while_count`` (local Ordering + 2 rank searches per
-    cross-device merge round + pointer build), collective bytes under
-    ``shard_collective_bytes_budget``. Native sorts are allowed — the
-    xla_sort strategy sorts inside the shard_map body."""
+    ``shard_convert_while_count`` (2 rank searches per cross-device merge
+    round + pointer build), collective bytes under
+    ``shard_collective_bytes_budget``. Each device sorts its span with a
+    native sort inside the shard_map body."""
     return Expectation(
         forbidden_ops=("scatter",),
         required_ops=("all-gather",),
-        while_count=shard_convert_while_count(cfg, w, n_dev, strategy),
+        while_count=shard_convert_while_count(cfg, w, n_dev),
         collective_ceiling=shard_collective_bytes_budget(cfg, w, n_dev),
     )
 
 
 def shard_cases(n_dev: int, grid: str = "full") -> list[Case]:
     w = Workload(n=200, e=2048)
-    cases = []
-    for strategy in SORT_STRATEGIES:
-        cfg = EngineConfig(w_upe=256, n_upe=8, sort_strategy=strategy)
-        cases.append(Case(
-            contract="shard",
-            label=f"{cfg.key} e={w.e} nd={n_dev}",
-            cfg=cfg, workload=w, strategy=strategy,
-            structure=("shard", strategy, n_dev),
-            expect=shard_expectation(cfg, w, n_dev, strategy),
-            n_dev=n_dev))
-    return cases
+    cfg = EngineConfig()
+    return [Case(contract="shard", label=f"{cfg.key} e={w.e} nd={n_dev}",
+                 cfg=cfg, workload=w, structure=("shard", n_dev),
+                 expect=shard_expectation(cfg, w, n_dev), n_dev=n_dev)]
 
 
 def serve_expectation() -> Expectation:
@@ -422,54 +335,18 @@ def registry_summary() -> dict:
         "convert_groups": len({c.structure for c in convert}),
         "delta_cases": len(delta_cases("full")),
         "workloads": [dataclasses.asdict(w) for w in CONVERT_WORKLOADS],
-        "strategies": list(SORT_STRATEGIES),
         "library_size": len(bitstream_library()),
     }
 
 
-def model_self_consistency(cfg: EngineConfig, w: Workload,
-                           strategy: str) -> str | None:
-    """Cross-check the census arithmetic against the model's own terms:
-    the ladder the census counts k² rank searches over must have exactly
-    the rounds ``merge_round_count`` prices, and the convert census's
-    pointer term must be the resolved SCR-epilogue strategy's dispatch
-    structure (fused ⇒ zero loop dispatches ⇒ zero extra whiles).
-    Returns an error string or None.
+def model_self_consistency(cfg: EngineConfig, w: Workload) -> str | None:
+    """Cross-check the model's own terms: the fused reindex epilogue must
+    price zero loop dispatches (the census's fused ⇒ zero whiles), and a
+    single-edge delta must always resolve to the merge path. Returns an
+    error string or None.
     """
-    from repro.core.costmodel import _merge_fan_ins
-    rounds = merge_round_count(cfg, w, strategy)
-    if strategy in ("global_radix", "xla_sort"):
-        want = 0
-    else:
-        want = sort_pass_count(cfg, w) * len(_merge_fan_ins(cfg, w))
-    if rounds != want:
-        return (f"merge_round_count={rounds} but the census ladder has "
-                f"{want} rounds")
-    ptr = (convert_while_count(cfg, w, strategy)
-           - sort_while_count(cfg, w, strategy))
-    ptr_strat = pointer_reindex_strategy(cfg, w)
-    if ptr != (0 if ptr_strat == "fused" else 1):
-        return (f"convert pointer while term {ptr} inconsistent with "
-                f"resolved pointer strategy {ptr_strat!r}")
     if reindex_dispatch_count("fused") != 0:
         return "fused reindex epilogue must price zero loop dispatches"
-    # Delta-term ties (priced at a canonical 64-edge bucket): the while
-    # census must decompose into the two delta-stream sorts plus the rank
-    # passes exactly as the resolved epilogue strategy dictates, the sort
-    # census must be the two streams' passes plus the ONE event-zip rung,
-    # and a single-edge delta must always resolve to the merge path.
-    from repro.core.delta import DELTA_RANK_PASSES
-    wd = delta_workload(w, 64)
-    ds = resolve_delta_sort_strategy(cfg, wd)
-    ranks = (0 if delta_epilogue_strategy(cfg, w, 64) == "fused"
-             else DELTA_RANK_PASSES)
-    if delta_while_count(cfg, w, 64) != \
-            2 * sort_while_count(cfg, wd, ds) + ranks:
-        return "delta while census inconsistent with its sort + rank terms"
-    if delta_sort_op_count(cfg, w, 64) != \
-            2 * sort_fn_op_count(cfg, wd, ds) + 1:
-        return ("delta sort census must be 2·stream passes + the event-zip "
-                "rung")
     # Below ~2048 edges both paths finish inside one dispatch quantum and
     # the model's fixed constants dominate either side of the tie, so the
     # mode assertion is only meaningful at real workload sizes.

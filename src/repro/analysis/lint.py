@@ -50,8 +50,8 @@ RULES = {r.rule_id: r for r in [
          "Python if/while branching on a jnp/lax expression",
          "Python control flow on a traced value either raises "
          "TracerBoolConversionError under jit or silently constant-folds "
-         "at trace time — the strategy dispatch in pipeline.convert must "
-         "stay host-side (resolve_sort_strategy on static metadata)."),
+         "at trace time — the epilogue dispatch in pipeline.convert must "
+         "stay host-side (pointer_reindex_strategy on static metadata)."),
     Rule("host-numpy-in-jit",
          "host numpy call inside a jax.jit-decorated function body",
          "np.* executes at trace time on host values: it constant-folds "

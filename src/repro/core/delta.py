@@ -9,10 +9,9 @@ positionally — every search the update issues runs either over the
 delta-sized streams or with delta-many queries; the existing edge array is
 never searched element-by-element, only streamed once at the end:
 
-1. one **delta sort** — ``stable_sort_by_key`` over just the delta stream
-   (packed ``(dst << bits) | src`` keys when the VID space fits int32, the
-   two-pass pair scheme otherwise — the same "auto" predicate as
-   ``ordering.edge_ordering``),
+1. one **delta sort** — ``ordering.edge_ordering`` over just the delta
+   stream (packed ``(dst << bits) | src`` keys when the VID space fits
+   int32, one two-key (dst, src) sort otherwise),
 2. **delete resolution** — each delete kills at most one matching existing
    edge (multiset semantics, misses are no-ops). Its victim's absolute slot
    is found by a two-level row search: ``ptr`` gathers bound the dst row,
@@ -38,9 +37,9 @@ is also inserted in the same delta removes a pre-existing copy if any,
 never the fresh insert). The result is bit-identical to a from-scratch
 ``pipeline.convert`` of the final edge list — the property
 tests/test_delta.py fuzzes — while the only sort in the program runs on
-the delta. Strategy/mode resolution lives above this layer
-(``pipeline.apply_delta`` via ``costmodel.resolve_delta_mode``), keeping
-this module model-free like ``ordering``.
+the delta. Mode resolution lives above this layer (``pipeline.apply_delta``
+via ``costmodel.resolve_delta_mode``), keeping this module model-free like
+``ordering``.
 """
 from __future__ import annotations
 
@@ -50,7 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from .graph import COO, CSC, SENTINEL, next_pow2, pad_to
-from .ordering import _bits_for, supports_packed_keys
+from .ordering import edge_ordering
 from .set_count import rank_in_sorted
 from .set_partition import prefix_sum, set_partition
 
@@ -122,7 +121,7 @@ def reconstruct_sorted_dst(csc: CSC, unroll: bool = False) -> jnp.ndarray:
     """Recover the sorted dst column the Reshaping consumed: slot j's dst
     is the number of pointer entries ≤ j, minus one (edges of vertex v
     occupy ``[ptr[v], ptr[v+1])``). Padded slots land at ``n_nodes`` — the
-    in-radix clip value every sort already uses for sentinels. One
+    clip value every sort already uses for sentinels. One
     E-query rank pass over the (n+1)-long pointer table; tolerant of
     pointer tails padded with ``ptr[-1]`` (the duplicates only inflate the
     clipped padding value). Only the rebuild fallback pays this — the
@@ -163,28 +162,16 @@ def _rank_in_rows(arr: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
     return l.astype(jnp.int32)
 
 
-def _sorted_delta_stream(dst, src, n_nodes: int, sort_fn):
-    """Sort one (dst, src) delta stream lexicographically: packed single
-    sort when the VID space fits an int32 key, the two-pass LSD pair
-    scheme otherwise — the same "auto" predicate as the full Ordering.
-    SENTINEL pads sort to the tail either way."""
-    bound = n_nodes
-    if supports_packed_keys(n_nodes):
-        bits = _bits_for(bound)
-        key_bound = (bound << bits) | bound
-        mask = (1 << bits) - 1
-        k = ((jnp.minimum(dst, jnp.int32(bound)) << bits)
-             | jnp.minimum(src, jnp.int32(bound)))
-        ks, _ = sort_fn(k, None, key_bound)  # pads restored to SENTINEL
-        pad = ks == SENTINEL
-        return (jnp.where(pad, SENTINEL, ks >> bits).astype(jnp.int32),
-                jnp.where(pad, SENTINEL, ks & mask).astype(jnp.int32))
-    s1, d1 = sort_fn(src, dst, bound)
-    d2, s2 = sort_fn(d1, s1, bound)
-    return d2, s2
+def _sorted_delta_stream(dst, src, n_nodes: int):
+    """Sort one (dst, src) delta stream lexicographically — the full
+    Ordering (``edge_ordering``, "auto" key scheme) on delta-sized
+    columns. SENTINEL pads sort to the tail."""
+    out = edge_ordering(COO(dst=dst, src=src, n_edges=jnp.int32(0),
+                            n_nodes=n_nodes))
+    return out.dst, out.src
 
 
-def _delete_positions(csc: CSC, delta: EdgeDelta, *, sort_fn):
+def _delete_positions(csc: CSC, delta: EdgeDelta):
     """Resolve the delete stream to tombstone *positions*: sorted absolute
     slots of the victims in the existing CSC (SENTINEL-padded tail), plus
     the effective delete count. Each delete kills at most one copy — its
@@ -192,7 +179,7 @@ def _delete_positions(csc: CSC, delta: EdgeDelta, *, sort_fn):
     key's multiplicity, read off two bounded row ranks. All delta-sized."""
     n = csc.n_nodes
     d_cap = delta.capacity
-    dd, ds = _sorted_delta_stream(delta.del_dst, delta.del_src, n, sort_fn)
+    dd, ds = _sorted_delta_stream(delta.del_dst, delta.del_src, n)
     k = jnp.arange(d_cap, dtype=jnp.int32)
     row = jnp.clip(dd, 0, n - 1)
     lo = jnp.take(csc.ptr, row, mode="clip")
@@ -212,16 +199,13 @@ def _delete_positions(csc: CSC, delta: EdgeDelta, *, sort_fn):
     return pos, jnp.sum(valid.astype(jnp.int32)).astype(jnp.int32)
 
 
-def delta_merge(csc: CSC, delta: EdgeDelta, *, sort_fn,
-                unroll: bool = False,
+def delta_merge(csc: CSC, delta: EdgeDelta, *, unroll: bool = False,
                 out_capacity: int | None = None) -> CSC:
     """Splice one EdgeDelta into a sorted CSC — the O(delta) update path.
 
-    ``sort_fn(keys, vals, key_bound) -> (keys, vals)`` is the ONE global
-    stable sorter (strategy-resolved by the caller on the *delta*
-    workload) this path invokes, and only on delta-sized streams; the
-    existing edges never re-sort and are never searched element-by-element
-    — every binary search either issues delta-many queries (delete row
+    Sorts run only on delta-sized streams; the existing edges never
+    re-sort and are never searched element-by-element — every binary
+    search either issues delta-many queries (delete row
     ranks) or runs over a delta-sized table (the event rank that drives
     the splice). ``unroll`` selects the fused SCR epilogue for the
     :data:`DELTA_RANK_PASSES` full-width rank passes (statically unrolled
@@ -253,10 +237,10 @@ def delta_merge(csc: CSC, delta: EdgeDelta, *, sort_fn,
     k = jnp.arange(d_cap, dtype=jnp.int32)
 
     # -------- deletes → sorted tombstone positions (delta-sized)
-    pos, n_del_eff = _delete_positions(csc, delta, sort_fn=sort_fn)
+    pos, n_del_eff = _delete_positions(csc, delta)
 
     # -------- inserts → output slots (delta-sized)
-    bd, bs = _sorted_delta_stream(delta.ins_dst, delta.ins_src, n, sort_fn)
+    bd, bs = _sorted_delta_stream(delta.ins_dst, delta.ins_src, n)
     valid_i = (k < delta.n_ins) & (bd < n) & (bs < n)
     pairs, _ = set_partition(jnp.stack([bd, bs], axis=1), valid_i)
     n_ins_eff = jnp.sum(valid_i.astype(jnp.int32)).astype(jnp.int32)
@@ -330,7 +314,7 @@ def delta_merge(csc: CSC, delta: EdgeDelta, *, sort_fn,
                n_edges=n_edges_new, n_nodes=n)
 
 
-def rebuild_coo(csc: CSC, delta: EdgeDelta, *, sort_fn,
+def rebuild_coo(csc: CSC, delta: EdgeDelta, *,
                 unroll: bool = False) -> COO:
     """The fallback's front half: apply deletes as SENTINEL tombstones and
     concatenate the inserts into one pow2 COO for a full re-convert
@@ -338,13 +322,13 @@ def rebuild_coo(csc: CSC, delta: EdgeDelta, *, sort_fn,
     model prices the delta as a large-enough graph fraction that the
     positional splice loses to one full sort).
 
-    Shares the positional delete matching with :func:`delta_merge`
-    (``sort_fn`` sorts only the delete stream here); tombstones need no
+    Shares the positional delete matching with :func:`delta_merge` (only
+    the delete stream is sorted here); tombstones need no
     compaction — the full sort pushes SENTINEL rows to the tail itself.
     """
     n = csc.n_nodes
     e_cap = csc.idx.shape[0]
-    pos, n_del_eff = _delete_positions(csc, delta, sort_fn=sort_fn)
+    pos, n_del_eff = _delete_positions(csc, delta)
     d_ex = reconstruct_sorted_dst(csc, unroll=unroll)
     slot = jnp.arange(e_cap, dtype=jnp.int32)
     hit = jnp.take(pos, rank_in_sorted(pos, slot, side="left",

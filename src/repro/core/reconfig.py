@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from .costmodel import (Calibration, EngineConfig, Workload, best_config,
+from .costmodel import (Calibration, EngineConfig, Workload,
                         bitstream_library, choose_config, estimate_seconds)
 
 # Paper: 230 ms full reconfig; halved when only one region changes.
@@ -50,9 +50,9 @@ def decide(w: Workload, current: EngineConfig | None,
     """DynPre's decision rule: score the library, switch when the predicted
     gain over the current configuration amortizes the reconfiguration.
     (Shared by ``DynPre`` and ``repro.engine.service.PreprocService``.)
-    The candidate carries a concrete ``sort_strategy`` (``choose_config``
-    pins the Table-I winner), so the dispatched program is the one the
-    model priced."""
+    The candidate carries a concrete ``reindex_strategy``
+    (``choose_config`` pins the Table-I winner), so the dispatched program
+    is the one the model priced."""
     cand = choose_config(w, library, cal)
     if current is None:
         return ReconfigDecision(True, cand, float("inf"), reconfig_cost_s)

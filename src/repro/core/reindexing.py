@@ -5,12 +5,11 @@ the convert spine's own machinery instead of private argsort round-trips:
 
 1. **One shared sort.** Pack ``(vid << pos_bits) | pos`` into a single
    int32 key (the position in the low bits makes ANY sort stable and
-   carries the payload for free) and run ONE strategy-dispatched
+   carries the payload for free) and run ONE keys-only
    ``ordering.stable_sort_by_key`` over the whole collected VID list —
-   the same chunked_merge / global_radix / xla_sort machinery the edge
-   Ordering uses, keys-only. When the VID space is too wide to pack
-   (``bits(vid_bound) + bits(cap-1) > 31``) the same sorter runs once in
-   pair mode (position payload).
+   the native sort the edge Ordering uses. When the VID space is too wide
+   to pack (``bits(vid_bound) + bits(cap-1) > 31``) the same sorter runs
+   once in pair mode (position payload).
 2. **Rank arithmetic instead of a second sort.** The old path argsorted
    the first-occurrence positions and inverted that permutation with a
    scatter. Now: one left-rank pass of the original VIDs against the
@@ -25,9 +24,8 @@ the convert spine's own machinery instead of private argsort round-trips:
    table, i.e. the SCR filter-tree query expressed on sorted data.
 
 Every rank pass runs ``fused`` (statically unrolled search rounds — zero
-while ops, no loop dispatch between rounds; the Pallas epilogue kernels in
-``kernels/reindex_epilogue.py`` execute them over VMEM-resident sorted
-tiles) or ``unfused`` (``fori_loop`` rank searches). Both are
+while ops, no loop dispatch between rounds) or ``unfused`` (``fori_loop``
+rank searches). Both are
 bit-identical; ``EngineConfig.reindex_strategy`` selects, priced by
 ``costmodel.resolve_reindex_strategy``.
 
@@ -36,10 +34,9 @@ counter-based numbering; a ``sorted`` order is also available.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from .graph import COO, SENTINEL, next_pow2
+from .graph import COO, SENTINEL
 from .ordering import _bits_for, stable_sort_by_key
 from .set_count import rank_in_sorted
 from .set_partition import prefix_sum
@@ -71,73 +68,57 @@ class ReindexMap:
     """
 
     def __init__(self, sorted_vids, slot_to_new, order, n_unique,
-                 unroll: bool = False, rank_fn=None, rename_fn=None):
+                 unroll: bool = False):
         self.sorted_vids = sorted_vids
         self.slot_to_new = slot_to_new
         self.order = order
         self.n_unique = n_unique
         self.unroll = unroll
-        self.rank_fn = rank_fn
-        self.rename_fn = rename_fn
 
     def lookup(self, vids: jnp.ndarray) -> jnp.ndarray:
         """Original VIDs → new VIDs (SENTINEL where not in the map).
 
         rank = set-count(sorted stream < vid); hit test = one comparator;
-        the new VID is a gather from the slot table. ``rename_fn`` (the
-        Pallas rename-epilogue kernel) fuses all three over VMEM-resident
-        sorted tiles.
+        the new VID is a gather from the slot table.
         """
-        if self.rename_fn is not None:
-            return self.rename_fn(self.sorted_vids, self.slot_to_new, vids)
-        if self.rank_fn is not None:
-            rank = self.rank_fn(self.sorted_vids, vids, "left")
-        else:
-            rank = rank_in_sorted(self.sorted_vids, vids, side="left",
-                                  unroll=self.unroll)
+        rank = rank_in_sorted(self.sorted_vids, vids, side="left",
+                              unroll=self.unroll)
         rank_c = jnp.clip(rank, 0, self.sorted_vids.shape[0] - 1)
         hit = jnp.take(self.sorted_vids, rank_c, mode="clip") == vids
         new = jnp.take(self.slot_to_new, rank_c, mode="clip")
         return jnp.where(hit & (vids != SENTINEL), new, SENTINEL)
 
 
-def _sort_vid_stream(vids: jnp.ndarray, vid_bound: int | None, sort_fn,
+def _sort_vid_stream(vids: jnp.ndarray, vid_bound: int | None
                      ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The ONE shared sort: → (sorted vids, their original positions).
 
     Packed single-stream when the key fits (position in the low bits =
-    free stability + free payload); pair mode otherwise. ``sort_fn(keys,
-    vals, key_bound) -> (keys, vals)`` is the strategy-dispatched global
-    sorter (default: ``stable_sort_by_key``).
+    free stability + free payload); pair mode otherwise.
     """
     n = vids.shape[0]
-    m = next_pow2(n)  # the sorter's chunk/tile machinery wants pow2
-    vp = jnp.pad(vids, (0, m - n), constant_values=int(SENTINEL))
-    pos = jnp.arange(m, dtype=jnp.int32)
+    pos = jnp.arange(n, dtype=jnp.int32)
     bound = SENTINEL if vid_bound is None else int(vid_bound)
-    if vid_bound is not None and reindex_supports_packed(bound, m):
-        pb = _pos_bits(m)
+    if vid_bound is not None and reindex_supports_packed(bound, n):
+        pb = _pos_bits(n)
         # sentinels/out-of-range clip to bound → past key_bound → restored
         # to SENTINEL by the sorter's clip/restore contract
-        v = jnp.minimum(vp, jnp.int32(bound))
-        packed = (v << pb) | pos
-        pk, _ = sort_fn(packed, None, bound << pb)
+        v = jnp.minimum(vids, jnp.int32(bound))
+        pk, _ = stable_sort_by_key((v << pb) | pos, None, bound << pb)
         valid = pk != SENTINEL
         sv = jnp.where(valid, pk >> pb, SENTINEL)
         sp = jnp.where(valid, pk & ((1 << pb) - 1), n - 1)
     else:
         # pair fallback: sort by vid with the position riding as payload
         # (stable, so positions stay ascending inside each run)
-        sv, sp = sort_fn(vp, pos, bound)
+        sv, sp = stable_sort_by_key(vids, pos, bound)
         sp = jnp.where(sv != SENTINEL, sp, n - 1)
-    # padding is pure SENTINEL → sorts to the tail; drop it
-    return sv[:n], sp[:n]
+    return sv, sp
 
 
 def build_reindex_map(vids: jnp.ndarray, numbering: str = "first_occurrence",
                       vid_bound: int | None = None,
-                      strategy: str = "unfused", sort_fn=None,
-                      rank_fn=None, rename_fn=None) -> ReindexMap:
+                      strategy: str = "unfused") -> ReindexMap:
     """Build the mapping from a (duplicated, SENTINEL-padded) VID list.
 
     ``vid_bound``: static exclusive upper bound on valid VIDs (the graph's
@@ -146,29 +127,20 @@ def build_reindex_map(vids: jnp.ndarray, numbering: str = "first_occurrence",
     unrolled rank rounds, zero while ops) or ``"unfused"`` (fori_loop rank
     searches) — resolved ABOVE this layer (``costmodel
     .resolve_reindex_strategy`` via ``pipeline.sample_subgraph``), keeping
-    Reindexing itself model-free exactly like Ordering. ``sort_fn``
-    overrides the shared sorter (the pipeline passes the cfg-configured
-    ``stable_sort_by_key``); ``rank_fn(sorted, queries, side)`` /
-    ``rename_fn(sorted, table, queries)`` swap in the Pallas epilogue
-    kernels.
+    Reindexing itself model-free exactly like Ordering.
     """
     if numbering not in ("first_occurrence", "sorted"):
         raise ValueError(numbering)
     if strategy not in ("fused", "unfused"):
         raise ValueError(strategy)
     unroll = strategy == "fused"
-    if sort_fn is None:
-        def sort_fn(k, v, bound):
-            return stable_sort_by_key(k, v, bound, strategy="xla_sort")
 
-    def rank(arr, q, side="left"):
-        if rank_fn is not None:
-            return rank_fn(arr, q, side)
-        return rank_in_sorted(arr, q, side=side, unroll=unroll)
+    def rank(arr, q):
+        return rank_in_sorted(arr, q, side="left", unroll=unroll)
 
     n = vids.shape[0]
     pos = jnp.arange(n, dtype=jnp.int32)
-    sv, sp = _sort_vid_stream(vids, vid_bound, sort_fn)
+    sv, sp = _sort_vid_stream(vids, vid_bound)
     valid = sv != SENTINEL
     is_head = valid & jnp.concatenate(
         [jnp.ones((1,), bool), sv[1:] != sv[:-1]])
@@ -203,7 +175,7 @@ def build_reindex_map(vids: jnp.ndarray, numbering: str = "first_occurrence",
             pos < n_unique,
             jnp.take(sv, jnp.clip(src, 0, n - 1), mode="clip"), SENTINEL)
     return ReindexMap(sv, slot_to_new, order, n_unique.astype(jnp.int32),
-                      unroll=unroll, rank_fn=rank_fn, rename_fn=rename_fn)
+                      unroll=unroll)
 
 
 def reindex_edges(rmap: ReindexMap, edge_dst: jnp.ndarray,

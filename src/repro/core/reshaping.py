@@ -14,15 +14,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .graph import COO, CSC, SENTINEL, pad_to
+from .graph import COO, CSC, pad_to
 from .set_count import count_less_than
 
 
 def build_pointer_array(sorted_dst: jnp.ndarray, n_nodes: int,
                         ptr_capacity: int | None = None,
                         count_fn=None, block: int = 2048,
-                        method: str = "sorted", unroll: bool = False,
-                        rank_fn=None) -> jnp.ndarray:
+                        method: str = "sorted", unroll: bool = False
+                        ) -> jnp.ndarray:
     """Pointer array via set-counting.
 
     ``method="sorted"`` (default): the paper's reshaper *consumes the sorted
@@ -39,19 +39,14 @@ def build_pointer_array(sorted_dst: jnp.ndarray, n_nodes: int,
     ``unroll=True`` is the fused SCR epilogue: the rank search's rounds
     unroll statically so the pointer build adds ZERO while ops to the
     convert program (dispatched by ``costmodel.pointer_reindex_strategy``).
-    ``rank_fn(sorted, targets, side)`` swaps in the Pallas rank-epilogue
-    kernel (``kernels/reindex_epilogue.py``), which runs the same unrolled
-    search over VMEM-resident sorted tiles; it outranks ``count_fn``.
 
     The full-graph convert calls this only where it is lowered for the
-    CPU or a Pallas route is set; on a TPU ``pipeline.pointer_array``
+    CPU or a ``count_fn`` is given; on a TPU ``pipeline.pointer_array``
     takes the windowed SCR kernel (``kernels/pointer_window.py``).
     Subgraph re-conversion and the delta rebuild keep this path.
     """
     targets = jnp.arange(n_nodes + 1, dtype=jnp.int32)
-    if rank_fn is not None:
-        ptr = rank_fn(sorted_dst, targets, "left")
-    elif count_fn is not None:
+    if count_fn is not None:
         ptr = count_fn(sorted_dst, targets)
     elif method == "sorted":
         from .set_count import rank_in_sorted
@@ -91,23 +86,18 @@ def build_pointer_array_serial(sorted_dst: jnp.ndarray, n_nodes: int
 
 
 def data_reshaping(sorted_coo: COO, ptr_capacity: int | None = None,
-                   count_fn=None, unroll: bool = False,
-                   rank_fn=None) -> CSC:
+                   count_fn=None, unroll: bool = False) -> CSC:
     """Sorted COO → CSC (pointer array + index array = the sorted src column)."""
     ptr = build_pointer_array(sorted_coo.dst, sorted_coo.n_nodes,
                               ptr_capacity=ptr_capacity, count_fn=count_fn,
-                              unroll=unroll, rank_fn=rank_fn)
+                              unroll=unroll)
     return CSC(ptr=ptr, idx=sorted_coo.src, n_edges=sorted_coo.n_edges,
                n_nodes=sorted_coo.n_nodes)
 
 
-def graph_convert(coo: COO, chunk: int | None = None, count_fn=None,
-                  chunk_sort_fn=None, ptr_capacity: int | None = None) -> CSC:
-    """Full graph conversion = Ordering + Reshaping (paper Fig. 3).
-
-    ``chunk=None`` resolves to ``ordering.DEFAULT_CHUNK`` — the one routed
-    chunk-width default shared with ``EngineConfig.w_upe``."""
+def graph_convert(coo: COO, count_fn=None,
+                  ptr_capacity: int | None = None) -> CSC:
+    """Full graph conversion = Ordering + Reshaping (paper Fig. 3)."""
     from .ordering import edge_ordering
-    sorted_coo = edge_ordering(coo, chunk=chunk, chunk_sort_fn=chunk_sort_fn)
-    return data_reshaping(sorted_coo, ptr_capacity=ptr_capacity,
+    return data_reshaping(edge_ordering(coo), ptr_capacity=ptr_capacity,
                           count_fn=count_fn)

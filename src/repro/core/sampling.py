@@ -14,7 +14,7 @@ TPU adaptation (DESIGN.md §2.2):
   (k ≤ 25 comparators per node, the SCR in miniature). Exactly uniform
   k-subsets, no degree cap, k sequential steps (k is small and fixed).
 * ``keysort``: attach a random key to each neighbor in a bounded window and
-  take the top-k smallest — one pass, the radix/UPE primitive does the sort.
+  take the top-k smallest — one pass, one sort.
   Exact when window ≥ max degree (set ``window`` accordingly in configs).
 * ``reservoir``: the conventional baseline (paper Table IV) — sequential
   reservoir sampling, data-dependent loop bounded by ``window``. Kept for the

@@ -11,8 +11,10 @@ log-depth binary search per slot. The relocation then lowers to ``jnp.take``
 (a gather), which shards under GSPMD and compiles to Mosaic cleanly, unlike
 the ``.at[dest].set`` scatter or the O(N²) one-hot MXU matmul it replaces.
 
-These jnp implementations are the *algorithmic* contribution in portable form;
-the Pallas kernels tile the same math through VMEM.
+These jnp implementations are the *algorithmic* contribution in portable
+form. The Ordering itself sorts with the native comparison sort
+(``core/ordering.py``); these primitives serve the delta splice, the
+reindex compaction and the MoE / DLRM dispatch.
 """
 from __future__ import annotations
 
@@ -89,114 +91,6 @@ def gather_sources_from_counts(incl_counts: jnp.ndarray, base: jnp.ndarray
     return lo.astype(jnp.int32)
 
 
-def digit_relocation_sources(digit: jnp.ndarray, n_buckets: int,
-                             prefix_sum_fn=None
-                             ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(sources, bucket bases) for one radix digit pass — the full router.
-
-    One-hot → inclusive per-bucket prefix sums → exclusive bucket bases →
-    ``gather_sources_from_counts``. Shared by ``radix_partition``,
-    ``radix_sort_by_key`` and the Pallas UPE chunk-sort kernel (which
-    passes its own ``prefix_sum_fn`` — ``kernels.common.prefix_sum_tree``,
-    same ``(x, axis=0, exclusive=False)`` contract) so the router wiring
-    lives in exactly one place.
-    """
-    psum = prefix_sum_fn or prefix_sum
-    onehot = (digit[:, None]
-              == jnp.arange(n_buckets, dtype=digit.dtype)[None, :])
-    incl = psum(onehot.astype(jnp.int32), axis=0)  # [N, B] inclusive
-    counts = incl[-1]  # [B]
-    base = psum(counts) - counts  # exclusive over buckets
-    return gather_sources_from_counts(incl, base), base.astype(jnp.int32)
-
-
-def tiled_digit_sources(digit: jnp.ndarray, n_buckets: int, tile: int,
-                        prefix_sum_fn=None) -> jnp.ndarray:
-    """Global one-digit-pass relocation sources via TWO-LEVEL rank arithmetic.
-
-    The flat router above needs the full [N, B] inclusive-count matrix; one
-    global digit pass over a large edge array would binary-search a N·B-entry
-    table per slot. This splits the pass the way the hardware does: every
-    ``tile``-sized span runs the flat router *locally* (the UPE working set),
-    and the global position of output slot j is pure rank arithmetic over the
-    small [T, B] per-tile histogram tables —
-
-      bucket  b  = last bucket whose global base is ≤ j
-      rank    r  = j - gbase[b]
-      tile    t  = first tile with inclusive-over-tiles count[t, b] ≥ r+1
-                   (log₂ T binary-search rounds over the [T, B] table)
-      source     = t·tile + local_sources[t][lbase[t, b] + (r - excl[t, b])]
-
-    — because a stable digit pass orders bucket-major then (tile, in-tile
-    position): the two-level composition IS the global stable partition.
-    One composed gather permutation per pass, no [N, B] materialization, no
-    scatter. This is the relocation behind the ``global_radix`` Ordering
-    strategy (zero merge rounds; see ``ordering.global_radix_sort_by_key``).
-    """
-    n = digit.shape[0]
-    if tile >= n:
-        return digit_relocation_sources(digit, n_buckets,
-                                        prefix_sum_fn=prefix_sum_fn)[0]
-    assert n % tile == 0, (n, tile)
-    psum = prefix_sum_fn or prefix_sum
-    d = digit.reshape(-1, tile)  # [T, tile]
-    local_src, lbase = jax.vmap(
-        lambda dd: digit_relocation_sources(dd, n_buckets,
-                                            prefix_sum_fn=prefix_sum_fn))(d)
-    n_tiles = d.shape[0]
-    # per-tile histograms from the exclusive in-tile bases
-    hist = jnp.diff(jnp.concatenate(
-        [lbase, jnp.full((n_tiles, 1), tile, jnp.int32)], axis=1), axis=1)
-    incl_t = psum(hist, axis=0)  # [T, B] inclusive over tiles
-    excl_t = incl_t - hist
-    counts = incl_t[-1]  # [B]
-    gbase = psum(counts) - counts  # exclusive global bucket bases
-    part_src = rank_gather_sources(gbase, incl_t, excl_t, lbase, tile)
-    # compose with the in-tile permutation → sources into the ORIGINAL array
-    t = part_src // tile
-    return (t * tile
-            + jnp.take(local_src.reshape(-1), part_src, mode="clip"))
-
-
-def rank_gather_sources(gbase: jnp.ndarray, incl_t: jnp.ndarray,
-                        excl_t: jnp.ndarray, lbase: jnp.ndarray,
-                        tile: int, j: jnp.ndarray | None = None
-                        ) -> jnp.ndarray:
-    """Output slot → source in the tile-partitioned layout (rank arithmetic).
-
-    Inputs are the small per-tile tables of ``tiled_digit_sources``:
-    ``gbase`` [B] global bucket bases, ``incl_t``/``excl_t`` [T, B]
-    inclusive/exclusive over-tiles bucket counts, ``lbase`` [T, B] in-tile
-    bucket bases. The returned index addresses the array in which every tile
-    has already been locally partitioned (tile t spans [t·tile, (t+1)·tile)).
-    Every slot is independent — log₂ T static search rounds plus O(B)
-    comparisons — so ``j`` may be any subset of output slots: the Pallas
-    rank-gather kernel (kernels/radix_sort.py) calls this per output tile
-    with only the small tables VMEM-resident. ``j=None`` = all slots.
-    """
-    n_tiles, nb = incl_t.shape
-    n = n_tiles * tile
-    if j is None:
-        j = jnp.arange(n, dtype=jnp.int32)
-    b = jnp.sum((gbase[None, :] <= j[:, None]).astype(jnp.int32), axis=1) - 1
-    r = j - jnp.take(gbase, b, mode="clip")
-    target = r + 1
-    flat_incl = incl_t.reshape(-1)
-    lo = jnp.zeros(j.shape, jnp.int32)
-    hi = jnp.full(j.shape, n_tiles, jnp.int32)
-    for _ in range(max(1, int(n_tiles).bit_length())):  # static log T rounds
-        mid = (lo + hi) >> 1
-        pivot = jnp.take(flat_incl,
-                         jnp.clip(mid, 0, n_tiles - 1) * nb + b, mode="clip")
-        go_right = pivot < target
-        lo = jnp.where(go_right, mid + 1, lo)
-        hi = jnp.where(go_right, hi, mid)
-    t = lo
-    r_in_tile = r - jnp.take(excl_t.reshape(-1), t * nb + b, mode="clip")
-    return (t * tile + jnp.take(lbase.reshape(-1), t * nb + b, mode="clip")
-            + r_in_tile).astype(jnp.int32)
-
-
 def set_partition(values: jnp.ndarray, cond: jnp.ndarray
                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Stable partition of ``values`` by ``cond``; returns (partitioned, n_selected).
@@ -211,67 +105,3 @@ def set_partition(values: jnp.ndarray, cond: jnp.ndarray
     base = jnp.stack([jnp.int32(0), n_sel])
     src = gather_sources_from_counts(incl, base)
     return jnp.take(values, src, axis=0, mode="clip"), n_sel.astype(jnp.int32)
-
-
-def radix_partition(values: jnp.ndarray, keys: jnp.ndarray, n_buckets: int
-                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Multi-way stable partition by small integer ``keys`` in [0, n_buckets).
-
-    One LSD radix-sort digit pass = this operation (paper: "digit-wise passes
-    are precisely set-partitioning"). Returns (partitioned values, bucket
-    start offsets [n_buckets]).
-
-    The per-bucket inclusive prefix sums (B cooperating adder columns) feed
-    the gather router; relocation is one ``jnp.take``. All vectorized, no
-    atomics, no scatter.
-    """
-    src, base = digit_relocation_sources(keys, n_buckets)
-    return jnp.take(values, src, axis=0, mode="clip"), base
-
-
-def radix_sort_by_key(values: jnp.ndarray, keys: jnp.ndarray, key_bits: int,
-                      radix_bits: int = 4) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Full LSD radix sort of (keys, values) via repeated gather-routed
-    digit passes. Stable; ``key_bits`` bounds the key magnitude. This is the
-    reference algorithm the UPE chunk-sort kernel implements in VMEM.
-
-    Keys and values relocate through the same per-pass source permutation
-    (two gathers), so payload bytes are moved once per pass — the old
-    ``jnp.stack([k, v], axis=1)`` row-scatter doubled the moved bytes.
-    """
-    n_buckets = 1 << radix_bits
-    n_passes = max(1, -(-key_bits // radix_bits))  # ceil div
-
-    def body(carry, _):
-        k, v, shift = carry
-        digit = (k >> shift) & (n_buckets - 1)
-        src, _ = digit_relocation_sources(digit, n_buckets)
-        k2 = jnp.take(k, src, mode="clip")
-        v2 = jnp.take(v, src, axis=0, mode="clip")
-        return (k2, v2, shift + radix_bits), None
-
-    (k, v, _), _ = jax.lax.scan(
-        body, (keys, values, jnp.int32(0)), None, length=n_passes)
-    return k, v
-
-
-def radix_sort_keys(keys: jnp.ndarray, key_bits: int,
-                    radix_bits: int = 4) -> jnp.ndarray:
-    """Keys-only LSD radix sort — ``radix_sort_by_key`` without a payload.
-
-    The packed-key Ordering discards its payload after sorting (the packed
-    (dst, src) key IS the data), so routing only the keys through the
-    per-pass gather halves the bytes moved per digit pass.
-    """
-    n_buckets = 1 << radix_bits
-    n_passes = max(1, -(-key_bits // radix_bits))  # ceil div
-
-    def body(carry, _):
-        k, shift = carry
-        digit = (k >> shift) & (n_buckets - 1)
-        src, _ = digit_relocation_sources(digit, n_buckets)
-        return (jnp.take(k, src, mode="clip"), shift + radix_bits), None
-
-    (k, _), _ = jax.lax.scan(body, (keys, jnp.int32(0)), None,
-                             length=n_passes)
-    return k

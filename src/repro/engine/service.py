@@ -288,21 +288,18 @@ class PreprocService:
     def decide(self, w: Workload) -> ReconfigDecision:
         """Score ``w`` against the library (Table-I cost model) and decide
         whether the predicted gain amortizes the reconfiguration cost.
-        The candidate is a library entry with both dispatch axes resolved
-        (``costmodel.choose_config`` pins ``sort_strategy`` AND
-        ``reindex_strategy``), so the dispatched program — merge ladder,
-        radix passes and the fused-vs-looped SCR epilogue alike — is the
-        one the model priced.
+        The candidate is a library entry with its dispatch axis resolved
+        (``costmodel.choose_config`` pins ``reindex_strategy``), so the
+        dispatched program — the fused-vs-looped SCR epilogue — is the one
+        the model priced.
 
         Example::
 
             >>> import dataclasses
             >>> svc = PreprocService(fanouts=(2,))
             >>> d = svc.decide(Workload(n=100, e=1000, l=1, k=2, b=16))
-            >>> dataclasses.replace(d.config, sort_strategy="auto",
+            >>> dataclasses.replace(d.config,
             ...                     reindex_strategy="auto") in svc.library
-            True
-            >>> d.config.sort_strategy != "auto"  # pinned by the model
             True
             >>> d.config.reindex_strategy in ("fused", "unfused")
             True

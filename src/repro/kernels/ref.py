@@ -1,34 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
-
-
-def prefix_partition_ref(values, cond, block):
-    """Blockwise stable partition, numpy semantics."""
-    v = np.asarray(values)
-    c = np.asarray(cond)
-    n = v.shape[0]
-    out = np.empty_like(v)
-    nsel = []
-    for s in range(0, n, block):
-        vb, cb = v[s:s + block], c[s:s + block]
-        sel = vb[cb]
-        out[s:s + block] = np.concatenate([sel, vb[~cb]])
-        nsel.append(len(sel))
-    return out, np.array(nsel, np.int32)
-
-
-def radix_sort_chunks_ref(keys, values, chunk):
-    k = np.asarray(keys)
-    v = np.asarray(values)
-    ok, ov = k.copy(), v.copy()
-    for s in range(0, len(k), chunk):
-        order = np.argsort(k[s:s + chunk], kind="stable")
-        ok[s:s + chunk] = k[s:s + chunk][order]
-        ov[s:s + chunk] = v[s:s + chunk][order]
-    return ok, ov
 
 
 def set_count_less_ref(elements, targets):

@@ -12,7 +12,7 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
   PYTHONPATH=src python -m repro.launch.dryrun --preprocess --mesh single
 
-Results are cached as JSON under benchmarks/results/dryrun/.
+Results are cached as JSON under dryrun_results/ at the repository root.
 """
 import argparse
 import json
@@ -21,13 +21,13 @@ import traceback
 
 import jax
 
-from repro.configs import all_cells, get_arch
+from repro.configs import all_cells
 from repro.launch.mesh import make_production_mesh
 from repro.launch.hlo_analysis import collective_bytes, loop_aware_stats
 from repro.launch.steps import Cell, build_cell, preprocess_cells
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
-                           "benchmarks", "results", "dryrun")
+                           "dryrun_results")
 
 
 def run_cell(cell: Cell, mesh, mesh_name: str) -> dict:

@@ -17,8 +17,8 @@ def make_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
     Every sharded path in this repo is written for GSPMD propagation
     (``shard_map`` plus compiler-placed collectives around it). JAX's
     default ``Explicit`` axes put shardings into the types instead, and
-    then a reshape of a sharded array, such as the merge ladder's
-    ``ks.reshape(-1, k, run)``, fails to type-check.
+    then a reshape of a sharded array, such as the cross-device merge
+    rounds' ``ks.reshape(-1, 2, run)``, fails to type-check.
     """
     return jax.make_mesh(tuple(shape), tuple(axes),
                          axis_types=(AxisType.Auto,) * len(axes),

@@ -8,8 +8,7 @@ real data on small meshes.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import Any, Callable
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -18,8 +17,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.configs import GNN_SHAPES, LM_SHAPES, RECSYS_SHAPES, get_arch, \
     get_config
 from repro.dist.collectives import seq_sharded_decode_attn_fn
-from repro.dist.sharding import (batch_sharding, dlrm_param_shardings,
-                                 dp_axes, gnn_batch_shardings,
+from repro.dist.sharding import (dlrm_param_shardings, dp_axes,
+                                 gnn_batch_shardings,
                                  lm_cache_shardings, lm_param_shardings,
                                  model_axis_size, replicated)
 from repro.models.dlrm import (DLRMConfig, dlrm_forward, dlrm_init,
@@ -304,7 +303,7 @@ def preprocess_cells(mesh: Mesh) -> list[Cell]:
     """The AutoGNN engine itself as dry-run cells (beyond the 40):
 
     * autognn-convert / reddit: distributed COO→CSC conversion through
-      ``engine.shard.shard_convert`` — per-device chunk sorts under
+      ``engine.shard.shard_convert`` — per-device native sorts under
       shard_map, cross-device merge rounds, tiled pointer set-count
     * autognn-sample / reddit-minibatch: Selecting+Reindexing with the graph
       replicated and batch nodes sharded — DGL-style data-parallel sampling
@@ -325,7 +324,7 @@ def preprocess_cells(mesh: Mesh) -> list[Cell]:
     coo_shard = COO(dst=NamedSharding(mesh, P(dp)),
                     src=NamedSharding(mesh, P(dp)),
                     n_edges=NamedSharding(mesh, P()), n_nodes=n)
-    ecfg = EngineConfig(w_upe=8192, n_upe=0)  # n_upe=0 → full vmap lanes
+    ecfg = EngineConfig()
 
     def convert_step(coo):
         return shard_convert(mesh, coo, ecfg)

@@ -99,11 +99,11 @@ def _ptr_seg_sum(ptr: jnp.ndarray, msgs: jnp.ndarray) -> jnp.ndarray:
 
 
 def seg_sum(batch: GraphBatch, msgs: jnp.ndarray,
-            use_pallas: bool = False) -> jnp.ndarray:
+            pallas: bool = False) -> jnp.ndarray:
     """Σ over incoming edges per dst node; SENTINEL edges contribute 0."""
     valid = _valid(batch)[:, None]
     msgs = jnp.where(valid, msgs, 0)
-    if use_pallas:
+    if pallas:
         from repro.kernels.ops import segment_sum_padded
         return segment_sum_padded(batch.edge_dst, msgs.astype(jnp.float32),
                                   batch.n_nodes).astype(msgs.dtype)
@@ -114,10 +114,10 @@ def seg_sum(batch: GraphBatch, msgs: jnp.ndarray,
 
 
 def seg_mean(batch: GraphBatch, msgs: jnp.ndarray,
-             use_pallas: bool = False) -> jnp.ndarray:
-    s = seg_sum(batch, msgs, use_pallas)
+             pallas: bool = False) -> jnp.ndarray:
+    s = seg_sum(batch, msgs, pallas)
     ones = jnp.ones((batch.edge_dst.shape[0], 1), msgs.dtype)
-    deg = seg_sum(batch, ones, use_pallas)
+    deg = seg_sum(batch, ones, pallas)
     return s / jnp.maximum(deg, 1.0)
 
 

@@ -1,8 +1,7 @@
 """Mixture-of-Experts layer with sort-based (UPE) token dispatch.
 
 Token→expert dispatch is a set-partitioning problem: partition the (token,
-expert) assignment pairs by expert id — one multi-way UPE pass
-(core.set_partition.radix_partition with n_buckets = n_experts). Rank within
+expert) assignment pairs by expert id — one multi-way UPE pass. Rank within
 each expert bucket (an exclusive prefix sum, the same adder network) gives
 the capacity slot; overflowing tokens are dropped (capacity_factor). This is
 the contention-free, atomic-free dispatch the paper's primitives buy us in
@@ -14,8 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.set_partition import prefix_sum
-from repro.dist.hints import (_current_mesh, mesh_info, shard_hint,
-                              suspend_hints)
+from repro.dist.hints import _current_mesh, mesh_info, shard_hint
 
 from .common import Params, dense_init
 
@@ -42,7 +40,7 @@ def moe_apply(p: Params, x: jnp.ndarray, *, top_k: int,
               act=jax.nn.silu) -> tuple[jnp.ndarray, jnp.ndarray]:
     """x [T, d] → (y [T, d], aux_loss scalar).
 
-    Sort-based dispatch: one radix partition by expert id + prefix-sum ranks.
+    Sort-based dispatch: one partition by expert id + prefix-sum ranks.
     """
     t, d = x.shape
     e = p["w_in"].shape[0]

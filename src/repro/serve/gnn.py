@@ -54,8 +54,8 @@ def build_slot_fn(gcfg: GNNConfig, fanouts: tuple[int, ...], seed_cap: int,
     """One slot's whole request: sample → convert → forward → argmax.
 
     ``bundle`` packs everything request-independent ({"gnn": params,
-    "csc": graph, "features": table}). The sequential oracle in tests and
-    benchmarks jits THIS function at batch 1; the engine step is its vmap
+    "csc": graph, "features": table}). The sequential oracle in the tests jits
+    THIS function at batch 1; the engine step is its vmap
     — bit-equality between the two is the serving acceptance criterion.
     """
 
@@ -138,8 +138,8 @@ class GnnServeEngine(SlotEngineBase):
     forward for every occupied slot per step and retires each request with
     its per-seed class predictions in ``Request.tokens_out``. The
     preprocessing configuration (``cfg``) pins the whole dispatch stack —
-    sort_strategy, reindex_strategy, Pallas routing — exactly as
-    ``engine.service`` dispatches it.
+    sort_mode and reindex_strategy — exactly as ``engine.service``
+    dispatches it.
 
     The graph itself is mutable under traffic: ``submit_update(inserts,
     deletes)`` enqueues a ``delta_cap``-bucketed edge batch on the SAME

@@ -117,7 +117,7 @@ def test_traced_if_flags_lax_while_and_allows_static_branch():
         def f(x, cfg):
             while lax.lt(x, 3):
                 x = x + 1
-            if cfg.use_pallas:
+            if cfg.fused:
                 return x
             return -x
     """
@@ -181,16 +181,17 @@ def _toy_case(expect):
     from repro.analysis.contracts import Case, Expectation
     from repro.core.costmodel import EngineConfig, Workload
     return Case(contract="toy", label="toy", cfg=EngineConfig(),
-                workload=Workload(n=8, e=8), strategy="chunked_merge",
-                structure=("toy",), expect=expect)
+                workload=Workload(n=8, e=8), structure=("toy",),
+                expect=expect)
 
 
 def test_checker_reports_pinned_scatter():
-    """Deliberately break the no-scatter invariant. A scatter op in the
-    program text is reported directly; and because XLA:CPU's scatter
-    expander rewrites small scatters into a while loop, a pinned
-    ``.at[].set`` also trips the while-op census — the two invariants
-    cover the regression on both sides of the expander."""
+    """Deliberately break the no-scatter invariant. A scatter op in
+    synthetic program text is reported; and a ``.at[].set`` pinned into a
+    jitted function is caught in its COMPILED program too: XLA:CPU keeps
+    it as a ``scatter`` (inside a ``wrapped_scatter`` fusion on JAX 0.9),
+    so the no-scatter invariant reports it there, not only in synthetic
+    text."""
     import jax.numpy as jnp
 
     from repro.analysis.checker import evaluate_hlo
@@ -209,8 +210,9 @@ def test_checker_reports_pinned_scatter():
     hlo = (jax.jit(scatter_convert)
            .lower(jnp.arange(16), jnp.arange(16))
            .compile().as_text())
-    census = evaluate_hlo(hlo, _toy_case(Expectation(while_count=0)))
-    assert [v.invariant for v in census] == ["while-census"], hlo
+    compiled = evaluate_hlo(hlo, _toy_case(Expectation(
+        forbidden_ops=("scatter",))))
+    assert [v.invariant for v in compiled] == ["no-scatter"], hlo
 
 
 def test_checker_reports_while_census_mismatch():
@@ -244,19 +246,20 @@ def test_checker_reports_collective_ceiling_breach():
 
 
 def test_model_self_consistency_ties_census_to_merge_round_count():
-    from repro.analysis.contracts import model_self_consistency
-    from repro.core.costmodel import EngineConfig, Workload
-    for strategy in ("chunked_merge", "global_radix", "xla_sort"):
-        assert model_self_consistency(
-            EngineConfig(w_upe=256), Workload(n=200, e=2048),
-            strategy) is None
+    from repro.analysis.contracts import (CONVERT_WORKLOADS,
+                                          model_self_consistency)
+    from repro.core.costmodel import EngineConfig
+    for w in CONVERT_WORKLOADS:
+        for cfg in (EngineConfig(), EngineConfig(sort_mode="two_pass"),
+                    EngineConfig(reindex_strategy="unfused")):
+            assert model_self_consistency(cfg, w) is None
 
 
 # --------------------------------------------------- full-registry (smoke)
 def test_registry_smoke_sweep_is_violation_free():
     """The shipped tree satisfies every contract on the smoke grid (CI's
     static-analysis job runs the full 81-config grid; this keeps tier-1
-    runtime bounded while still lowering all four strategies' programs)."""
+    runtime bounded while still lowering both key schemes' programs)."""
     from repro.analysis import checker
     rep = checker.check_all(grid="smoke", parts=("convert", "sample"))
     assert rep.checks > 0
@@ -264,14 +267,14 @@ def test_registry_smoke_sweep_is_violation_free():
 
 
 def test_convert_structure_dedup_collapses_library():
-    """The 81-config library × one workload dedupes to a handful of
-    lowered programs: the program depends on chunk/ladder shape, never on
-    SCR geometry — the observation that makes the full sweep compile ~40
-    programs instead of ~1000."""
-    from repro.analysis.contracts import convert_cases
+    """The 81-config library × the workload grid dedupes to a handful of
+    lowered programs: the program depends on shapes, the key scheme and
+    the pointer epilogue, never on UPE or SCR geometry — the observation
+    that makes the full sweep compile a few programs instead of ~340."""
+    from repro.analysis.contracts import CONVERT_WORKLOADS, convert_cases
     cases = convert_cases("full")
     groups = {c.structure for c in cases}
-    assert len(cases) >= 3 * 81 * 3  # strategies × library × workloads
+    assert len(cases) >= 81 * len(CONVERT_WORKLOADS)  # library × workloads
     assert len(groups) < len(cases) / 10
 
 
@@ -279,6 +282,6 @@ def test_registry_summary_shape():
     from repro.analysis.contracts import registry_summary
     s = registry_summary()
     assert s["library_size"] == 81
-    assert s["convert_cases"] >= 972
+    assert s["convert_cases"] >= 81 * 4
     assert set(s["contracts"]) == {"convert", "sample", "shard", "serve",
                                    "gnn_serve", "delta_update"}

@@ -29,7 +29,7 @@ def make_coo(seed=0, n_nodes=50, n_edges=300, cap=512):
 # ---------------------------------------------------------------- ordering
 def test_edge_ordering_matches_lexsort():
     coo, dst, src = make_coo()
-    out = edge_ordering(coo, chunk=128)
+    out = edge_ordering(coo)
     order = np.lexsort((src, dst))
     e = len(dst)
     np.testing.assert_array_equal(np.asarray(out.dst)[:e], dst[order])
@@ -42,7 +42,7 @@ def test_edge_ordering_matches_lexsort():
 # ---------------------------------------------------------------- reshaping
 def test_pointer_array_matches_serial_and_oracle():
     coo, dst, src = make_coo(seed=1)
-    sc = edge_ordering(coo, chunk=128)
+    sc = edge_ordering(coo)
     n = coo.n_nodes
     ptr = build_pointer_array(sc.dst, n)
     ptr_serial = build_pointer_array_serial(sc.dst, n)

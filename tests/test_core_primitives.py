@@ -9,8 +9,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import (SENTINEL, count_equal, count_less_than, displacement,
                         filter_lookup, merge_sorted, partition_indices,
-                        radix_partition, radix_sort_by_key, set_partition,
-                        stable_sort_by_key)
+                        set_partition, stable_sort_by_key)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -36,30 +35,6 @@ def test_partition_indices_is_permutation(conds):
     dest, n_sel = partition_indices(cond)
     assert sorted(np.asarray(dest).tolist()) == list(range(len(conds)))
     assert int(n_sel) == sum(conds)
-
-
-@settings(max_examples=30, deadline=None)
-@given(st.lists(st.integers(0, 7), min_size=1, max_size=300))
-def test_radix_partition_matches_stable_argsort(keys):
-    keys = jnp.array(keys, jnp.int32)
-    vals = jnp.arange(keys.shape[0], dtype=jnp.int32)
-    out, base = radix_partition(vals, keys, 8)
-    expect = np.asarray(vals)[np.argsort(np.asarray(keys), kind="stable")]
-    np.testing.assert_array_equal(out, expect)
-    # bucket bases = exclusive cumsum of histogram
-    hist = np.bincount(np.asarray(keys), minlength=8)
-    np.testing.assert_array_equal(base, np.cumsum(hist) - hist)
-
-
-@settings(max_examples=20, deadline=None)
-@given(st.lists(st.integers(0, 2**16 - 1), min_size=1, max_size=256))
-def test_radix_sort_by_key(keys):
-    k = jnp.array(keys, jnp.int32)
-    v = jnp.arange(k.shape[0], dtype=jnp.int32)
-    ks, vs = radix_sort_by_key(v, k, key_bits=16, radix_bits=4)
-    order = np.argsort(np.asarray(keys), kind="stable")
-    np.testing.assert_array_equal(ks, np.asarray(keys)[order])
-    np.testing.assert_array_equal(vs, order)
 
 
 # ---------------------------------------------------------------- counting
@@ -119,13 +94,13 @@ def test_merge_sorted(a, b):
         assert all(run[i] <= run[i + 1] for i in range(len(run) - 1))
 
 
-@pytest.mark.parametrize("n,chunk", [(64, 16), (256, 64), (1024, 256)])
-def test_stable_sort_by_key_global(n, chunk):
+@pytest.mark.parametrize("n", [64, 256, 1024])
+def test_stable_sort_by_key_global(n):
     rng = np.random.default_rng(0)
     keys = rng.integers(0, 97, size=n).astype(np.int32)
     vals = np.arange(n, dtype=np.int32)
     ks, vs = stable_sort_by_key(jnp.array(keys), jnp.array(vals),
-                                key_bound=100, chunk=chunk)
+                                key_bound=100)
     order = np.argsort(keys, kind="stable")
     np.testing.assert_array_equal(ks, keys[order])
     np.testing.assert_array_equal(vs, order)
@@ -134,6 +109,6 @@ def test_stable_sort_by_key_global(n, chunk):
 def test_stable_sort_handles_sentinels():
     keys = jnp.array([5, int(SENTINEL), 1, int(SENTINEL)], jnp.int32)
     vals = jnp.array([0, 1, 2, 3], jnp.int32)
-    ks, vs = stable_sort_by_key(keys, vals, key_bound=10, chunk=4)
+    ks, vs = stable_sort_by_key(keys, vals, key_bound=10)
     np.testing.assert_array_equal(ks, [1, 5, int(SENTINEL), int(SENTINEL)])
     np.testing.assert_array_equal(vs, [2, 0, 1, 3])

@@ -1,13 +1,12 @@
 """Cost model (Table I) + reconfiguration controller properties."""
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 hypothesis = pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
-from repro.core import (Calibration, EngineConfig, Workload, best_config,
+from repro.core import (EngineConfig, Workload, best_config,
                         bitstream_library, estimate_seconds)
 from repro.core.reconfig import DynPre, autopre, statpre
 
@@ -80,33 +79,14 @@ def test_statpre_autopre_lane_split():
 def test_cost_model_ranks_match_simulated_hardware():
     """The model must rank configs correctly for its OWN cycle semantics
     (sanity: more lanes → fewer cycles; wider SCR → fewer edge cycles).
-    Pinned to a radix strategy: lane count is a UPE knob, and the native
-    xla_sort strategy (which CPU calibration picks at this scale) rightly
-    ignores it."""
+    Lane count is a UPE knob of the Table-I Ordering term; the native
+    sort the engine runs ignores it."""
+    from repro.core.costmodel import ordering_cycles, reshaping_cycles
     w = Workload(n=10**5, e=10**7)
-    c_few = EngineConfig(n_upe=4, sort_strategy="global_radix")
-    c_many = EngineConfig(n_upe=64, sort_strategy="global_radix")
+    c_few = EngineConfig(n_upe=4)
+    c_many = EngineConfig(n_upe=64)
+    assert ordering_cycles(c_many, w) < ordering_cycles(c_few, w)
+    assert (reshaping_cycles(EngineConfig(w_scr=8192), w)
+            < reshaping_cycles(EngineConfig(w_scr=512), w))
     assert (estimate_seconds(c_many, w)["ordering"]
-            < estimate_seconds(c_few, w)["ordering"])
-
-
-def test_strategy_ranking_matches_benchmark():
-    """The Table-I amendment the benchmark pins: global_radix outranks
-    chunked_merge exactly where BENCH_convert.json measures it winning
-    (every case whose merge ladder is ≥ 3 rounds deep), both are priced
-    above the native sort on the CPU calibration at every benched scale,
-    and global_radix runs zero merge rounds."""
-    from repro.core import merge_round_count, resolve_sort_strategy
-    from repro.core.costmodel import Calibration, _ordering_seconds
-    cal = Calibration()
-    cfg = EngineConfig(w_upe=1024, n_upe=8)
-    for e, n in [(16384, 2048), (131072, 16384), (1 << 20, 131072)]:
-        w = Workload(n=n, e=e)
-        assert merge_round_count(cfg, w, "global_radix") == 0
-        assert merge_round_count(cfg, w, "xla_sort") == 0
-        assert merge_round_count(cfg, w, "chunked_merge") >= 3
-        t = {s: _ordering_seconds(cfg, w, cal, s)
-             for s in ("chunked_merge", "global_radix", "xla_sort")}
-        assert t["global_radix"] < t["chunked_merge"], (e, t)
-        assert t["xla_sort"] < t["global_radix"], (e, t)
-        assert resolve_sort_strategy(cfg, w) == "xla_sort"
+            == estimate_seconds(c_few, w)["ordering"])

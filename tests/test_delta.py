@@ -1,21 +1,17 @@
 """core.delta: incremental conversion guards — bit-identity of the
 delta-merge against a from-scratch convert of the post-update edge list,
-across sort strategies, packed/pair key modes, fused/unfused rank lowering,
+across packed/pair key modes, fused/unfused rank lowering,
 adversarial delete patterns (duplicates, misses, all-delete, SENTINEL-heavy
 tails) and chained updates; plus the merge-vs-rebuild mode equality and a
 hypothesis property sweep when hypothesis is installed."""
-import dataclasses
-
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import pipeline
 from repro.core.costmodel import EngineConfig, Workload
-from repro.core.delta import EdgeDelta, delta_merge
-from repro.core.graph import COO, SENTINEL, next_pow2, random_coo
-from repro.core.ordering import stable_sort_by_key
+from repro.core.delta import EdgeDelta
+from repro.core.graph import COO, SENTINEL, next_pow2
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -86,20 +82,32 @@ def _rand_case(rng, n_nodes, n_edges, n_ins, n_del, n_miss=0, d_cap=None):
 
 
 # ------------------------------------------------- bit-identity, all axes
-@pytest.mark.parametrize("strategy",
-                         ["auto", "xla_sort", "chunked_merge",
-                          "global_radix"])
 @pytest.mark.parametrize("reindex", ["fused", "unfused"])
-def test_merge_bit_identical_across_strategies(strategy, reindex):
-    """The acceptance axis: every (sort_strategy, reindex_strategy) pair
-    produces the EXACT CSC a from-scratch convert of the updated edge
-    list produces — the delta path is a pure optimization."""
+def test_merge_bit_identical_across_strategies(reindex):
+    """The acceptance axis: either reindex_strategy produces the EXACT CSC
+    a from-scratch convert of the updated edge list produces — the delta
+    path is a pure optimization."""
     rng = np.random.default_rng(7)
     dst, src, ins, dels, delta = _rand_case(rng, 512, 1500, 100, 60,
                                             n_miss=20, d_cap=256)
-    cfg = EngineConfig(sort_strategy=strategy, reindex_strategy=reindex)
+    cfg = EngineConfig(reindex_strategy=reindex)
     csc = pipeline.convert(_coo(dst, src, 512, capacity=2048), cfg)
     _check(csc, delta, dst, src, ins, dels, cfg=cfg, mode="merge")
+
+
+@pytest.mark.parametrize("n_nodes", [300, 1 << 17], ids=["packed", "pair"])
+@pytest.mark.parametrize("reindex", ["fused", "unfused"])
+def test_merge_and_rebuild_match_oracle_per_key_scheme(reindex, n_nodes):
+    """Both delta modes under either rank lowering, on a graph whose
+    (dst, src) packs into one int32 key and on one too wide to pack (its
+    delta streams sort as one two-key sort), equal the oracle CSC."""
+    rng = np.random.default_rng(n_nodes % 97)
+    dst, src, ins, dels, delta = _rand_case(rng, n_nodes, 700, 40, 30,
+                                            n_miss=6, d_cap=64)
+    cfg = EngineConfig(reindex_strategy=reindex)
+    csc = pipeline.convert(_coo(dst, src, n_nodes, capacity=1024), cfg)
+    for mode in ("merge", "rebuild"):
+        _check(csc, delta, dst, src, ins, dels, cfg=cfg, mode=mode)
 
 
 def test_merge_equals_rebuild_mode():
@@ -232,24 +240,25 @@ def test_auto_mode_merges_small_deltas_rebuilds_huge_ones():
 
 def test_delta_program_census_expectations():
     """The numbers the HLO contract prices: resolved delta programs are
-    while-free (native delta sorts + fused ranks) with 2·passes + 1 sort
-    ops (the +1 is the event-zip merge rung)."""
-    from repro.core.costmodel import (delta_sort_op_count,
-                                      delta_while_count,
-                                      resolve_delta_sort_strategy,
-                                      delta_workload)
+    while-free (native delta sorts + fused ranks), and the compiled merge
+    path holds three sort ops — one per delta stream in either key scheme
+    (the pair is one two-key sort) plus the event-zip merge rung."""
+    from repro.analysis.contracts import DELTA_SORTS
+    from repro.core.costmodel import delta_while_count
+    from repro.launch.hlo_analysis import op_counts
     cfg = EngineConfig()
-    w = Workload(n=512, e=2048)  # packs: 1 pass per delta sort
-    assert resolve_delta_sort_strategy(cfg, delta_workload(w, 256)) == \
-        "xla_sort"
+    w = Workload(n=512, e=2048)
     assert delta_while_count(cfg, w, 256) == 0
-    assert delta_sort_op_count(cfg, w, 256) == 3
-    wp = Workload(n=1 << 17, e=2048)  # pair mode: 2 passes per delta sort
-    assert delta_sort_op_count(cfg, wp, 256) == 5
-    # forced radix strategies loop; forced unfused ranks loop
-    assert delta_while_count(cfg, w, 256, strategy="chunked_merge") > 0
     cfg_u = EngineConfig(reindex_strategy="unfused")
     assert delta_while_count(cfg_u, w, 256) == 3  # DELTA_RANK_PASSES
+    rng = np.random.default_rng(13)
+    for n_nodes in (512, 1 << 17):  # packed key, then the (dst, src) pair
+        dst, src, _, _, delta = _rand_case(rng, n_nodes, 300, 20, 10,
+                                           d_cap=64)
+        csc = pipeline.convert(_coo(dst, src, n_nodes, capacity=512))
+        hlo = (jax.jit(lambda c, d: pipeline.apply_delta(c, d, mode="merge"))
+               .lower(csc, delta).compile().as_text())
+        assert op_counts(hlo).get("sort", 0) == DELTA_SORTS, n_nodes
 
 
 # ------------------------------------------------------------ property sweep

@@ -77,7 +77,7 @@ def test_architecture_module_map_names_real_files():
     the table cells are backticked paths, so the generic checker covers
     them; this asserts the specific concept→module pairs exist."""
     must_exist = [
-        "src/repro/kernels/radix_sort.py",   # UPE
+        "src/repro/core/ordering.py",        # UPE (the Ordering)
         "src/repro/core/set_partition.py",   # UPE router
         "src/repro/core/set_count.py",       # SCR
         "src/repro/core/reindexing.py",      # Reindexing

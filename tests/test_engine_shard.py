@@ -16,9 +16,9 @@ def test_shard_preprocess_bit_identical_to_single_device():
         from repro.core import COO, EngineConfig, preprocess, random_coo
         from repro.engine.shard import jit_shard_preprocess
         rng = np.random.default_rng(0)
-        cfgs = [EngineConfig(w_upe=256, n_upe=0),
-                EngineConfig(w_upe=128, n_upe=4, selection="keysort"),
-                EngineConfig(w_upe=256, n_upe=0, use_pallas=True)]
+        cfgs = [EngineConfig(),
+                EngineConfig(selection="keysort"),
+                EngineConfig(sort_mode="two_pass")]
         for (n, e, cap) in [(200, 2000, 2048), (500, 6000, 8192)]:
             dst, src = random_coo(rng, n, e)
             coo = COO.from_arrays(dst, src, n, capacity=cap)
@@ -53,7 +53,7 @@ def test_shard_convert_matches_single_device():
         rng = np.random.default_rng(3)
         dst, src = random_coo(rng, 300, 3000)
         coo = COO.from_arrays(dst, src, 300, capacity=4096)
-        cfg = EngineConfig(w_upe=256, n_upe=0)
+        cfg = EngineConfig()
         ref = convert(coo, cfg)
         with jax.set_mesh(mesh):
             got = jax.jit(lambda c: shard_convert(mesh, c, cfg))(coo)
@@ -80,7 +80,7 @@ def test_shard_preprocess_on_2d_mesh_dp_axes_only():
         coo = COO.from_arrays(dst, src, 200, capacity=2048)
         bn = jnp.arange(8, dtype=jnp.int32)
         key = jax.random.PRNGKey(1)
-        cfg = EngineConfig(w_upe=128, n_upe=0)
+        cfg = EngineConfig()
         ref = preprocess(coo, bn, (3, 2), key, cfg)
         with jax.set_mesh(mesh):
             got = jit_shard_preprocess(mesh)(
@@ -108,7 +108,7 @@ def test_shard_sort_falls_back_on_non_pow2_device_count():
         coo = COO.from_arrays(dst, src, 120, capacity=2048)
         bn = jnp.arange(8, dtype=jnp.int32)
         key = jax.random.PRNGKey(2)
-        cfg = EngineConfig(w_upe=256, n_upe=0)
+        cfg = EngineConfig()
         with jax.set_mesh(mesh):
             got = jax.jit(lambda c, b, k: shard_preprocess(
                 mesh, c, b, (3, 2), k, cfg))(coo, bn, key)
@@ -144,10 +144,9 @@ def test_preprocess_cells_construct_with_shard_route():
 
 
 def test_shard_convert_strategy_equality():
-    """Acceptance (PR 5): the mesh-sharded convert is bit-identical to the
-    single-device one under every sort_strategy — including the Pallas
-    tiled digit-pass pair for global_radix (per-device merge-free local
-    sorts; cross-device merge rounds unchanged)."""
+    """The mesh-sharded convert (per-device native sorts + cross-device
+    merge rounds) is bit-identical to the single-device one under every
+    key scheme: the packed key, and the pair as two stable passes."""
     out = run_under_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.launch.mesh import make_mesh
@@ -157,17 +156,13 @@ def test_shard_convert_strategy_equality():
         rng = np.random.default_rng(13)
         dst, src = random_coo(rng, 300, 3000)
         coo = COO.from_arrays(dst, src, 300, capacity=4096)
-        ref = convert(coo, EngineConfig(w_upe=256, n_upe=0))
-        cases = [("chunked_merge", False), ("global_radix", False),
-                 ("xla_sort", False), ("auto", False),
-                 ("global_radix", True)]
-        for strat, use_pallas in cases:
-            cfg = EngineConfig(w_upe=256, n_upe=0, sort_strategy=strat,
-                               use_pallas=use_pallas)
+        ref = convert(coo, EngineConfig())
+        for mode in ("auto", "packed", "two_pass"):
+            cfg = EngineConfig(sort_mode=mode)
             with jax.set_mesh(mesh):
                 got = jax.jit(lambda c, cfg=cfg: shard_convert(
                     mesh, c, cfg))(coo)
-            tag = (strat, use_pallas)
+            tag = mode
             np.testing.assert_array_equal(np.asarray(got.ptr),
                                           np.asarray(ref.ptr), tag)
             np.testing.assert_array_equal(np.asarray(got.idx),
@@ -193,17 +188,13 @@ def test_shard_preprocess_reindex_strategy_equality():
         coo = COO.from_arrays(dst, src, 300, capacity=4096)
         bn = jnp.arange(16, dtype=jnp.int32)
         key = jax.random.PRNGKey(4)
-        ref = preprocess(coo, bn, (4, 3), key,
-                         EngineConfig(w_upe=256, n_upe=0))
-        cases = [("fused", False), ("unfused", False), ("auto", False),
-                 ("fused", True)]
-        for strat, use_pallas in cases:
-            cfg = EngineConfig(w_upe=256, n_upe=0, reindex_strategy=strat,
-                               use_pallas=use_pallas)
+        ref = preprocess(coo, bn, (4, 3), key, EngineConfig())
+        for strat in ("fused", "unfused", "auto"):
+            cfg = EngineConfig(reindex_strategy=strat)
             with jax.set_mesh(mesh):
                 got = jit_shard_preprocess(mesh)(
                     coo, bn, fanouts=(4, 3), key=key, cfg=cfg)
-            tag = (strat, use_pallas)
+            tag = strat
             np.testing.assert_array_equal(np.asarray(got.order),
                                           np.asarray(ref.order), tag)
             np.testing.assert_array_equal(np.asarray(got.csc.ptr),

@@ -5,72 +5,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.common import onehot_relocate_i32, prefix_sum_tree
 
 jax.config.update("jax_platform_name", "cpu")
-
-
-# ------------------------------------------------------------- helpers
-@pytest.mark.parametrize("n", [8, 128, 1024])
-@pytest.mark.parametrize("exclusive", [False, True])
-def test_prefix_sum_tree(n, exclusive):
-    rng = np.random.default_rng(0)
-    x = jnp.array(rng.integers(0, 5, n), jnp.int32)
-    got = prefix_sum_tree(x, exclusive=exclusive)
-    want = np.cumsum(np.asarray(x))
-    if exclusive:
-        want = want - np.asarray(x)
-    np.testing.assert_array_equal(got, want)
-
-
-def test_onehot_relocate_exact_for_large_int32():
-    """fp32 matmul relocation must be exact beyond 2^24 (16-bit split)."""
-    vals = jnp.array([0x7FFFFFFE, 0x01000001, -5, 123456789, 0, -2147483647],
-                     jnp.int32)
-    dest = jnp.array([5, 3, 1, 0, 2, 4], jnp.int32)
-    got = onehot_relocate_i32(dest, vals)
-    want = np.empty(6, np.int32)
-    want[np.asarray(dest)] = np.asarray(vals)
-    np.testing.assert_array_equal(got, want)
-
-
-# ------------------------------------------------------- prefix_partition
-@pytest.mark.parametrize("n,block", [(128, 128), (512, 128), (2048, 512)])
-def test_prefix_partition_kernel(n, block):
-    rng = np.random.default_rng(1)
-    vals = jnp.array(rng.integers(-2**31, 2**31 - 1, n, dtype=np.int64)
-                     .astype(np.int32))
-    cond = jnp.array(rng.random(n) < 0.4)
-    got, nsel = ops.prefix_partition(vals, cond, block=block)
-    want, want_n = ref.prefix_partition_ref(vals, cond, block)
-    np.testing.assert_array_equal(got, want)
-    np.testing.assert_array_equal(nsel, want_n)
-
-
-# ----------------------------------------------------------- radix_sort
-@pytest.mark.parametrize("n,chunk,bits", [(256, 256, 16), (512, 128, 10),
-                                          (1024, 256, 31)])
-def test_radix_sort_chunks(n, chunk, bits):
-    rng = np.random.default_rng(2)
-    hi = min(2**bits - 1, 2**31 - 1)
-    keys = jnp.array(rng.integers(0, hi, n).astype(np.int32))
-    vals = jnp.arange(n, dtype=jnp.int32)
-    gk, gv = ops.radix_sort_chunks(keys, vals, chunk=chunk, key_bits=bits)
-    wk, wv = ref.radix_sort_chunks_ref(keys, vals, chunk)
-    np.testing.assert_array_equal(gk, wk)
-    np.testing.assert_array_equal(gv, wv)
-
-
-def test_pallas_chunk_sort_plugs_into_global_sort():
-    from repro.core import stable_sort_by_key
-    rng = np.random.default_rng(3)
-    keys = jnp.array(rng.integers(0, 997, 1024).astype(np.int32))
-    vals = jnp.arange(1024, dtype=jnp.int32)
-    ks, vs = stable_sort_by_key(keys, vals, key_bound=1000, chunk=256,
-                                chunk_sort_fn=ops.pallas_chunk_sort_fn)
-    order = np.argsort(np.asarray(keys), kind="stable")
-    np.testing.assert_array_equal(ks, np.asarray(keys)[order])
-    np.testing.assert_array_equal(vs, order)
 
 
 # ------------------------------------------------------------ set_count
@@ -90,8 +26,8 @@ def test_pallas_count_fn_builds_pointer_array():
     rng = np.random.default_rng(5)
     dst, src = random_coo(rng, 100, 1500)
     coo = COO.from_arrays(dst, src, 100, capacity=2048)
-    csc_pl = convert(coo, EngineConfig(w_upe=256), count_fn=ops.pallas_count_fn)
-    csc_jnp = convert(coo, EngineConfig(w_upe=256))
+    csc_pl = convert(coo, EngineConfig(), count_fn=ops.pallas_count_fn)
+    csc_jnp = convert(coo, EngineConfig())
     np.testing.assert_array_equal(csc_pl.ptr, csc_jnp.ptr)
     np.testing.assert_array_equal(csc_pl.idx, csc_jnp.idx)
 

@@ -89,25 +89,22 @@ def test_jitted_convert_hlo_has_no_scatter(mode):
     in (scatters serialize under GSPMD and lower poorly to Mosaic)."""
     from repro.core import EngineConfig
     from repro.launch.hlo_analysis import op_counts
-    ops = op_counts(_convert_hlo(EngineConfig(w_upe=256, sort_mode=mode)))
+    ops = op_counts(_convert_hlo(EngineConfig(sort_mode=mode)))
     scatters = {k: v for k, v in ops.items() if "scatter" in k}
     assert not scatters, f"scatter ops in convert HLO ({mode}): {scatters}"
     assert any("gather" in k for k in ops), sorted(ops)
 
 
 def test_packed_convert_runs_one_global_sort():
-    """Packed-key convert must not contain the second sort pass: one
-    chunk-sort + merge-tree instead of two. Counted on compiled sort ops
-    (line-count comparisons are no longer meaningful now the fused
-    pointer epilogue flattens each program differently). On xla_sort
-    (what this workload resolves to) the two-pass scheme is one sort too,
-    over the two (dst, src) columns where the packed key sorts one."""
+    """Packed-key convert is one sort of one operand; the two-pass scheme
+    is one sort too, over the two (dst, src) columns. Counted on compiled
+    sort ops."""
     import re
 
     from repro.core import EngineConfig
     from repro.launch.hlo_analysis import op_counts
-    packed = _convert_hlo(EngineConfig(w_upe=256, sort_mode="packed"))
-    two = _convert_hlo(EngineConfig(w_upe=256, sort_mode="two_pass"))
+    packed = _convert_hlo(EngineConfig(sort_mode="packed"))
+    two = _convert_hlo(EngineConfig(sort_mode="two_pass"))
     assert op_counts(packed).get("sort", 0) == 1
     assert op_counts(two).get("sort", 0) == 1
 
@@ -119,60 +116,33 @@ def test_packed_convert_runs_one_global_sort():
     assert operands(two) == 2
 
 
-# The while-op budgets are no longer hand-derived here: the contract
-# registry (repro.analysis.contracts) computes them from the cost model
-# (costmodel.convert_while_count — pointer build + per-sort chunk scan +
-# Σ k² rank searches over the merge_round_fan_ins rungs), and the tests
-# below evaluate the compiled program against that registry exactly the
-# way `python -m repro.analysis --hlo` does.
-def _convert_contract_violations(cfg, w):
+# The while-op budget is not hand-derived here: the contract registry
+# (repro.analysis.contracts) computes it from the cost model
+# (costmodel.convert_while_count — the pointer build's rank-search loop
+# when its epilogue resolves unfused), and the test below evaluates the
+# compiled program against that registry exactly the way
+# `python -m repro.analysis --hlo` does.
+@pytest.mark.parametrize("n_nodes", [200, 70000], ids=["fused", "unfused"])
+def test_convert_hlo_census_matches_costmodel(n_nodes):
+    """One native sort and exactly ``convert_while_count`` while ops: zero
+    where ``pointer_reindex_strategy`` unrolls the 201-target pointer
+    build, one fori_loop past the fused crossover (~375 queries/pass)."""
     from repro.analysis.checker import evaluate_hlo
     from repro.analysis.contracts import (Case, convert_expectation,
                                           convert_structure)
-    from repro.core.costmodel import resolve_sort_strategy
-    strategy = resolve_sort_strategy(cfg, w)
-    case = Case(contract="convert", label=cfg.key, cfg=cfg, workload=w,
-                strategy=strategy,
-                structure=convert_structure(cfg, w, strategy),
-                expect=convert_expectation(cfg, w, strategy))
-    return evaluate_hlo(_convert_hlo(cfg), case)
-
-
-def test_global_radix_convert_hlo_has_zero_merge_rounds():
-    """The jitted global_radix convert contains ZERO merge rounds AND — at
-    this 201-target scale, where ``pointer_reindex_strategy`` resolves the
-    SCR epilogue fused — zero while ops outright: the pointer-build rank
-    search unrolls statically, so the registry expectation prices exactly
-    convert_while_count == 0. It stays scatter- and native-sort-free."""
-    from repro.core import EngineConfig, Workload, pointer_reindex_strategy
+    from repro.core import COO, EngineConfig, Workload, convert, random_coo
     from repro.core.costmodel import convert_while_count
-    cfg = EngineConfig(w_upe=256, sort_strategy="global_radix")
-    w = Workload(n=200, e=2048)  # _convert_hlo's graph: 2048-capacity
-    assert pointer_reindex_strategy(cfg, w) == "fused"
-    assert convert_while_count(cfg, w, "global_radix") == 0
-    # past the fused crossover (~375 queries/pass) the build stays a loop
-    assert convert_while_count(
-        cfg, Workload(n=70000, e=2048), "global_radix") == 1
-    vios = _convert_contract_violations(cfg, w)
+    cfg = EngineConfig()
+    w = Workload(n=n_nodes, e=2048)
+    assert convert_while_count(cfg, w) == (0 if n_nodes == 200 else 1)
+    dst, src = random_coo(np.random.default_rng(0), n_nodes, 1500)
+    coo = COO.from_arrays(dst, src, n_nodes, capacity=2048)
+    hlo = jax.jit(lambda c: convert(c, cfg)).lower(coo).compile().as_text()
+    case = Case(contract="convert", label=cfg.key, cfg=cfg, workload=w,
+                structure=convert_structure(cfg, w),
+                expect=convert_expectation(cfg, w))
+    vios = evaluate_hlo(hlo, case)
     assert not vios, "\n".join(str(v) for v in vios)
-
-
-@pytest.mark.parametrize("fan_in", [2, 4])
-def test_chunked_ladder_round_count_matches_costmodel(fan_in):
-    """The compiled merge ladder has exactly the round structure
-    ``costmodel.merge_round_count`` prices: the registry expectation's
-    while census is pointer + per-sort chunk scan + Σ k² rank searches
-    over the rungs of ``merge_round_fan_ins``."""
-    from repro.core import EngineConfig, Workload, merge_round_count
-    from repro.core.ordering import merge_round_fan_ins
-    cfg = EngineConfig(w_upe=256, sort_strategy="chunked_merge",
-                       merge_fan_in=fan_in)
-    w = Workload(n=200, e=2048)  # _convert_hlo's graph: 2048-capacity
-    fans = merge_round_fan_ins(2048, 256, fan_in)
-    assert merge_round_count(cfg, w, "chunked_merge") == len(fans)
-    assert merge_round_count(cfg, w, "global_radix") == 0
-    vios = _convert_contract_violations(cfg, w)
-    assert not vios, (fan_in, fans, [str(v) for v in vios])
 
 
 def _bytes_accessed(jitted, *args) -> float:
@@ -181,58 +151,46 @@ def _bytes_accessed(jitted, *args) -> float:
 
 
 def test_packed_ordering_keys_only_moves_fewer_bytes():
-    """The packed key IS the data — the keys-only Ordering (default) must
-    route no edge-id payload through the chunk sorts and merge rounds.
-
-    Two guards. (1) Compiled packed-mode convert accesses strictly fewer
-    bytes than two-pass (one keys-only global sort vs two payload-carrying
-    ones). (2) The keys-only *traced program* is strictly smaller than the
-    payload-carrying A/B variant (``keys_only=False``): jaxpr-level DCE
-    already strips the dead payload before XLA:CPU ever sees it, so
-    compiled bytes can't separate the two — but the opaque Mosaic kernels
-    (``radix_sort_chunks`` / ``fused_merge_rounds``) execute whatever they
-    were handed, so the payload stream must be gone at trace level, not
-    merely dead."""
+    """The packed key IS the data — the packed Ordering routes no edge-id
+    payload through its sort: the compiled packed-mode convert accesses
+    strictly fewer bytes than two-pass (one operand sorted instead of
+    two; the operand counts are pinned in
+    ``test_packed_convert_runs_one_global_sort``)."""
     from functools import partial
 
     from repro.core import COO, EngineConfig, convert, random_coo
-    from repro.core.ordering import edge_ordering
     rng = np.random.default_rng(0)
     dst, src = random_coo(rng, 200, 1500)
     coo = COO.from_arrays(dst, src, 200, capacity=2048)
 
     packed = _bytes_accessed(jax.jit(partial(
-        convert, cfg=EngineConfig(w_upe=256, sort_mode="packed"))), coo)
+        convert, cfg=EngineConfig(sort_mode="packed"))), coo)
     two_pass = _bytes_accessed(jax.jit(partial(
-        convert, cfg=EngineConfig(w_upe=256, sort_mode="two_pass"))), coo)
+        convert, cfg=EngineConfig(sort_mode="two_pass"))), coo)
     assert packed < two_pass, (packed, two_pass)
-
-    def traced_size(keys_only):
-        return len(str(jax.make_jaxpr(partial(
-            edge_ordering, chunk=256, mode="packed",
-            keys_only=keys_only))(coo)))
-
-    assert traced_size(True) < traced_size(False)
 
 
 def test_keys_only_sort_matches_payload_sort_keys():
-    """The keys-only stack (jnp and Pallas chunk sorters, fused merge)
-    returns exactly the key stream of the payload-carrying sort."""
-    from repro.core.ordering import stable_sort_by_key
-    from repro.kernels.ops import make_pallas_chunk_sort_fn, pallas_merge_fn
+    """The keys-only sort, alone and through the pairwise merge tree the
+    sharded sorter completes its runs with, returns exactly the key stream
+    of the payload-carrying sort."""
+    from repro.core.ordering import merge_rounds, stable_sort_by_key
     rng = np.random.default_rng(1)
     keys = jnp.array(rng.integers(0, 500, 1024), jnp.int32)
     vals = jnp.arange(1024, dtype=jnp.int32)
-    want, _ = stable_sort_by_key(keys, vals, 500, chunk=128)
-    got, none = stable_sort_by_key(keys, None, 500, chunk=128)
+    want, want_v = stable_sort_by_key(keys, vals, 500)
+    got, none = stable_sort_by_key(keys, None, 500)
     assert none is None
     np.testing.assert_array_equal(got, want)
-    got_p, none_p = stable_sort_by_key(
-        keys, None, 500, chunk=128,
-        chunk_sort_fn=make_pallas_chunk_sort_fn(4),
-        merge_fn=pallas_merge_fn)
-    assert none_p is None
-    np.testing.assert_array_equal(got_p, want)
+    runs = jnp.sort(keys.reshape(8, 128), axis=1).reshape(-1)
+    got_m, none_m = merge_rounds(runs, None, 128)
+    assert none_m is None
+    np.testing.assert_array_equal(got_m, want)
+    run_v = jnp.argsort(keys.reshape(8, 128), axis=1, stable=True)
+    run_v = (run_v + 128 * jnp.arange(8)[:, None]).reshape(-1)
+    got_k, got_v = merge_rounds(runs, run_v.astype(jnp.int32), 128)
+    np.testing.assert_array_equal(got_k, want)
+    np.testing.assert_array_equal(got_v, want_v)
 
 
 # ------------------------------------------------- sorted-stream reshaping

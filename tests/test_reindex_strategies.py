@@ -1,10 +1,9 @@
-"""Fused SCR epilogue: reindex strategy equality, kernels, and dispatch.
+"""Fused SCR epilogue: reindex strategy equality and dispatch.
 
-The PR-7 tentpole contract: ``build_reindex_map`` rides ONE shared
-strategy-dispatched sort and rank-arithmetic epilogues, and every
-(strategy × numbering × sorter × kernel) combination is bit-identical to
-``reindex_serial_oracle`` — so the cost-model dispatcher is free to pick
-purely on predicted latency, exactly like the sort-strategy axis.
+``build_reindex_map`` rides ONE shared native sort and rank-arithmetic
+epilogues, and every (strategy × numbering × key scheme) combination is
+bit-identical to ``reindex_serial_oracle`` — so the cost-model dispatcher
+is free to pick purely on predicted latency.
 """
 import numpy as np
 import pytest
@@ -15,7 +14,6 @@ import jax.numpy as jnp
 from repro.core import (COO, EngineConfig, SENTINEL, Workload, convert,
                         pointer_reindex_strategy, random_coo,
                         resolve_reindex_strategy, sample_subgraph)
-from repro.core.ordering import stable_sort_by_key
 from repro.core.reindexing import (build_reindex_map, reindex_edges,
                                    reindex_serial_oracle,
                                    reindex_supports_packed)
@@ -80,29 +78,36 @@ def test_sorted_numbering_ranks_uniques(strategy):
         np.testing.assert_array_equal(lk, want, name)
 
 
-@pytest.mark.parametrize("sort_strategy",
-                         ["chunked_merge", "global_radix", "xla_sort"])
-def test_shared_sort_strategy_dispatch_is_bit_identical(sort_strategy):
-    """The reindex map is invariant to which reduction structure the ONE
-    shared sort runs — the same stable-sort-canonical-output argument as
-    the Ordering strategies (and the reason ``sample_subgraph`` can
-    dispatch it from the same cost model)."""
+@pytest.mark.parametrize("numbering", ["first_occurrence", "sorted"])
+@pytest.mark.parametrize("vid_bound", [2048, None], ids=["packed", "pair"])
+def test_shared_sort_key_schemes_match_serial_oracle(vid_bound, numbering):
+    """The packed ``vid << pos`` stream (a bound that fits) and the pair
+    fallback (no bound: the position rides as payload) × both numberings
+    reproduce the oracle — map, order and edge rename."""
     vids = _vid_cases()["random"]
-
-    def sort_fn(k, v, bound):
-        return stable_sort_by_key(k, v, bound,
-                                  chunk=min(256, k.shape[0]),
-                                  strategy=sort_strategy)
-
-    ref = build_reindex_map(jnp.array(vids), vid_bound=2048)
-    got = build_reindex_map(jnp.array(vids), vid_bound=2048,
-                            sort_fn=sort_fn)
-    np.testing.assert_array_equal(np.asarray(got.sorted_vids),
-                                  np.asarray(ref.sorted_vids))
-    np.testing.assert_array_equal(np.asarray(got.order),
-                                  np.asarray(ref.order))
-    np.testing.assert_array_equal(np.asarray(got.slot_to_new),
-                                  np.asarray(ref.slot_to_new))
+    seen, order = reindex_serial_oracle(vids)
+    if numbering == "sorted":
+        order = sorted(seen)
+        seen = {v: i for i, v in enumerate(order)}
+    assert reindex_supports_packed(2048, vids.shape[0])
+    rm = build_reindex_map(jnp.array(vids), numbering=numbering,
+                           vid_bound=vid_bound)
+    assert int(rm.n_unique) == len(order)
+    got = np.asarray(rm.order)
+    np.testing.assert_array_equal(got[:len(order)], np.array(order))
+    assert (got[len(order):] == SENTINEL).all()
+    e_dst = np.concatenate([vids[:200], np.array([4000, SENTINEL], np.int32)])
+    e_src = vids[200:402]
+    sub = reindex_edges(rm, jnp.array(e_dst), jnp.array(e_src),
+                        n_nodes_cap=vids.shape[0])
+    want_d = np.array([seen.get(int(v), SENTINEL) for v in e_dst], np.int32)
+    want_s = np.array([seen.get(int(v), SENTINEL) for v in e_src], np.int32)
+    bad = (want_d == SENTINEL) | (want_s == SENTINEL)
+    np.testing.assert_array_equal(np.asarray(sub.dst),
+                                  np.where(bad, SENTINEL, want_d))
+    np.testing.assert_array_equal(np.asarray(sub.src),
+                                  np.where(bad, SENTINEL, want_s))
+    assert int(sub.n_edges) == int((~bad).sum())
 
 
 def test_packed_predicate_and_pair_fallback_agree():
@@ -116,29 +121,6 @@ def test_packed_predicate_and_pair_fallback_agree():
     none = build_reindex_map(jnp.array(vids), vid_bound=None)   # pair mode
     np.testing.assert_array_equal(np.asarray(wide.order),
                                   np.asarray(none.order))
-
-
-def test_pallas_epilogue_kernels_match_jnp_paths():
-    """The VMEM-tiled rank/rename kernels are drop-in equal to the jnp
-    fused path, for the map build AND the edge rename."""
-    from repro.kernels.ops import pallas_rank_fn, pallas_rename_fn
-    rng = np.random.default_rng(7)
-    vids = rng.integers(0, 300, 1000).astype(np.int32)
-    vids[rng.random(1000) < 0.3] = SENTINEL
-    ref = build_reindex_map(jnp.array(vids), vid_bound=300,
-                            strategy="fused")
-    ker = build_reindex_map(jnp.array(vids), vid_bound=300,
-                            strategy="fused", rank_fn=pallas_rank_fn,
-                            rename_fn=pallas_rename_fn)
-    np.testing.assert_array_equal(np.asarray(ker.order),
-                                  np.asarray(ref.order))
-    e_dst = jnp.array(rng.integers(0, 400, 256).astype(np.int32))
-    e_src = jnp.array(rng.integers(0, 400, 256).astype(np.int32))
-    a = reindex_edges(ref, e_dst, e_src, n_nodes_cap=1000)
-    b = reindex_edges(ker, e_dst, e_src, n_nodes_cap=1000)
-    np.testing.assert_array_equal(np.asarray(a.dst), np.asarray(b.dst))
-    np.testing.assert_array_equal(np.asarray(a.src), np.asarray(b.src))
-    assert int(a.n_edges) == int(b.n_edges)
 
 
 def test_pointer_build_unroll_is_bit_identical_and_dispatched():
@@ -177,7 +159,7 @@ def test_resolver_crossover_matches_calibration():
 
 def test_sample_subgraph_bit_identical_across_reindex_strategies():
     """The serving hot path: fused vs unfused vs auto produce the same
-    Subgraph bit-for-bit, on the jnp and Pallas routes."""
+    Subgraph bit-for-bit."""
     rng = np.random.default_rng(3)
     d, s = random_coo(rng, n_nodes=200, n_edges=1500)
     coo = COO.from_arrays(d, s, n_nodes=200, capacity=2048)
@@ -185,12 +167,10 @@ def test_sample_subgraph_bit_identical_across_reindex_strategies():
     bn = jnp.arange(8, dtype=jnp.int32)
     key = jax.random.PRNGKey(7)
     subs = {}
-    for rs, pallas in [("fused", False), ("unfused", False),
-                       ("auto", False), ("fused", True)]:
-        cfg = EngineConfig(w_upe=256, reindex_strategy=rs,
-                           use_pallas=pallas)
-        subs[(rs, pallas)] = sample_subgraph(csc, bn, (2, 2), key, cfg)
-    ref = subs[("fused", False)]
+    for rs in ("fused", "unfused", "auto"):
+        subs[rs] = sample_subgraph(csc, bn, (2, 2), key,
+                                   EngineConfig(reindex_strategy=rs))
+    ref = subs["fused"]
     for k, sub in subs.items():
         np.testing.assert_array_equal(np.asarray(sub.csc.ptr),
                                       np.asarray(ref.csc.ptr), k)

@@ -1,13 +1,11 @@
-"""The de-quadratic'd Ordering stack: packed-key single-pass sort,
-gather-routed relocation, fused VMEM merges, and the strategy axis
-(chunked radix sort + k-ary merge ladder vs the merge-free global radix
-sort).
+"""The Ordering: one native sort of the packed key or the (dst, src)
+pair, with the clip/restore SENTINEL contract.
 
-Every path must be *bit-identical*: packed vs two-pass vs the XLA
-comparison-sort baseline, chunked_merge vs global_radix vs auto, across
-non-pow2 VID spaces, sentinel-heavy padding, the ``radix_bits`` and
-``merge_fan_in`` sweeps, and the Pallas kernels (chunk sort + fused k-ary
-merge + tiled digit pass) against the jnp formulations.
+Every key scheme must equal ``np.lexsort`` bit for bit — SENTINEL tail
+included — across non-pow2 VID spaces on both sides of the packed-key
+bound and edge shapes from random to sentinel-heavy, hub and repeats;
+``stable_sort_by_key`` must equal ``np.argsort(kind="stable")`` with and
+without a payload. No other sorter cross-checks these any more.
 """
 import jax
 import jax.numpy as jnp
@@ -15,14 +13,9 @@ import numpy as np
 import pytest
 
 from repro.core import (COO, SENTINEL, EngineConfig, convert, convert_xla,
-                        global_radix_sort_by_key, random_coo,
-                        stable_sort_by_key, supports_packed_keys)
-from repro.core.ordering import (edge_ordering, edge_ordering_xla,
-                                 merge_round_fan_ins, merge_rounds,
-                                 merge_sorted_k)
-from repro.core.set_partition import (digit_relocation_sources,
-                                      gather_sources_from_counts,
-                                      tiled_digit_sources)
+                        random_coo, stable_sort_by_key, supports_packed_keys)
+from repro.core.ordering import edge_ordering, edge_ordering_xla
+from repro.core.set_partition import gather_sources_from_counts
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -35,45 +28,86 @@ def _coo(n_nodes, e, cap, seed=0):
     return COO.from_arrays(dst, src, n_nodes, capacity=cap), dst, src
 
 
-# ------------------------------------------------------ packed vs two-pass
-@pytest.mark.parametrize("n_nodes", [1, 7, 50, 997, 5000, 32767])
-def test_packed_two_pass_xla_bit_equal_across_vid_widths(n_nodes):
-    """Non-pow2 VID spaces, including the widest packed-capable one."""
-    e = min(4 * n_nodes, 300)
-    coo, dst, src = _coo(n_nodes, e, cap=512, seed=n_nodes)
-    packed = edge_ordering(coo, chunk=128, mode="packed")
-    two = edge_ordering(coo, chunk=128, mode="two_pass")
-    auto = edge_ordering(coo, chunk=128, mode="auto")
-    for name, out in [("two_pass", two), ("auto", auto)]:
-        np.testing.assert_array_equal(np.asarray(packed.dst),
-                                      np.asarray(out.dst), name)
-        np.testing.assert_array_equal(np.asarray(packed.src),
-                                      np.asarray(out.src), name)
+# ------------------------------------------------ the one sort vs lexsort
+WIDTHS = [1, 7, 50, 997, 5000, 32767, 32768, 40000, 300000]
+SHAPES = ["random", "sentinel_heavy", "hub", "repeats"]
+
+
+def _shaped_graph(n_nodes, shape):
+    """(dst, src, capacity) of one edge shape over ``n_nodes`` ids."""
+    rng = np.random.default_rng(n_nodes * 7 + SHAPES.index(shape))
+    if shape == "sentinel_heavy":  # capacity ≫ edges
+        dst, src = random_coo(rng, n_nodes, 20)
+        return dst, src, 1024
+    dst, src = random_coo(rng, n_nodes, 300)
+    if shape == "hub":  # the widest id holds half the edges
+        dst[rng.random(300) < 0.5] = n_nodes - 1
+        src[:8] = n_nodes - 1
+    elif shape == "repeats":  # 300 draws of 12 (dst, src) pairs
+        pick = rng.integers(0, 12, 300)
+        dst, src = dst[pick], src[pick]
+    return dst, src, 512
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+@pytest.mark.parametrize("mode,n_nodes",
+                         [("packed", n) for n in WIDTHS
+                          if supports_packed_keys(n)]
+                         + [("two_pass", n) for n in WIDTHS])
+def test_ordering_matches_lexsort(mode, n_nodes, shape):
+    """Each key scheme the VID space supports, non-pow2 widths on both
+    sides of the packed bound (32767 | 32768), against ``np.lexsort`` —
+    this guards ``stable_sort_by_key``'s and ``xla_lex_sort``'s
+    clip/restore of the SENTINEL tail in both columns."""
+    dst, src, cap = _shaped_graph(n_nodes, shape)
+    e = dst.shape[0]
+    coo = COO.from_arrays(dst, src, n_nodes, capacity=cap)
+    out = edge_ordering(coo, mode=mode)
     order = np.lexsort((src, dst))
-    np.testing.assert_array_equal(np.asarray(packed.dst)[:e], dst[order])
-    np.testing.assert_array_equal(np.asarray(packed.src)[:e], src[order])
-    assert np.all(np.asarray(packed.dst)[e:] == SEN)
-    assert np.all(np.asarray(packed.src)[e:] == SEN)
+    np.testing.assert_array_equal(np.asarray(out.dst)[:e], dst[order])
+    np.testing.assert_array_equal(np.asarray(out.src)[:e], src[order])
+    assert np.all(np.asarray(out.dst)[e:] == SEN)
+    assert np.all(np.asarray(out.src)[e:] == SEN)
+
+
+@pytest.mark.parametrize("sentinel_share", [0.0, 0.9])
+@pytest.mark.parametrize("n", [64, 256, 1024, 4096])
+@pytest.mark.parametrize("payload", [False, True])
+def test_stable_sort_by_key_matches_argsort(payload, n, sentinel_share):
+    """Keys-only and with a payload, against ``np.argsort(kind="stable")``:
+    SENTINEL keys (and keys past ``key_bound``) sort last and come back
+    as SENTINEL; equal keys keep their input order."""
+    rng = np.random.default_rng(n + int(10 * sentinel_share))
+    keys = rng.integers(0, 97, n).astype(np.int32)
+    keys[rng.random(n) < sentinel_share] = SEN
+    vals = jnp.arange(n, dtype=jnp.int32) if payload else None
+    ks, vs = stable_sort_by_key(jnp.array(keys), vals, key_bound=97)
+    order = np.argsort(keys, kind="stable")
+    np.testing.assert_array_equal(np.asarray(ks), keys[order])
+    if payload:
+        np.testing.assert_array_equal(np.asarray(vs), order)
+    else:
+        assert vs is None
 
 
 def test_auto_mode_falls_back_for_wide_vid_space():
     assert supports_packed_keys(32767) and not supports_packed_keys(32768)
     coo, dst, src = _coo(40000, 200, cap=256, seed=1)
-    auto = edge_ordering(coo, chunk=64, mode="auto")
-    two = edge_ordering(coo, chunk=64, mode="two_pass")
+    auto = edge_ordering(coo, mode="auto")
+    two = edge_ordering(coo, mode="two_pass")
     np.testing.assert_array_equal(np.asarray(auto.dst), np.asarray(two.dst))
     np.testing.assert_array_equal(np.asarray(auto.src), np.asarray(two.src))
     with pytest.raises(ValueError, match="packed"):
-        edge_ordering(coo, chunk=64, mode="packed")
+        edge_ordering(coo, mode="packed")
     with pytest.raises(ValueError, match="mode"):
-        edge_ordering(coo, chunk=64, mode="bogus")
+        edge_ordering(coo, mode="bogus")
 
 
 def test_sentinel_heavy_padding_stays_at_tail():
     """Capacity ≫ edges: the padded tail must survive every mode."""
     coo, dst, src = _coo(30, 20, cap=1024, seed=2)
     for mode in ("packed", "two_pass"):
-        out = edge_ordering(coo, chunk=256, mode=mode)
+        out = edge_ordering(coo, mode=mode)
         order = np.lexsort((src, dst))
         np.testing.assert_array_equal(np.asarray(out.dst)[:20], dst[order])
         np.testing.assert_array_equal(np.asarray(out.src)[:20], src[order])
@@ -85,69 +119,9 @@ def test_convert_bit_identical_across_modes_and_vs_xla():
     coo, dst, src = _coo(120, 900, cap=1024, seed=3)
     ref = convert_xla(coo)
     for mode in ("packed", "two_pass", "auto"):
-        csc = convert(coo, EngineConfig(w_upe=256, sort_mode=mode))
+        csc = convert(coo, EngineConfig(sort_mode=mode))
         np.testing.assert_array_equal(csc.ptr[:121], ref.ptr[:121], mode)
         np.testing.assert_array_equal(csc.idx[:900], ref.idx[:900], mode)
-
-
-# ---------------------------------------------------------- radix_bits knob
-@pytest.mark.parametrize("radix_bits", [2, 4, 8])
-def test_radix_bits_sweep_bit_identical(radix_bits):
-    """One EngineConfig.radix_bits value routes through both the jnp chunk
-    sorter and (below, via use_pallas) the Pallas kernel — outputs must not
-    depend on the digit width."""
-    coo, dst, src = _coo(90, 700, cap=1024, seed=4)
-    ref = convert(coo, EngineConfig(w_upe=256))  # default radix_bits=4
-    csc = convert(coo, EngineConfig(w_upe=256, radix_bits=radix_bits))
-    np.testing.assert_array_equal(csc.ptr, ref.ptr)
-    np.testing.assert_array_equal(csc.idx, ref.idx)
-
-
-@pytest.mark.parametrize("radix_bits", [2, 8])
-def test_radix_bits_routes_through_pallas_kernel(radix_bits):
-    coo, dst, src = _coo(60, 300, cap=512, seed=5)
-    ref = convert(coo, EngineConfig(w_upe=128))
-    csc = convert(coo, EngineConfig(w_upe=128, radix_bits=radix_bits,
-                                    use_pallas=True))
-    np.testing.assert_array_equal(csc.ptr, ref.ptr)
-    np.testing.assert_array_equal(csc.idx, ref.idx)
-
-
-def test_stable_sort_radix_bits_sweep():
-    rng = np.random.default_rng(6)
-    keys = rng.integers(0, 1009, 512).astype(np.int32)
-    vals = np.arange(512, dtype=np.int32)
-    order = np.argsort(keys, kind="stable")
-    for rb in (2, 4, 8):
-        ks, vs = stable_sort_by_key(jnp.array(keys), jnp.array(vals),
-                                    key_bound=1024, chunk=128,
-                                    radix_bits=rb)
-        np.testing.assert_array_equal(ks, keys[order], rb)
-        np.testing.assert_array_equal(vs, order, rb)
-
-
-# ------------------------------------------------------- strategy equality
-@pytest.mark.parametrize("n_nodes", [1, 7, 50, 997, 5000, 32767, 40000])
-def test_strategy_equality_sweep_across_vid_widths(n_nodes):
-    """global_radix == chunked_merge == lexsort for every key scheme the
-    VID space supports, over non-pow2 VID spaces including the widest
-    packed-capable one (32767) and a two-pass-only one (40000)."""
-    e = min(4 * n_nodes, 300)
-    coo, dst, src = _coo(n_nodes, e, cap=512, seed=n_nodes)
-    order = np.lexsort((src, dst))
-    modes = ["two_pass"] + (["packed"] if supports_packed_keys(n_nodes)
-                            else [])
-    for mode in modes:
-        for strategy in ("chunked_merge", "global_radix", "xla_sort"):
-            out = edge_ordering(coo, chunk=128, mode=mode,
-                                strategy=strategy)
-            tag = (n_nodes, mode, strategy)
-            np.testing.assert_array_equal(np.asarray(out.dst)[:e],
-                                          dst[order], tag)
-            np.testing.assert_array_equal(np.asarray(out.src)[:e],
-                                          src[order], tag)
-            assert np.all(np.asarray(out.dst)[e:] == SEN), tag
-            assert np.all(np.asarray(out.src)[e:] == SEN), tag
 
 
 def _wide_graph(case):
@@ -172,17 +146,16 @@ def _wide_graph(case):
 @pytest.mark.parametrize("case", ["random_32768", "random_300k", "repeats",
                                   "self_loops", "hub", "empty"])
 def test_two_pass_one_sort_on_xla_sort_bit_equal(case):
-    """On ``xla_sort`` the two-pass scheme is ONE two-key sort over the
-    (dst, src) columns: bit-equal to the two stable LSD passes (the
-    chunked_merge two-pass) and to the XLA lexsort baseline, SENTINEL
+    """The two-pass scheme is ONE two-key sort over the (dst, src)
+    columns: bit-equal to two stable LSD passes (the ``sort_fn`` form the
+    mesh-sharded sorter takes) and to the XLA lexsort baseline, SENTINEL
     tail of both columns included."""
     n, dst, src = _wide_graph(case)
     e = dst.shape[0]
     coo = COO.from_arrays(dst, src, n, capacity=4096)
     assert not supports_packed_keys(n)
-    one = edge_ordering(coo, chunk=128, mode="two_pass", strategy="xla_sort")
-    two = edge_ordering(coo, chunk=128, mode="two_pass",
-                        strategy="chunked_merge")
+    one = edge_ordering(coo, mode="two_pass")
+    two = edge_ordering(coo, mode="two_pass", sort_fn=stable_sort_by_key)
     base = edge_ordering_xla(coo)
     for name, ref in [("two LSD passes", two), ("lexsort", base)]:
         np.testing.assert_array_equal(np.asarray(one.dst),
@@ -195,136 +168,48 @@ def test_two_pass_one_sort_on_xla_sort_bit_equal(case):
     assert np.all(np.asarray(one.dst)[e:] == SEN)
     assert np.all(np.asarray(one.src)[e:] == SEN)
     sorts = [q for q in jax.make_jaxpr(lambda c: edge_ordering(
-        c, mode="two_pass", strategy="xla_sort"))(coo).eqns
+        c, mode="two_pass"))(coo).eqns
         if q.primitive.name == "sort"]
     assert [len(q.invars) for q in sorts] == [2]
     assert sorts[0].params["num_keys"] == 2
     assert not sorts[0].params["is_stable"]
 
 
-@pytest.mark.parametrize("fan_in", [2, 3, 4, 8])
-def test_merge_fan_in_sweep_bit_identical(fan_in):
-    """The k-ary ladder is a refinement of the binary tree: any fan-in
-    yields the same stable-sort output (and the rung count matches
-    merge_round_fan_ins)."""
-    coo, dst, src = _coo(200, 900, cap=2048, seed=21)
-    ref = edge_ordering(coo, chunk=128, fan_in=2)
-    got = edge_ordering(coo, chunk=128, fan_in=fan_in)
-    np.testing.assert_array_equal(np.asarray(got.dst), np.asarray(ref.dst))
-    np.testing.assert_array_equal(np.asarray(got.src), np.asarray(ref.src))
-    # rung count drops from log2 to log_k
-    assert len(merge_round_fan_ins(2048, 128, fan_in)) <= \
-        len(merge_round_fan_ins(2048, 128, 2))
-
-
-def test_merge_ladder_handles_non_pow2_run_counts():
-    """Regression: a run count with no divisor ≤ fan_in (3 runs under
-    fan_in=2) merges in one wider rung instead of hanging, and a chunk
-    that does not tile n contributes zero rounds to the cost model."""
-    assert merge_round_fan_ins(384, 128, 2) == [3]
-    assert merge_round_fan_ins(1152, 128, 2) == [3, 3]
-    assert merge_round_fan_ins(4096, 3000, 2) == []
-    rng = np.random.default_rng(20)
-    keys = rng.integers(0, 500, 384).astype(np.int32)
-    order = np.argsort(keys, kind="stable")
-    ks, vs = stable_sort_by_key(jnp.array(keys),
-                                jnp.arange(384, dtype=jnp.int32), 500,
-                                chunk=128)
-    np.testing.assert_array_equal(ks, keys[order])
-    np.testing.assert_array_equal(vs, order)
-
-
-def test_merge_sorted_k_matches_pairwise_fold():
-    rng = np.random.default_rng(22)
-    for k, run in [(2, 32), (3, 16), (4, 64), (8, 8)]:
-        kr = np.sort(rng.integers(0, 40, (k, run)).astype(np.int32), axis=1)
-        vr = np.arange(k * run, dtype=np.int32).reshape(k, run)
-        got_k, got_v = merge_sorted_k(jnp.array(kr), jnp.array(vr))
-        flat_k = kr.reshape(-1)
-        flat_v = vr.reshape(-1)
-        order = np.argsort(flat_k, kind="stable")
-        np.testing.assert_array_equal(np.asarray(got_k), flat_k[order], k)
-        np.testing.assert_array_equal(np.asarray(got_v), flat_v[order], k)
-        kk, none = merge_sorted_k(jnp.array(kr), None)
-        assert none is None
-        np.testing.assert_array_equal(np.asarray(kk), flat_k[order])
-
-
-def test_global_radix_sentinel_heavy_tail():
-    """Capacity ≫ edges under the merge-free strategy: the padded tail
-    must stay at the tail through every digit pass."""
-    coo, dst, src = _coo(30, 20, cap=1024, seed=23)
-    for mode in ("packed", "two_pass"):
-        out = edge_ordering(coo, chunk=256, mode=mode,
-                            strategy="global_radix")
-        order = np.lexsort((src, dst))
-        np.testing.assert_array_equal(np.asarray(out.dst)[:20], dst[order])
-        np.testing.assert_array_equal(np.asarray(out.src)[:20], src[order])
-        assert np.all(np.asarray(out.dst)[20:] == SEN), mode
-        assert np.all(np.asarray(out.src)[20:] == SEN), mode
-
-
-def test_global_radix_keys_only_matches_payload_sort():
-    """Keys-only and payload-carrying global radix sorts agree on the key
-    stream (the packed Ordering rides no payload)."""
-    rng = np.random.default_rng(24)
-    keys = jnp.array(rng.integers(0, 700, 1024), jnp.int32)
-    vals = jnp.arange(1024, dtype=jnp.int32)
-    want_k, want_v = global_radix_sort_by_key(keys, vals, 700, tile=128)
-    got_k, none = global_radix_sort_by_key(keys, None, 700, tile=128)
-    assert none is None
-    np.testing.assert_array_equal(got_k, want_k)
-    order = np.argsort(np.asarray(keys), kind="stable")
-    np.testing.assert_array_equal(np.asarray(want_v), order)
-
-
-def test_tiled_digit_sources_equals_flat_router():
-    """The two-level rank-arithmetic router is the flat [N, B] router,
-    tile by tile — any tile size, any bucket count."""
-    rng = np.random.default_rng(25)
-    for n, tile, nb in [(256, 32, 4), (512, 128, 16), (64, 64, 8),
-                        (128, 256, 2)]:
-        d = jnp.array(rng.integers(0, nb, n).astype(np.int32))
-        ref, _ = digit_relocation_sources(d, nb)
-        got = tiled_digit_sources(d, nb, tile)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref),
-                                      (n, tile, nb))
-
-
 def test_convert_strategies_bit_identical_incl_pallas():
-    """convert under every (strategy × backend) — including the Pallas
-    tiled digit-pass pair and the k-ary fused merge kernel — equals the
-    XLA baseline CSC."""
+    """convert — under each key scheme, and with the Pallas SCR count
+    kernel building the pointers — equals the XLA baseline CSC."""
+    from repro.kernels.ops import pallas_count_fn
     coo, dst, src = _coo(120, 900, cap=1024, seed=26)
     ref = convert_xla(coo)
-    for strategy in ("chunked_merge", "global_radix", "xla_sort", "auto"):
-        for use_pallas in (False, True):
-            cfg = EngineConfig(w_upe=256, sort_strategy=strategy,
-                               use_pallas=use_pallas, merge_fan_in=4)
-            csc = convert(coo, cfg)
-            tag = (strategy, use_pallas)
+    for mode in ("packed", "two_pass", "auto"):
+        for count_fn in (None, pallas_count_fn):
+            csc = convert(coo, EngineConfig(sort_mode=mode),
+                          count_fn=count_fn)
+            tag = (mode, count_fn)
             np.testing.assert_array_equal(csc.ptr, ref.ptr, tag)
             np.testing.assert_array_equal(csc.idx[:900], ref.idx[:900], tag)
 
 
 def test_preprocess_strategies_bit_identical_end_to_end():
-    """The full pipeline is strategy-invariant: same sampled subgraph
-    bit-for-bit under chunked_merge, global_radix and auto."""
-    from repro.core import preprocess
+    """The full pipeline equals the comparison-sort baseline
+    (``preprocess_xla_baseline``) bit for bit, under either reindex
+    epilogue."""
+    from repro.core import preprocess, preprocess_xla_baseline
     coo, dst, src = _coo(150, 1200, cap=2048, seed=27)
     bn = jnp.arange(8, dtype=jnp.int32)
     key = jax.random.PRNGKey(0)
-    subs = [preprocess(coo, bn, (4, 3), key,
-                       EngineConfig(w_upe=256, sort_strategy=s))
-            for s in ("chunked_merge", "global_radix", "xla_sort", "auto")]
-    for got in subs[1:]:
-        np.testing.assert_array_equal(np.asarray(subs[0].order),
-                                      np.asarray(got.order))
-        np.testing.assert_array_equal(np.asarray(subs[0].csc.ptr),
-                                      np.asarray(got.csc.ptr))
-        np.testing.assert_array_equal(np.asarray(subs[0].csc.idx),
-                                      np.asarray(got.csc.idx))
-        assert int(subs[0].n_sub_nodes) == int(got.n_sub_nodes)
+    ref = preprocess_xla_baseline(coo, bn, (4, 3), key)
+    for rs in ("fused", "unfused"):
+        got = preprocess(coo, bn, (4, 3), key,
+                         EngineConfig(reindex_strategy=rs,
+                                      selection="keysort"))
+        np.testing.assert_array_equal(np.asarray(ref.order),
+                                      np.asarray(got.order), rs)
+        np.testing.assert_array_equal(np.asarray(ref.csc.ptr),
+                                      np.asarray(got.csc.ptr), rs)
+        np.testing.assert_array_equal(np.asarray(ref.csc.idx),
+                                      np.asarray(got.csc.idx), rs)
+        assert int(ref.n_sub_nodes) == int(got.n_sub_nodes), rs
 
 
 # ------------------------------------------------------------ gather router
@@ -347,40 +232,6 @@ def test_gather_router_inverse_randomized():
         np.testing.assert_array_equal(dest[src], np.arange(n))
 
 
-# ------------------------------------------------------------- fused merge
-@pytest.mark.parametrize("n,run,max_block", [(1024, 64, 65536),
-                                             (1024, 64, 256),
-                                             (512, 512, 65536),
-                                             (2048, 32, 512)])
-def test_fused_merge_rounds_matches_jnp_tree(n, run, max_block):
-    from repro.kernels.merge import fused_merge_rounds
-    rng = np.random.default_rng(8)
-    keys = rng.integers(0, 1000, n).astype(np.int32)
-    kr = keys.reshape(-1, run)
-    order = (np.argsort(kr, axis=1, kind="stable")
-             + (np.arange(n // run) * run)[:, None])
-    k0 = jnp.array(np.sort(kr, axis=1).reshape(-1))
-    v0 = jnp.array(order.reshape(-1).astype(np.int32))
-    ref_k, ref_v = merge_rounds(k0, v0, run)
-    got_k, got_v = merge_rounds(
-        k0, v0, run,
-        merge_fn=lambda k, v, r: fused_merge_rounds(k, v, r,
-                                                    max_block=max_block))
-    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(ref_k))
-    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(ref_v))
-
-
-def test_full_pallas_sort_stack_bit_identical():
-    """Pallas chunk sort + fused VMEM merges == jnp path, end to end."""
-    coo, dst, src = _coo(80, 600, cap=1024, seed=9)
-    for mode in ("packed", "two_pass"):
-        ref = convert(coo, EngineConfig(w_upe=256, sort_mode=mode))
-        got = convert(coo, EngineConfig(w_upe=256, sort_mode=mode,
-                                        use_pallas=True))
-        np.testing.assert_array_equal(got.ptr, ref.ptr, mode)
-        np.testing.assert_array_equal(got.idx, ref.idx, mode)
-
-
 def test_preprocess_modes_bit_identical_end_to_end():
     """The full pipeline (Selecting/Reindexing included) is mode-invariant:
     same sampled subgraph bit-for-bit."""
@@ -389,7 +240,7 @@ def test_preprocess_modes_bit_identical_end_to_end():
     bn = jnp.arange(8, dtype=jnp.int32)
     key = jax.random.PRNGKey(0)
     subs = [preprocess(coo, bn, (4, 3), key,
-                       EngineConfig(w_upe=256, sort_mode=m))
+                       EngineConfig(sort_mode=m))
             for m in ("packed", "two_pass")]
     np.testing.assert_array_equal(np.asarray(subs[0].order),
                                   np.asarray(subs[1].order))

@@ -5,9 +5,6 @@ windowed pointer build and the whole-graph GAT at ogbn-products' size.
 Nothing runs here. The TPU compiler compiles for a chip that is described,
 not attached, so this catches what Mosaic or XLA:TPU would refuse (block
 shapes, layouts, unlowerable primitives, device memory) at no chip time.
-A kernel Mosaic refuses is a strict xfail with the compiler's reason, so a
-kernel that starts to compile fails here until it leaves
-``kernels.ops.MOSAIC_REFUSALS``.
 """
 import os
 import re
@@ -59,6 +56,17 @@ def no_compile_cache():
     jax.config.update("jax_enable_compilation_cache", old)
 
 
+@pytest.fixture
+def default_precision():
+    """The kernels compile at JAX's default matmul precision. The bench
+    drivers, which other tests run in the same process, set a global one
+    (``high``) that Mosaic's dot lowering does not take."""
+    old = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    yield
+    jax.config.update("jax_default_matmul_precision", old)
+
+
 def _kernel_case(name):
     """(fn, [(shape, dtype)]) for kernel ``name`` at real widths."""
     i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
@@ -75,27 +83,6 @@ def _kernel_case(name):
         "flash_attention_fwd": (
             lambda q, k, v: flash_attention_fwd(q, k, v),
             [((8, 2048, 128), bf16)] * 3),
-        "prefix_partition": (
-            lambda v, c: ops.prefix_partition(v, c),
-            [((N,), i32), ((N,), jnp.bool_)]),
-        "radix_sort_chunks": (
-            lambda k, v: ops.radix_sort_chunks(k, v, chunk=T, key_bits=16),
-            [((N,), i32), ((N,), i32)]),
-        "radix_sort_chunks_keys": (
-            lambda k: ops.radix_sort_chunks_keys(k, chunk=T, key_bits=16),
-            [((N,), i32)]),
-        "global_digit_pass": (
-            lambda k, v: ops.global_digit_pass(k, v, shift=0, tile=T),
-            [((N,), i32), ((N,), i32)]),
-        "fused_merge_rounds": (
-            lambda k, v: ops.fused_merge_rounds(k, v, run=T)[:2],
-            [((N,), i32), ((N,), i32)]),
-        "rank_search_tiles": (
-            lambda a, q: ops.rank_search_tiles(a, q),
-            [((N,), i32), ((T,), i32)]),
-        "reindex_rename_tiles": (
-            lambda a, t, q: ops.reindex_rename_tiles(a, t, q),
-            [((N,), i32), ((N,), i32), ((T,), i32)]),
     }[name]
 
 
@@ -103,15 +90,9 @@ COMPILING = ["set_count_less", "filter_tree_lookup", "segment_sum_sorted",
              "flash_attention_fwd"]
 
 
-def _refused(name):
-    return pytest.param(name, marks=pytest.mark.xfail(
-        strict=True, raises=NotImplementedError,
-        reason=f"Mosaic: {ops.MOSAIC_REFUSALS[name]}"))
-
-
-@pytest.mark.parametrize(
-    "name", COMPILING + [_refused(n) for n in ops.MOSAIC_REFUSALS])
-def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
+@pytest.mark.parametrize("name", COMPILING)
+def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache,
+                                 default_precision):
     fn, specs = _kernel_case(name)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -125,20 +106,15 @@ def _coo_spec(sharding, n_nodes, capacity):
                n_nodes=n_nodes)
 
 
-@pytest.mark.parametrize("cfg", [
-    EngineConfig(),
-    EngineConfig(use_pallas=True, sort_strategy="xla_sort",
-                 reindex_strategy="unfused"),
-], ids=["default", "pallas_pointer_build"])
+@pytest.mark.parametrize("cfg", [EngineConfig()], ids=["default"])
 def test_convert_jit_compiles_for_v5e(cfg, one_chip, no_compile_cache):
     coo = _coo_spec(one_chip, 20_000, 1 << 20)
     compiled = convert_jit.lower(coo, cfg=cfg).compile()
     mem = compiled.memory_analysis()
     # two int32 edge arrays in, one out: the program holds O(E) state
     assert mem.argument_size_in_bytes >= 2 * 4 * (1 << 20)
-    # one kernel either way, the pointer build's: the windowed SCR count
-    # by default, the all-pairs SCR count under use_pallas; it carries
-    # the scope that pointer_ms.convert reads
+    # one kernel, the pointer build's windowed SCR count; it carries the
+    # scope that pointer_ms.convert reads
     text = compiled.as_text()
     kernels = [m.group(1) for m in map(hlo_analysis._INSTR_RE.match,
                                        text.splitlines())
@@ -149,9 +125,8 @@ def test_convert_jit_compiles_for_v5e(cfg, one_chip, no_compile_cache):
 
 def test_two_pass_convert_runs_one_sort_for_v5e(one_chip, no_compile_cache):
     """300,000 nodes are 19-bit ids, too wide for the packed key, so the
-    default convert orders by the two-pass scheme on ``xla_sort``: ONE
-    two-key sort over the (dst, src) columns in ``convert.ordering``,
-    with no ``iota`` operand (XLA:TPU's way to make a sort stable) where
+    default convert orders by the two-pass scheme: ONE two-key sort over
+    the (dst, src) columns in ``convert.ordering``, with no ``iota`` operand (XLA:TPU's way to make a sort stable) where
     two stable LSD passes would sort three arrays each."""
     compiled = convert_jit.lower(_coo_spec(one_chip, 300_000, 1 << 20),
                                  cfg=EngineConfig()).compile()
@@ -208,28 +183,3 @@ def test_whole_graph_gat_compiles_at_products_size(one_chip,
     assert hlo_analysis.op_counts(text).get("scatter", 0) == 0
     assert set(hlo_analysis.op_scopes(text).values()) - {None} == {
         scopes.INFER_PROJECT, scopes.INFER_EDGE}
-
-
-@pytest.mark.parametrize("name", sorted(ops.MOSAIC_REFUSALS))
-def test_refused_kernel_raises_named_error_on_tpu(name, monkeypatch):
-    """On a TPU backend a refused kernel is never interpreted or replaced
-    by the jnp reference: asking for it raises, naming the kernel."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    stand_in = ops.refused_on_tpu(name, lambda *a: None)
-    with pytest.raises(NotImplementedError, match=name):
-        stand_in()
-
-
-def test_pipeline_routes_refuse_on_tpu(monkeypatch):
-    from repro.core.pipeline import kernel_fns
-    cfg = EngineConfig(use_pallas=True)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    chunk, count, merge, digit, rank, rename = kernel_fns(cfg)
-    assert count is ops.pallas_count_fn  # the SCR count kernel compiles
-    for fn, name in [(chunk, "radix_sort_chunks"),
-                     (merge, "fused_merge_rounds"),
-                     (digit, "global_digit_pass"),
-                     (rank, "rank_search_tiles"),
-                     (rename, "reindex_rename_tiles")]:
-        with pytest.raises(NotImplementedError, match=name):
-            fn()
